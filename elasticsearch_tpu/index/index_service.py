@@ -449,7 +449,9 @@ class IndexService:
     def mesh_executor(self):
         """Lazy per-index MeshSearchExecutor: one ('shard',) mesh over
         min(num_shards, available devices); its device-array caches live as
-        long as the index. None when the mesh can't be built."""
+        long as the index. None when the mesh can't be built — logged and
+        counted (``mesh_build_failed``) once, after which every search of
+        this index counts ``mesh_fallback_total`` on the host loop."""
         if self._mesh_executor is None:
             try:
                 from elasticsearch_tpu.parallel.executor import MeshSearchExecutor
@@ -460,6 +462,14 @@ class IndexService:
                 # the executor must never pin merged-away segments in memory
                 self._mesh_executor = MeshSearchExecutor(mesh, self.shards)
             except Exception:
+                import logging
+
+                from elasticsearch_tpu.monitor import kernels
+
+                kernels.record("mesh_build_failed")
+                logging.getLogger(__name__).exception(
+                    "[%s] shard mesh could not be built; serving from the "
+                    "host per-shard loop", self.name)
                 self._mesh_executor = False
         return self._mesh_executor or None
 

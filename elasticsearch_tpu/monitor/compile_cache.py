@@ -34,6 +34,9 @@ Event names (the ``source`` label of ``estpu_compile_cache_events_total``):
                    compiled program still serves)
   call_fallback    a resolved executable rejected its arguments at call
                    time — dropped from the memo, the plain jit path served
+  resolve_error    blob load or fresh AOT compile raised — logged, and the
+                   plain jit path serves that shape class (re-raising the
+                   program's own error if that is what it was)
 
 Phase seconds (``estpu_compile_cache_seconds_total``): ``deserialize``,
 ``compile``, ``serialize``.
@@ -50,7 +53,7 @@ from typing import Dict, Optional
 
 EVENTS = ("aot_hit", "xla_dir_hit", "fresh", "corrupt_miss",
           "mismatch_miss", "deserialize_error", "store", "store_skipped",
-          "store_error", "call_fallback")
+          "store_error", "call_fallback", "resolve_error")
 PHASES = ("deserialize", "compile", "serialize")
 
 _LOCK = threading.Lock()

@@ -37,6 +37,9 @@ Names:
   pq_cache_hit        PQ tier reloaded from the persisted blob cache
   mesh_search         request served by the mesh product path
   mesh_fallback_total request fell back to the host per-shard loop
+  mesh_build_failed   an index's shard mesh could not be built (logged
+                      with its traceback; index_service.mesh_executor) —
+                      every later search of it is a mesh_fallback_total
   mesh_host_by_design request routed to the host loop ON PURPOSE (IVF
                       probing) — not a fallback, excluded from the budget
   span_clause_truncated  a deeply-nested span clause exceeded

@@ -243,23 +243,45 @@ def os_stats() -> dict:
     return out
 
 
+def device_label() -> dict:
+    """This process's devices as JAX reports them — what every benchmark
+    record and smoke verdict names. Local devices: in a multi-process
+    world ``jax.devices()`` also lists the other ranks' chips."""
+    import jax
+
+    devs = jax.local_devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def device_stats() -> dict:
     """Accelerator stats — the TPU-native analogue of the reference's JVM
-    heap section: device kind + HBM usage when the backend exposes it."""
-    out: Dict[str, Any] = {}
-    try:
-        import jax
+    heap section: platform, device kind and count as JAX reports them, and
+    per-device HBM usage where the backend exposes it (XLA:CPU does not).
+    Every device of THIS process (``jax.local_devices()``: a remote rank's
+    chip is not addressable and has no memory stats here — each node
+    reports its own). ``hbm`` sums over them; ``devices`` carries one row
+    per chip, so the four-chip host shows where each shard's bytes
+    actually sit."""
+    import jax
 
-        dev = jax.devices()[0]
-        out["platform"] = dev.platform
-        out["device_kind"] = getattr(dev, "device_kind", "unknown")
-        ms = getattr(dev, "memory_stats", None)
-        if callable(ms):
-            stats = ms() or {}
-            out["hbm"] = {
-                "bytes_in_use": stats.get("bytes_in_use", 0),
-                "bytes_limit": stats.get("bytes_limit", 0),
-            }
-    except Exception:
-        out["platform"] = "unavailable"
-    return out
+    devs = jax.local_devices()
+    rows = []
+    for dev in devs:
+        stats = dev.memory_stats() or {}
+        rows.append({
+            "id": dev.id,
+            "bytes_in_use": stats.get("bytes_in_use", 0),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use", 0),
+            "bytes_limit": stats.get("bytes_limit", 0),
+        })
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+        "hbm": {
+            "bytes_in_use": sum(r["bytes_in_use"] for r in rows),
+            "bytes_limit": sum(r["bytes_limit"] for r in rows),
+        },
+        "devices": rows,
+    }

@@ -842,6 +842,7 @@ class Node:
                                                      process_stats)
 
         from elasticsearch_tpu.monitor.stats import SearchStats
+        from elasticsearch_tpu.native import native_available
 
         # seed keys from SearchStats itself: one source of truth
         search = {k: 0 for k in SearchStats().to_json()}
@@ -954,8 +955,12 @@ class Node:
                     # the /_cluster/diagnostics bundle
                     "flight": self.flight.stats(),
                     "watchdog": self.watchdog.stats(),
-                    # TPU-native extra: device kind + HBM usage
+                    # TPU-native extra: device kind + per-device HBM usage
                     "accelerator": device_stats(),
+                    # which translog/postings codec serves: the C++ one
+                    # built from native/codec.cpp, or the numpy fallback
+                    "native": {"codec": ("native" if native_available()
+                                         else "python")},
                 }
             },
         }

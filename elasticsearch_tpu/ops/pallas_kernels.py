@@ -28,10 +28,30 @@ NEG_INF = float("-inf")  # python scalar: jnp constants would be captured consts
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+# Scoped-VMEM ceiling every kernel here hands to Mosaic, and the budget the
+# tile choosers below fit their estimates under. The compiler's default
+# scoped limit is 16 MiB of a v5e core's 128 MiB; one explicit number keeps
+# what the choosers assume and what the compiler enforces the same thing.
+# The estimates count what Mosaic actually allocates: every BlockSpec'd
+# block twice (the pipeline double-buffers it), the minor dim padded to 128
+# lanes, and the kernel body's live temporaries. The quarter left over is
+# for what they cannot see (relayouts, spills).
+_VMEM_LIMIT_BYTES = 32 << 20
+_VMEM_BUDGET_BYTES = 24 << 20
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _lanes(n: int) -> int:
+    """Minor-dim extent as VMEM lays it out: padded to 128 lanes."""
+    return ((n + 127) // 128) * 128
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "tile", "interpret",
@@ -92,7 +112,7 @@ def knn_topk_pallas(queries, vecs, mask, *, k: int, metric: str = "cosine",
                          keepdims=True)
             v2 = jnp.sum(v.astype(jnp.float32) ** 2, axis=-1)[None, :]
             s = 1.0 / (1.0 + jnp.maximum(q2 - 2.0 * s + v2, 0.0))
-        s = jnp.where(m_ref[:][None, :], s, NEG_INF)
+        s = jnp.where(m_ref[:], s, NEG_INF)  # mask block is [1, tile]
 
         base = step * tile
         tile_ids = base + jax.lax.broadcasted_iota(jnp.int32, (Q, tile), 1)
@@ -131,7 +151,10 @@ def knn_topk_pallas(queries, vecs, mask, *, k: int, metric: str = "cosine",
         in_specs=[
             pl.BlockSpec((Q, dims), lambda i: (0, 0)),          # queries: resident
             pl.BlockSpec((tile, dims), lambda i: (i, 0)),       # corpus tile
-            pl.BlockSpec((tile,), lambda i: (i,)),              # mask tile
+            # mask rides as [1, D]: Mosaic refuses a 1-D block below the
+            # XLA tiling ("XLA layout ({0:T(1024)S(1)}) does not match
+            # Mosaic layout ({0:T(512)S(1)})" at tile=512)
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((Q, k), lambda i: (0, 0)),             # running top-k
@@ -141,8 +164,9 @@ def knn_topk_pallas(queries, vecs, mask, *, k: int, metric: str = "cosine",
             jax.ShapeDtypeStruct((Q, k), jnp.float32),
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qh, vecs, mask)
+    )(qh, vecs, mask[None, :])
     return out_v, out_i
 
 
@@ -247,8 +271,7 @@ def bm25_dense_topk_pallas(qw, impact, mask, *, k: int, tile: int = 2048,
         in_specs=[
             pl.BlockSpec((QT, F), lambda qi, di: (qi, 0)),     # query block
             pl.BlockSpec((F, tile), lambda qi, di: (0, di)),   # impact tile
-            # mask rides as [1, D] — 1-D i32 blocks can hit XLA/Mosaic
-            # layout mismatches at small tiles (T(1024) vs T(tile))
+            # mask rides as [1, D] (see knn_topk_pallas)
             pl.BlockSpec((1, tile), lambda qi, di: (0, di)),
         ],
         out_specs=[
@@ -259,6 +282,7 @@ def bm25_dense_topk_pallas(qw, impact, mask, *, k: int, tile: int = 2048,
             jax.ShapeDtypeStruct((Q, k), jnp.float32),
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qh, impact, mask[None, :])
     # the kernel's buffer is unsorted: order by (-value, doc id) — id
@@ -274,17 +298,19 @@ def bm25_dense_topk_pallas(qw, impact, mask, *, k: int, tile: int = 2048,
 
 def bm25_dense_tiles_for(Q: int, F: int, D: int):
     """(q_tile, tile) keeping the working set under the VMEM budget:
-    qw block (bf16) + impact tile (f32) + ~3 live [q_tile, tile] f32
-    intermediates (scores + candidate copies) ≤ ~10 MB."""
-    budget = 10 * 1024 * 1024
+    double-buffered qw block (bf16), impact tile (f32) and [q_tile, k]
+    outputs, the tile's bf16 copy, and ~4 live [q_tile, tile] 4-byte
+    intermediates (scores, ids, the selection loop's candidate copies)."""
     for q_tile in (512, 256, 128, 64, 32, 16, 8):
         if Q % q_tile:
             continue
         for tile in (4096, 2048, 1024, 512):
             if D % tile:
                 continue
-            est = q_tile * F * 2 + F * tile * 4 + 3 * q_tile * tile * 4
-            if est <= budget:
+            est = (2 * q_tile * _lanes(F) * 2 + 2 * F * tile * 4
+                   + F * tile * 2 + 4 * q_tile * tile * 4
+                   + 4 * q_tile * 128 * 4)
+            if est <= _VMEM_BUDGET_BYTES:
                 return q_tile, tile
     return 0, 0
 
@@ -300,10 +326,16 @@ _BM25_TRANSIENT_FAILS = [0]
 _BM25_TRANSIENT_LIMIT = 8
 
 # error shapes that mean "this kernel will NEVER compile/lower here" —
-# deterministic, so one failure latches. Everything else is treated as
-# transient (RESOURCE_EXHAUSTED, cancelled transfers, backend restarts).
+# deterministic, so one failure latches. That includes a tile over the
+# scoped VMEM limit, which Mosaic reports at compile time as
+# "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... Scoped
+# allocation with size 31.74M and limit 16.00M" (first v5e run): the same
+# shapes fail the same way every time. Everything else is treated as
+# transient (HBM RESOURCE_EXHAUSTED, cancelled transfers, backend
+# restarts).
 _COMPILE_ERR_MARKERS = ("mosaic", "lowering", "unsupported", "unimplemented",
-                        "compilation", "cannot lower")
+                        "compilation", "cannot lower", "memory space vmem",
+                        "scoped allocation")
 
 
 def _is_compile_error(e: BaseException) -> bool:
@@ -453,9 +485,18 @@ def adc_scores_pallas(codes, lut, *, tile: int = 2048,
         # small tiles (same note as the BM25 mask input) — ride as [1, W]
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, W), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(codes, lut)
     return out[0]
+
+
+# The ADC and MaxSim kernels unroll over the M subspaces, and Mosaic keeps
+# one lane-padded [tile, 128] f32 temporary alive per unrolled step instead
+# of reusing it: compiling adc_scores_pallas for a v5e at tile=4096, M=16
+# allocates 31.74 MiB of scoped VMEM (484 B per row and subspace; MaxSim at
+# the same shape 17.91 MiB, 273 B). Both choosers charge the rounded bound.
+_UNROLL_ROW_BYTES = 512
 
 
 # sticky failure latch for the ADC kernel — same discipline as the fused
@@ -475,12 +516,14 @@ def adc_pallas_tile(W: int, M: int, K: int) -> int:
         return 0
     if K % 128 != 0 or M > 32:
         return 0  # lane-aligned LUT rows; M bounds the unroll
-    budget = 8 * 1024 * 1024
     for tile in (4096, 2048, 1024, 512):
         if W % tile:
             continue
-        est = tile * M * 4 + M * K * 4 + 2 * tile * K * 4
-        if est <= budget:
+        est = (tile * M * _UNROLL_ROW_BYTES      # per-subspace temporaries
+               + 2 * tile * _lanes(M) * 4        # code block
+               + 2 * max(M, 8) * K * 4           # LUT
+               + 2 * 8 * tile * 4)               # [1, tile] output
+        if est <= _VMEM_BUDGET_BYTES:
             return tile
     return 0
 
@@ -520,16 +563,19 @@ def note_adc_success() -> None:
 
 def _knn_tile_for(Q: int, dims: int, k: int, D: int) -> int:
     """Largest corpus tile keeping the kernel's VMEM working set in budget:
-    query block + corpus tile + ~3 live [Q, tile+k] candidate copies. A
-    Q-blind tile (r4 regression: Q=256 x tile=8192 = 17 MB stack) OOMs
-    scoped vmem at batch sizes the executor actually sends."""
-    budget = 12 * 1024 * 1024
+    double-buffered query block, corpus tile and [Q, k] outputs, the
+    tile's normalized and cast copies, and ~5 live [Q, tile+k] 4-byte
+    candidate arrays in the selection loop. A Q-blind tile (r4 regression:
+    Q=256 x tile=8192 = 17 MB stack) OOMs scoped vmem at batch sizes the
+    executor actually sends."""
     qpad = ((Q + 7) // 8) * 8
     for tile in (8192, 4096, 2048, 1024, 512):
         if D % tile:
             continue
-        est = qpad * dims * 4 + tile * dims * 4 + 3 * qpad * (tile + k) * 4
-        if est <= budget:
+        est = (2 * qpad * dims * 4 + 2 * tile * dims * 4
+               + 2 * tile * dims * 4 + 5 * qpad * _lanes(tile + k) * 4
+               + 4 * qpad * 128 * 4)
+        if est <= _VMEM_BUDGET_BYTES:
             return tile
     return 0
 
@@ -615,9 +661,13 @@ def maxsim_adc_pallas(codes, luts, *, t_real: int, tile: int = 2048,
         for m in range(M):  # static unroll, M <= 32
             onehot = (jax.lax.broadcasted_iota(jnp.int32, (tile, K), 1)
                       == c[:, m][:, None]).astype(jnp.float32)
+            # HIGHEST: at the default precision the MXU rounds the LUT
+            # operand to bf16, and the kernel then disagrees with its XLA
+            # twin beyond f32 tolerance (first v5e run, PR 21)
             acc = acc + jax.lax.dot_general(
                 onehot, lut_ref[m], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
         tok = jax.lax.broadcasted_iota(jnp.int32, (tile, Tp), 1)
         acc = jnp.where(tok < t_real, acc, NEG_INF)
         out_ref[0, :] = jnp.max(acc, axis=1)
@@ -632,6 +682,7 @@ def maxsim_adc_pallas(codes, luts, *, t_real: int, tile: int = 2048,
         # 1-D outputs ride as [1, W] (same layout note as the ADC kernel)
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, W), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(codes, luts)
     return out[0]
@@ -653,13 +704,15 @@ def maxsim_adc_tile(W: int, M: int, K: int, Tp: int) -> int:
         return 0
     if K % 128 != 0 or M > 32 or Tp > 64:
         return 0  # lane-aligned LUT rows; M bounds the unroll
-    budget = 8 * 1024 * 1024
     for tile in (4096, 2048, 1024, 512):
         if W % tile:
             continue
-        est = (tile * M * 4 + M * K * Tp * 4 + tile * K * 4
-               + 2 * tile * Tp * 4)
-        if est <= budget:
+        est = (tile * M * _UNROLL_ROW_BYTES      # per-subspace temporaries
+               + 2 * tile * _lanes(M) * 4        # code block
+               + 2 * M * K * _lanes(Tp) * 4      # resident token LUTs
+               + 2 * tile * _lanes(Tp) * 4       # accumulator + masked copy
+               + 2 * 8 * tile * 4)               # [1, tile] output
+        if est <= _VMEM_BUDGET_BYTES:
             return tile
     return 0
 

@@ -1,7 +1,8 @@
 """Product quantization: PQ-coded vector slabs + asymmetric distance.
 
-BENCH_r05 measured the IVF cliff (389.5 -> 73.3 -> 12.6 qps as
-num_candidates grows 1k -> 16k) because the fine-rank stage gathers and
+IVF throughput falls off a cliff as num_candidates grows (a CPU run of
+round 5 recorded 389.5 -> 73.3 -> 12.6 qps from 1k to 16k; that record
+is no longer in the tree) because the fine-rank stage gathers and
 re-scores full-precision f32 vectors for EVERY probed candidate — a
 memory-bandwidth wall, exactly what TileMaxSim (arXiv:2606.26439)
 attacks with tiled scoring over fused product quantization. The fix is

@@ -106,13 +106,7 @@ def topk_block_config() -> int:
     silently frozen by the first trace."""
     v = os.environ.get("ESTPU_BLOCKED_TOPK", "").lower()
     if not v:
-        try:
-            import jax
-
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:  # backend probe must never break scoring
-            on_tpu = False
-        return 8192 if on_tpu else 0
+        return 8192 if jax.default_backend() == "tpu" else 0
     if v in ("0", "false", "off"):
         return 0
     if v in ("1", "true", "on"):
@@ -674,12 +668,7 @@ def tail_mode_batch() -> bool:
     mode = os.environ.get("ESTPU_TAIL_MODE", "auto").lower()
     if mode in ("candidates", "scatter"):
         return mode == "candidates"
-    try:
-        import jax as _jax
-
-        return _jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------

@@ -65,7 +65,7 @@ import threading
 import time
 from typing import Any, Dict, Optional, Set, Tuple
 
-VERSION = 1
+VERSION = 2  # 2: payload names the devices the executable was compiled for
 _EXT = "aotx"
 
 _ENABLED_LOCK = threading.Lock()
@@ -260,6 +260,17 @@ class AotProgram:
             if compiled is None:
                 compiled = self._compile_and_store(key, sig, args, kw)
         except Exception:
+            # the plain jit path serves this shape class from now on (and
+            # re-raises whatever is wrong with the program itself) — but
+            # never unseen: counted, and logged with its traceback
+            import logging
+
+            from elasticsearch_tpu.monitor import compile_cache
+
+            compile_cache.event("resolve_error")
+            logging.getLogger(__name__).exception(
+                "AOT resolution of [%s] failed; serving it through plain "
+                "jit", self.program)
             compiled = None
         with self._lock:
             if compiled is not None:
@@ -292,9 +303,17 @@ class AotProgram:
         try:
             from jax.experimental import serialize_executable as se
 
+            import jax
+
+            # load onto the devices the executable was compiled for:
+            # the default (every device of the backend) makes a
+            # one-device program on a four-chip host demand 4 shards
+            # of every argument at call time
+            by_id = {d.id: d for d in jax.devices()}
             t0 = time.perf_counter()
             compiled = se.deserialize_and_load(
-                payload["exe"], payload["in_tree"], payload["out_tree"])
+                payload["exe"], payload["in_tree"], payload["out_tree"],
+                execution_devices=[by_id[i] for i in payload["devices"]])
             compile_cache.seconds("deserialize",
                                   time.perf_counter() - t0)
         except Exception:
@@ -360,6 +379,10 @@ class AotProgram:
                 "backend": backend_fingerprint(),
                 "jax": jax.__version__,
                 "host": _host_component(),
+                # serialize() itself reads _unloaded_executable; its
+                # device_list is the assignment order the load must keep
+                "devices": [d.id for d in compiled._executable
+                            ._unloaded_executable.device_list],
                 "exe": exe,
                 "in_tree": in_tree,
                 "out_tree": out_tree,

@@ -63,29 +63,3 @@ def training_mesh(n_devices: Optional[int] = None, devices: Optional[Sequence] =
 
 def mesh_size(mesh) -> int:
     return int(np.prod(list(mesh.shape.values())))
-
-
-def get_shard_map():
-    """Version-agnostic shard_map: jax.shard_map (≥0.8, check_vma kwarg) or
-    jax.experimental.shard_map (older, check_rep kwarg)."""
-    jax = _jax()
-
-    def wrapper(f, *, mesh, in_specs, out_specs, check_rep=False):
-        sm = getattr(jax, "shard_map", None)
-        if sm is not None:
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-            except TypeError:
-                pass
-            try:  # older top-level signature spelled the flag check_rep
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
-            except TypeError:
-                return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        from jax.experimental.shard_map import shard_map as esm
-
-        return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
-
-    return wrapper

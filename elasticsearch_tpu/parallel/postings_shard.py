@@ -138,9 +138,7 @@ class PostingsShardSplit:
         from elasticsearch_tpu.ops.scoring import (bm25_score_segment,
                                                    match_count_segment,
                                                    term_mask)
-        from elasticsearch_tpu.parallel.mesh import get_shard_map
 
-        shard_map = get_shard_map()
         mesh = self.mesh
 
         def local(doc_ids, tfnorm, starts, lens, ws):
@@ -156,11 +154,12 @@ class PostingsShardSplit:
                     term_mask(d, s_, l_, P=P, D=D).astype(np.int32), "pshard")
             return (scores,)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local, mesh=mesh,
             in_specs=(PS("pshard"), PS("pshard"), PS("pshard"),
                       PS("pshard"), PS("pshard")),
             out_specs=(PS(),) if kind == "score" else (PS(), PS()),
+            check_vma=False,
         )
         prog = jax.jit(sharded)
         with self._lock:

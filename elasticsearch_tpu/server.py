@@ -30,10 +30,8 @@ def main(argv=None):
                          "the master-eligible voting configuration")
     args = ap.parse_args(argv)
 
-    from elasticsearch_tpu.utils.platform import (enable_compilation_cache,
-                                                   ensure_cpu_if_requested)
+    from elasticsearch_tpu.utils.platform import enable_compilation_cache
 
-    ensure_cpu_if_requested()
     enable_compilation_cache()  # persistent XLA cache: warm-start restarts
 
     cluster = None

@@ -3,8 +3,7 @@
 The profiler splits device time into COMPILE vs EXECUTE by watching
 ``jax.jit`` trace counts around each device call: a call whose trace
 count moved paid tracing+compilation; a steady call ran a cached
-program. The counter is tools.tpulint.trace_audit's auditor — the same
-instrument tools/tpu_ab.py uses for ``retraces_timed`` — installed
+program. The counter is tools.tpulint.trace_audit's auditor, installed
 process-wide.
 
 Install-order constraint (see trace_audit's module docstring): the

@@ -1025,8 +1025,6 @@ import sys
 sys.path.insert(0, "/root/repo")
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
-from elasticsearch_tpu.utils.platform import ensure_cpu_if_requested
-ensure_cpu_if_requested()
 from elasticsearch_tpu.cluster.bootstrap import initialize_distributed
 initialize_distributed("127.0.0.1:{port}", 1, 0)
 import jax
@@ -1036,3 +1034,58 @@ print("DIST_OK", jax.device_count(), flush=True)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120)
     assert "DIST_OK" in out.stdout, (out.stdout, out.stderr)
+
+
+def test_nodes_stats_in_a_two_process_jax_world(tmp_path):
+    """`--coordinator` with two real processes: ``jax.devices()`` lists
+    the other rank's devices too, which are not addressable here (their
+    ``memory_stats()`` raises). ``/_nodes/stats`` on every rank must
+    answer, reporting that rank's own devices."""
+    import json
+    import os
+    import urllib.request
+
+    from tests.integration.multihost_util import REPO
+
+    coord, tport = _free_port(), _free_port()
+    rest = [_free_port(), _free_port()]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", ESTPU_WARMUP="0")
+    env.pop("XLA_FLAGS", None)  # one local device per rank
+    procs = [subprocess.Popen(
+        [sys.executable, "-m", "elasticsearch_tpu.server",
+         "--coordinator", f"127.0.0.1:{coord}", "--num-processes", "2",
+         "--process-id", str(r), "--name", f"rank{r}",
+         "--port", str(rest[r]), "--transport-port", str(tport),
+         "--data-path", str(tmp_path / f"d{r}")],
+        cwd=REPO, env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True) for r in (0, 1)]
+
+    def stats(port):
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/_nodes/stats", timeout=10) as r:
+            return r.status, json.loads(r.read())
+
+    def up(port):
+        try:
+            return stats(port)[0] == 200
+        except OSError:
+            return False
+
+    try:
+        assert _wait(lambda: up(rest[0]) and up(rest[1]), timeout=90.0), \
+            [p.poll() for p in procs]
+        for port in rest:
+            st, body = stats(port)
+            assert st == 200, body
+            # rank 0 fans out to rank 1: both nodes' sections arrive whole
+            assert body["nodes"], body
+            for node in body["nodes"].values():
+                acc = node["accelerator"]
+                assert acc["platform"] == "cpu", acc
+                assert acc["device_count"] == len(acc["devices"]) == 1, acc
+        assert len(stats(rest[0])[1]["nodes"]) == 2
+    finally:
+        for p in procs:
+            p.kill()
+        for p in procs:
+            p.wait()

@@ -170,3 +170,21 @@ def test_bm25_dense_topk_early_exit_tie_parity():
         wv, wi = lax.top_k(jnp.asarray(sc), k)
         np.testing.assert_allclose(np.asarray(v), np.asarray(wv), rtol=1e-6)
         np.testing.assert_array_equal(np.asarray(i), np.asarray(wi))
+
+
+def test_scoped_vmem_exhaustion_latches_as_a_compile_error():
+    """Mosaic reports a tile over the scoped VMEM limit as
+    RESOURCE_EXHAUSTED at compile time: deterministic for the shapes, so
+    it latches at once instead of being retried as a transient. An HBM
+    RESOURCE_EXHAUSTED at run time stays transient."""
+    from elasticsearch_tpu.ops.pallas_kernels import _is_compile_error
+
+    vmem = RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack for %adc. Scoped allocation with size 31.74M "
+        "and limit 16.00M exceeded scoped vmem limit by 15.74M.")
+    hbm = RuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+        "4294967296 bytes.")
+    assert _is_compile_error(vmem)
+    assert not _is_compile_error(hbm)

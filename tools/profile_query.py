@@ -12,10 +12,8 @@ sys.argv = [sys.argv[0]]  # keep bench's module-level argparse inert
 sys.path.insert(0, "/root/repo")
 import bench
 
-from elasticsearch_tpu.utils.platform import (enable_compilation_cache,
-                                              ensure_cpu_if_requested)
+from elasticsearch_tpu.utils.platform import enable_compilation_cache
 
-ensure_cpu_if_requested()
 enable_compilation_cache()
 
 vocab = 30000

@@ -18,7 +18,8 @@ steady-state deltas, which is what the assertions use.
 
 Install order matters: the codebase binds ``jax.jit`` at import time
 (``@partial(jax.jit, static_argnames=...)``), so call ``install()``
-*before* importing ``elasticsearch_tpu``/``bench`` (see tools/tpu_ab.py),
+*before* importing ``elasticsearch_tpu``/``bench`` (as
+``elasticsearch_tpu/tracing/retrace.py`` does from the package inits),
 or use the ``trace_audit()`` context manager around code that builds its
 programs inside (program factories, tests).
 """
