@@ -401,7 +401,12 @@ def span_sink(registry: MetricsRegistry) -> Callable[[Any], None]:
     histogram labeled by span name (bounded: span names are
     instrumentation-defined, not data-derived), plus an error counter —
     the whole span substrate becomes time-series without re-instrumenting
-    a single call site. Install via ``Tracer.set_sink``."""
+    a single call site. Two counters carry what a histogram of durations
+    cannot: the span's SELF wall seconds (own minus direct children, so
+    a container's series is the time no phase below it covers) and its
+    self CPU seconds (thread CPU, same-thread children taken out, so the
+    family summed over every span counts each thread-second once).
+    Install via ``Tracer.set_sink``."""
     hist = registry.histogram(
         "estpu_span_duration_seconds",
         "Latency of every finished tracer span, by span name",
@@ -409,9 +414,28 @@ def span_sink(registry: MetricsRegistry) -> Callable[[Any], None]:
     errs = registry.counter(
         "estpu_span_errors_total",
         "Spans that finished with an error, by span name", ("span",))
+    self_wall = registry.counter(
+        "estpu_span_self_seconds_total",
+        "Wall seconds of a span that none of its direct children covers, "
+        "by span name", ("span",))
+    self_cpu = registry.counter(
+        "estpu_span_cpu_seconds_total",
+        "Thread CPU seconds of a span that no same-thread child accounts "
+        "for, by span name", ("span",))
+    # one lookup a close instead of three: span names are a small fixed
+    # vocabulary, so the memo stays as bounded as the families
+    series: Dict[str, Tuple[Any, Any, Any]] = {}
 
     def sink(span) -> None:
-        hist.labels(span.name).observe(span.duration)
+        got = series.get(span.name)
+        if got is None:
+            got = series[span.name] = (hist.labels(span.name),
+                                       self_wall.labels(span.name),
+                                       self_cpu.labels(span.name))
+        got[0].observe(span.duration)
+        got[1].inc(span.self_wall)
+        if span.cpu:  # a phase outside a profiler session read no CPU
+            got[2].inc(span.self_cpu)
         if span.error:
             errs.labels(span.name).inc()
 

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import fnmatch
 import json
+import logging
 import os
 import re
 import uuid
@@ -23,6 +24,22 @@ from elasticsearch_tpu.utils.errors import (
     IndexNotFoundException,
 )
 from elasticsearch_tpu import __version__
+
+logger = logging.getLogger(__name__)
+
+# exception types a fused _msearch attempt raised and the sequential
+# loop absorbed: each is logged once a process, counted every time
+# (estpu_span_errors_total{span="msearch.batch_attempt"})
+_BATCH_ERRORS_LOGGED: set = set()
+
+
+def _log_batch_error_once(e: BaseException) -> None:
+    name = type(e).__name__
+    if name not in _BATCH_ERRORS_LOGGED:
+        _BATCH_ERRORS_LOGGED.add(name)
+        logger.warning(
+            "a fused _msearch batch raised %s (%s); its bodies ran one by "
+            "one instead. Logged once a process for this type.", name, e)
 
 
 class Node:
@@ -650,6 +667,14 @@ class Node:
 
     def search(self, index: Optional[str], body: dict,
                preference: Optional[str] = None) -> dict:
+        # the container span of one search, whichever door it came
+        # through: _search, _search_typed, _search_all, each body of an
+        # _msearch, the Python client
+        with self.tracer.span("search", index=index or "_all"):
+            return self._search(index, body, preference)
+
+    def _search(self, index: Optional[str], body: dict,
+                preference: Optional[str]) -> dict:
         mh = getattr(self, "multihost", None)
         if mh is not None and index is not None:
             rname = mh.data.resolve_index(index)
@@ -811,11 +836,21 @@ class Node:
                     from elasticsearch_tpu.search.batch import try_batched_msearch
 
                     svc = self.indices[resolved[0]]
-                    try:
-                        check_open(svc, op="read")  # closed/blocked → sequential
-                        out = try_batched_msearch(svc, [b for _, b in pairs])
-                    except Exception:
-                        out = None  # sequential path is always correct
+                    with self.tracer.span("msearch.batch_attempt") as sp:
+                        try:
+                            check_open(svc, op="read")  # closed/blocked → sequential
+                            out = try_batched_msearch(
+                                svc, [b for _, b in pairs])
+                            sp.tag(outcome="declined" if out is None
+                                   else "fused")
+                        except Exception as e:
+                            out = None  # sequential path is always correct
+                            # ... and the cause is on record: the span's
+                            # error (estpu_span_errors_total) and one log
+                            # line a process
+                            sp.tag(outcome="error")
+                            sp.error = f"{type(e).__name__}: {e}"
+                            _log_batch_error_once(e)
                     if out is not None:
                         pre = out
         from elasticsearch_tpu.search.batch import msearch_error_entry

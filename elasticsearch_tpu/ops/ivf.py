@@ -25,6 +25,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from elasticsearch_tpu.tracing.tracer import span
 from elasticsearch_tpu.utils.shapes import pow2_bucket
 
 
@@ -231,7 +232,8 @@ def ivf_candidate_scores(index: IvfIndex, vecs, query_np: np.ndarray,
         # observatory: kernel-entry dispatch time on the shape-class key
         with REGISTRY.timed("ivf_search",
                             static_sig(C=index.C, Lmax=index.Lmax, D=D,
-                                       nprobe=nprobe)):
+                                       nprobe=nprobe)), \
+                span("device.dispatch", program="ivf_search"):
             return prog(q, index.centroids, index.lists, vecs)
 
     from elasticsearch_tpu.monitor import kernels
@@ -272,14 +274,16 @@ def ivf_candidate_scores(index: IvfIndex, vecs, query_np: np.ndarray,
             args += [pq.codes_dev(), pq.codebooks]
         if use_filter:
             args.append(filter_words)
+        name = "ivf_pq_search" if pq is not None else "ivf_search"
         try:
             # timed() records nothing when the dispatch raises — the
             # Pallas→XLA retry must not pollute the execute histogram
             with REGISTRY.timed(
-                    "ivf_pq_search" if pq is not None else "ivf_search",
+                    name,
                     static_sig(C=index.C, Lmax=index.Lmax, D=D,
                                nprobe=nprobe, fk=fk,
-                               filtered=use_filter, tile=tile)):
+                               filtered=use_filter, tile=tile)), \
+                    span("device.dispatch", program=name):
                 out = prog(*args)
         except Exception as e:
             if tile:

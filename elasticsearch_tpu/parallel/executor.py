@@ -36,6 +36,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from elasticsearch_tpu.tracing.tracer import span, tag_active
 from elasticsearch_tpu.utils.shapes import pow2_bucket
 
 # device-array LRU capacity per executor (entries are whole segment rounds;
@@ -583,7 +584,30 @@ class MeshSearchExecutor:
         return self._rounds_for(self.shards)
 
     def _search_round(self, field, query_terms, row, k):
+        with span("search.plan"):
+            prog, sig, dev, Qr, lut_shard, lut_ord = self._plan_round(
+                field, query_terms, row, k)
+        from elasticsearch_tpu.monitor.programs import REGISTRY
 
+        # program observatory: wall time (dispatch + the host pull below)
+        # lands on the (program, padded shape class, backend) key, split
+        # compile-vs-execute by this thread's trace delta
+        with REGISTRY.timed("mesh_bm25", sig, field=field):
+            with span("device.dispatch", program="mesh_bm25"):
+                vals, slot, local, totals = prog(*dev)
+            with span("device.wait"):
+                slot = np.asarray(slot)[:Qr]
+                vals, local, totals = (np.asarray(vals)[:Qr],
+                                       np.asarray(local)[:Qr],
+                                       np.asarray(totals)[:Qr])
+        # slot index → originating shard + its segment ordinal (wrap-aware);
+        # [:Qr] drops the pow2 query-padding rows
+        return vals, lut_shard[slot], local, lut_ord[slot], totals
+
+    def _plan_round(self, field, query_terms, row, k):
+        """Host half of one ``mesh_bm25`` round: shape buckets, chunk
+        tables, device inputs. Returns (program, shape signature, device
+        arguments, real query count, slot→shard and slot→segment maps)."""
         seg_row = [e[2] if e is not None else None for e in row]
         lut_shard = np.asarray([e[0] if e is not None else -1 for e in row],
                                np.int32)
@@ -659,26 +683,16 @@ class MeshSearchExecutor:
 
         prog = _bm25_program(self.mesh, self._programs,
                              Q=Q, T=T, P=Pmax, D=D, k=min(k, D))
-        from elasticsearch_tpu.monitor.programs import REGISTRY, static_sig
+        from elasticsearch_tpu.monitor.programs import static_sig
 
-        # program observatory: wall time (dispatch + the host pull below)
-        # lands on the (program, padded shape class, backend) key, split
-        # compile-vs-execute by this thread's trace delta
         # nnz in the sig: the postings buffers are [S, nnz], so two nnz
         # classes are two distinct device programs — census keys must
         # separate them or warmup verification over-reports warm
-        with REGISTRY.timed("mesh_bm25",
-                            static_sig(S=self.S, Q=Q, T=T, P=Pmax, D=D,
-                                       k=min(k, D), nnz=nnz), field=field):
-            vals, slot, local, totals = prog(
-                d_doc, d_tfn, put(h_starts), put(h_lens), put(h_ws),
-                put(h_live))
-            slot = np.asarray(slot)[:Qr]
-        # slot index → originating shard + its segment ordinal (wrap-aware);
-        # [:Qr] drops the pow2 query-padding rows
-        return (np.asarray(vals)[:Qr], lut_shard[slot],
-                np.asarray(local)[:Qr], lut_ord[slot],
-                np.asarray(totals)[:Qr])
+        sig = static_sig(S=self.S, Q=Q, T=T, P=Pmax, D=D, k=min(k, D),
+                         nnz=nnz)
+        dev = (d_doc, d_tfn, put(h_starts), put(h_lens), put(h_ws),
+               put(h_live))
+        return prog, sig, dev, Qr, lut_shard, lut_ord
 
     # -- kNN ----------------------------------------------------------------
 
@@ -783,13 +797,15 @@ class MeshSearchExecutor:
                                               if qarr.ndim == 3 else 1),
                                            D=D, dims=dims, k=min(k, D)),
                                 field=field):
-                vals, slot, local = prog(
-                    # offbudget: transient per-call query/token upload
-                    jax.device_put(np.asarray(qarr, np.float32)),  # tpulint: offbudget
-                    d_vecs, self._put_sharded(h_live))
-                slot = np.asarray(slot)
-            out = (np.asarray(vals), lut_shard[slot], np.asarray(local),
-                   lut_ord[slot], None)
+                with span("device.dispatch", program=prog_name):
+                    vals, slot, local = prog(
+                        # offbudget: transient per-call query/token upload
+                        jax.device_put(np.asarray(qarr, np.float32)),  # tpulint: offbudget
+                        d_vecs, self._put_sharded(h_live))
+                with span("device.wait"):
+                    slot = np.asarray(slot)
+                    vals, local = np.asarray(vals), np.asarray(local)
+            out = (vals, lut_shard[slot], local, lut_ord[slot], None)
             merged = out if merged is None else _merge_rounds(merged, out, k)
         return merged
 
@@ -840,15 +856,16 @@ class MeshSearchExecutor:
             # opt-in semantics). Segment identity + per-segment tombstone
             # counts key the entry, so any write/refresh invalidates.
             prep_key = None
-            if memo_key is not None and global_stats is None:
-                prep_key = (memo_key, rno,
-                            tuple((id(s), s.deleted_count)
-                                  if s is not None else None
-                                  for s in seg_row),
-                            k, k_dev, want_mask)
-            with self._prep_lock:
-                prep = (self._prep.get(prep_key)
-                        if prep_key is not None else None)
+            with span("search.plan"):
+                if memo_key is not None and global_stats is None:
+                    prep_key = (memo_key, rno,
+                                tuple((id(s), s.deleted_count)
+                                      if s is not None else None
+                                      for s in seg_row),
+                                k, k_dev, want_mask)
+                with self._prep_lock:
+                    prep = (self._prep.get(prep_key)
+                            if prep_key is not None else None)
             from elasticsearch_tpu.monitor.programs import (
                 REGISTRY as _PROGRAMS, shape_sig as _shape_sig)
 
@@ -859,7 +876,7 @@ class MeshSearchExecutor:
                     # program — its wall time (dispatch + packed-result
                     # pull) accrues as execute on the padded-shape key
                     with _PROGRAMS.timed("mesh_dsl", _shape_sig(dev)):
-                        out = jax.device_get(prog(*dev))
+                        out = _run_and_pull(prog, dev, "mesh_dsl")
                 except Exception:
                     # drop the entry and fall through to the fresh path,
                     # which carries the scatter-fallback insurance
@@ -875,15 +892,18 @@ class MeshSearchExecutor:
 
                     kernels.record("executor_prep_hit")
                     self._record_tgroup_kernels(compiled)
-                    self._decode_round(out, compiled, kk, sort_spec,
-                                       lut_shard, lut_ord, seg_row, merged,
-                                       agg_rounds, mask_rounds, want_mask)
+                    with span("search.fetch"):
+                        self._decode_round(out, compiled, kk, sort_spec,
+                                           lut_shard, lut_ord, seg_row,
+                                           merged, agg_rounds, mask_rounds,
+                                           want_mask)
                     totals += int(out[0][-1])
                     continue
             D = pow2_bucket(max((s.max_docs if s is not None else 1)
                                 for s in seg_row))
-            ctxs = [SegmentContext(s, mappings, analysis, global_stats)
-                    if s is not None else None for s in seg_row]
+            with span("search.plan"):
+                ctxs = [SegmentContext(s, mappings, analysis, global_stats)
+                        if s is not None else None for s in seg_row]
 
             def has_dense(field, _row=seg_row):
                 # triggers the lazy dense-impact build exactly like the host
@@ -897,11 +917,12 @@ class MeshSearchExecutor:
             def col_everywhere(field, _row=seg_row):
                 return all(s is None or field in s.numerics for s in _row)
 
-            comp = MeshQueryCompiler(mappings, analysis, global_stats, D=D,
-                                     has_dense=has_dense,
-                                     col_everywhere=col_everywhere)
-            compiled = comp.compile(body_query, sort_spec, agg_specs,
-                                    want_mask=want_mask)
+            with span("search.rewrite"):
+                comp = MeshQueryCompiler(mappings, analysis, global_stats,
+                                         D=D, has_dense=has_dense,
+                                         col_everywhere=col_everywhere)
+                compiled = comp.compile(body_query, sort_spec, agg_specs,
+                                        want_mask=want_mask)
             self._record_tgroup_kernels(compiled)
 
             # build per-prim data + statics; cacheable groups are device-put
@@ -911,71 +932,74 @@ class MeshSearchExecutor:
                     key, lambda: [self._put_sharded(a) for a in fn()],
                     seg_row)
 
-            arrays: List[Any] = []
-            counts: List[int] = []
-            statics: List[tuple] = []
-            for prim in compiled.prims:
-                arrs, static = prim.build(seg_row, ctxs, D, self.S, cache_fn)
-                arrays.extend(arrs)
-                counts.append(len(arrs))
-                statics.append(static)
-            kk = min(k_dev, D)
-            from elasticsearch_tpu.ops.scoring import topk_block_config
+            # device inputs: per-prim data + statics, the program for
+            # their shape class, the per-query host tables placed
+            with span("search.plan"):
+                arrays: List[Any] = []
+                counts: List[int] = []
+                statics: List[tuple] = []
+                for prim in compiled.prims:
+                    arrs, static = prim.build(seg_row, ctxs, D, self.S, cache_fn)
+                    arrays.extend(arrs)
+                    counts.append(len(arrs))
+                    statics.append(static)
+                kk = min(k_dev, D)
+                from elasticsearch_tpu.ops.scoring import topk_block_config
 
-            from elasticsearch_tpu.ops.scoring import tail_mode_batch
+                from elasticsearch_tpu.ops.scoring import tail_mode_batch
 
-            prog_key = ("dsl", compiled.struct_key(), tuple(statics),
-                        tuple(tuple(a.shape) + (str(a.dtype),) for a in arrays),
-                        kk, topk_block_config(),
-                        _tail_candidates_mode(compiled), tail_mode_batch())
-            # per-query host tables (row lists, chunk tables, bounds) ship
-            # as ONE packed word buffer: each separate device_put is a
-            # full host→device round trip
-            pack_idx = [i for i, a in enumerate(arrays)
-                        if not hasattr(a, "sharding")
-                        and isinstance(a, np.ndarray) and a.ndim >= 2
-                        and a.shape[0] == self.S and a.dtype.itemsize == 4]
-            pack_spec = ()
-            if len(pack_idx) >= 2:
-                pack_spec = tuple((i, arrays[i].shape[1:],
-                                   str(arrays[i].dtype)) for i in pack_idx)
-            prog = self._programs.get((prog_key, pack_spec))
-            if prog is None:
-                prog = _dsl_program(self.mesh, compiled, counts, statics,
-                                    kk, pack_spec,
-                                    aot_key=(prog_key, pack_spec))
-                self._programs[(prog_key, pack_spec)] = prog
-            in_pack = set(pack_idx) if pack_spec else set()
-            # fresh_bytes: only THIS entry's exclusive placements count
-            # toward its residency token — arrays that arrive already
-            # device-resident (hasattr .sharding) are the shared
-            # _cached_data groups, charged once by their own token;
-            # re-counting them per memo entry multiplied phantom bytes
-            # until the parent breaker tripped real reservations
-            fresh_bytes = 0
-            dev = []
-            for i, a in enumerate(arrays):
-                if i in in_pack:
-                    continue
-                if hasattr(a, "sharding"):
-                    dev.append(a)
-                else:
-                    d = self._put_sharded(a)
-                    fresh_bytes += int(getattr(d, "nbytes", 0) or 0)
-                    dev.append(d)
-            if pack_spec:
-                words = np.concatenate(
-                    [np.ascontiguousarray(arrays[i]).reshape(self.S, -1)
-                     .view(np.int32) for i in pack_idx], axis=1)
-                packed_dev = self._put_sharded(words)
-                fresh_bytes += int(getattr(packed_dev, "nbytes", 0) or 0)
-                dev.append(packed_dev)
+                prog_key = ("dsl", compiled.struct_key(), tuple(statics),
+                            tuple(tuple(a.shape) + (str(a.dtype),) for a in arrays),
+                            kk, topk_block_config(),
+                            _tail_candidates_mode(compiled), tail_mode_batch())
+                # per-query host tables (row lists, chunk tables, bounds) ship
+                # as ONE packed word buffer: each separate device_put is a
+                # full host→device round trip
+                pack_idx = [i for i, a in enumerate(arrays)
+                            if not hasattr(a, "sharding")
+                            and isinstance(a, np.ndarray) and a.ndim >= 2
+                            and a.shape[0] == self.S and a.dtype.itemsize == 4]
+                pack_spec = ()
+                if len(pack_idx) >= 2:
+                    pack_spec = tuple((i, arrays[i].shape[1:],
+                                       str(arrays[i].dtype)) for i in pack_idx)
+                prog = self._programs.get((prog_key, pack_spec))
+                if prog is None:
+                    prog = _dsl_program(self.mesh, compiled, counts, statics,
+                                        kk, pack_spec,
+                                        aot_key=(prog_key, pack_spec))
+                    self._programs[(prog_key, pack_spec)] = prog
+                in_pack = set(pack_idx) if pack_spec else set()
+                # fresh_bytes: only THIS entry's exclusive placements count
+                # toward its residency token — arrays that arrive already
+                # device-resident (hasattr .sharding) are the shared
+                # _cached_data groups, charged once by their own token;
+                # re-counting them per memo entry multiplied phantom bytes
+                # until the parent breaker tripped real reservations
+                fresh_bytes = 0
+                dev = []
+                for i, a in enumerate(arrays):
+                    if i in in_pack:
+                        continue
+                    if hasattr(a, "sharding"):
+                        dev.append(a)
+                    else:
+                        d = self._put_sharded(a)
+                        fresh_bytes += int(getattr(d, "nbytes", 0) or 0)
+                        dev.append(d)
+                if pack_spec:
+                    words = np.concatenate(
+                        [np.ascontiguousarray(arrays[i]).reshape(self.S, -1)
+                         .view(np.int32) for i in pack_idx], axis=1)
+                    packed_dev = self._put_sharded(words)
+                    fresh_bytes += int(getattr(packed_dev, "nbytes", 0) or 0)
+                    dev.append(packed_dev)
             # ONE host transfer for the packed result — per-array pulls
             # each pay a fixed device round-trip (the dominant per-query
             # cost on network-attached chips)
             try:
                 with _PROGRAMS.timed("mesh_dsl", _shape_sig(dev)):
-                    out = jax.device_get(prog(*dev))
+                    out = _run_and_pull(prog, dev, "mesh_dsl")
             except Exception:
                 from elasticsearch_tpu.ops.scoring import tail_mode_batch
 
@@ -997,7 +1021,7 @@ class MeshSearchExecutor:
                 # to the scatter program instead of re-failing
                 self._programs[(prog_key, pack_spec)] = prog
                 with _PROGRAMS.timed("mesh_dsl_scatter", _shape_sig(dev)):
-                    out = jax.device_get(prog(*dev))
+                    out = _run_and_pull(prog, dev, "mesh_dsl_scatter")
             if prep_key is not None:
                 from elasticsearch_tpu import resources
                 from elasticsearch_tpu.monitor import kernels
@@ -1026,9 +1050,10 @@ class MeshSearchExecutor:
                     if len(self._prep) > self._PREP_CACHE_CAP:
                         self._prep.popitem(last=False)
             totals += int(out[0][-1])
-            self._decode_round(out, compiled, kk, sort_spec, lut_shard,
-                               lut_ord, seg_row, merged, agg_rounds,
-                               mask_rounds, want_mask)
+            with span("search.fetch"):
+                self._decode_round(out, compiled, kk, sort_spec, lut_shard,
+                                   lut_ord, seg_row, merged, agg_rounds,
+                                   mask_rounds, want_mask)
         if sort_spec:
             # field-sorted: every per-shard candidate goes back — the exact
             # full-tuple ordering AND truncation happen on host
@@ -1118,7 +1143,21 @@ class MeshSearchExecutor:
         # class (per-field vocab caps), not request data  # tpulint: bucketed
         prog = _psum_program(self.mesh, self._programs, partials.shape[1:])
         with REGISTRY.timed("mesh_psum", shape_sig((partials,))):
-            return np.asarray(prog(self._put_sharded(partials)))
+            with span("device.dispatch", program="mesh_psum"):
+                res = prog(self._put_sharded(partials))
+            with span("device.wait"):
+                return np.asarray(res)
+
+
+def _run_and_pull(prog, dev, name: str):
+    """``jax.device_get(prog(*dev))`` as its two halves: the call that
+    only enqueues the program, and the pull that blocks on its result."""
+    with span("device.dispatch", program=name):
+        res = prog(*dev)
+    with span("device.wait"):
+        out = _jax().device_get(res)
+        tag_active(bytes=sum(int(getattr(a, "nbytes", 0)) for a in out))
+    return out
 
 
 def _segments_of(s) -> list:

@@ -17,6 +17,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from elasticsearch_tpu.parallel.compiler import MeshCompileError
+from elasticsearch_tpu.tracing.tracer import span
 
 
 # host-loop-only request features: their presence skips the mesh path.
@@ -156,17 +157,18 @@ def _try_mesh_search(svc, searchers, body: dict, global_stats=None) -> Optional[
             if any(inv.wants_postings_shard()
                    for inv in seg.inverted.values()):
                 return None
-    aggs = parse_aggs(body.get("aggs") or body.get("aggregations"))
-    # terms aggs without subs reduce fully on device; ANY other agg tree
-    # consumes the program's match mask through the host-side collectors —
-    # the query phase stays one mesh program either way
-    device_aggs = bool(aggs) and all(_terms_agg_eligible(a, svc.mappings)
-                                     for a in aggs)
-    agg_specs = ([(a.name, a.body.get("field")) for a in aggs]
-                 if device_aggs else None)
-    want_mask = bool(aggs) and not device_aggs
-    sort_spec = _parse_sort(body.get("sort"))
-    query = parse_query(body.get("query"))
+    with span("search.rewrite"):
+        aggs = parse_aggs(body.get("aggs") or body.get("aggregations"))
+        # terms aggs without subs reduce fully on device; ANY other agg tree
+        # consumes the program's match mask through the host-side
+        # collectors — the query phase stays one mesh program either way
+        device_aggs = bool(aggs) and all(
+            _terms_agg_eligible(a, svc.mappings) for a in aggs)
+        agg_specs = ([(a.name, a.body.get("field")) for a in aggs]
+                     if device_aggs else None)
+        want_mask = bool(aggs) and not device_aggs
+        sort_spec = _parse_sort(body.get("sort"))
+        query = parse_query(body.get("query"))
     t0 = time.perf_counter()
     executor = svc.mesh_executor()
     if executor is None:
@@ -175,12 +177,13 @@ def _try_mesh_search(svc, searchers, body: dict, global_stats=None) -> Optional[
     # prepared-query memo key: the canonical request body (repeated hot
     # queries skip compile/build/transfer; executor.search_dsl re-executes
     # the program every time — results are never cached here)
-    try:
-        import json as _json
+    with span("search.plan"):
+        try:
+            import json as _json
 
-        memo_key = _json.dumps(body, sort_keys=True)
-    except TypeError:
-        memo_key = None
+            memo_key = _json.dumps(body, sort_keys=True)
+        except TypeError:
+            memo_key = None
     try:
         cands, totals, agg_rounds, mask_rounds = executor.search_dsl(
             query, svc.mappings, svc.analysis, k,
@@ -197,78 +200,79 @@ def _try_mesh_search(svc, searchers, body: dict, global_stats=None) -> Optional[
     from elasticsearch_tpu.search.context import SegmentContext
     from elasticsearch_tpu.search.service import ShardDoc, _sort_key, _sort_value
 
-    # candidates → ShardDocs (resolve segment objects from the snapshot)
-    docs: List[ShardDoc] = []
-    ctx_cache: Dict[tuple, Any] = {}
-    for val, sh, seg_ord, local in cands:
-        seg = shard_segs[sh][seg_ord]
+    with span("search.fetch"):
+        # candidates → ShardDocs (resolve segment objects from the snapshot)
+        docs: List[ShardDoc] = []
+        ctx_cache: Dict[tuple, Any] = {}
+        for val, sh, seg_ord, local in cands:
+            seg = shard_segs[sh][seg_ord]
+            if sort_spec:
+                key2 = (sh, seg_ord)
+                ctx = ctx_cache.get(key2)
+                if ctx is None:
+                    ctx = SegmentContext(seg, svc.mappings, svc.analysis)
+                    ctx_cache[key2] = ctx
+                sv = tuple(_sort_value(ctx, s, local, None) for s in sort_spec)
+                d = ShardDoc(sh, seg, local, float("nan"), sv)
+            else:
+                d = ShardDoc(sh, seg, local, val)
+            d._seg_ord = seg_ord
+            docs.append(d)
         if sort_spec:
-            key2 = (sh, seg_ord)
-            ctx = ctx_cache.get(key2)
-            if ctx is None:
-                ctx = SegmentContext(seg, svc.mappings, svc.analysis)
-                ctx_cache[key2] = ctx
-            sv = tuple(_sort_value(ctx, s, local, None) for s in sort_spec)
-            d = ShardDoc(sh, seg, local, float("nan"), sv)
-        else:
-            d = ShardDoc(sh, seg, local, val)
-        d._seg_ord = seg_ord
-        docs.append(d)
-    if sort_spec:
-        # exact host ordering on the full value tuple (device rank is the
-        # f32 preselect, like the host loop's _sorted_candidates), staged
-        # the way the host loop stages it: per-segment full-tuple top-k,
-        # per-shard top-k, then the global merge — a global primary-rank
-        # truncation would drop tied docs the full tuple ranks higher
-        k_req = frm + size
-        by_seg: Dict[tuple, List[ShardDoc]] = {}
-        for d in docs:
-            by_seg.setdefault((d.shard_ord, d._seg_ord), []).append(d)
-        per_shard: Dict[int, List[ShardDoc]] = {}
-        for (sh, _so), ds in sorted(by_seg.items()):
-            ds.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
-                                   d.local_id))
-            per_shard.setdefault(sh, []).extend(ds[:k_req])
-        docs = []
-        for sh in sorted(per_shard):
-            ds = per_shard[sh]
-            ds.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
-                                   d._seg_ord, d.local_id))
-            docs.extend(ds[:k_req])
-        docs.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
-                                 d.shard_ord, d._seg_ord, d.local_id))
-    page = docs[frm: frm + size]
-    max_score = None
-    if not sort_spec and cands:
-        max_score = max(v for v, *_ in cands)
+            # exact host ordering on the full value tuple (device rank is the
+            # f32 preselect, like the host loop's _sorted_candidates), staged
+            # the way the host loop stages it: per-segment full-tuple top-k,
+            # per-shard top-k, then the global merge — a global primary-rank
+            # truncation would drop tied docs the full tuple ranks higher
+            k_req = frm + size
+            by_seg: Dict[tuple, List[ShardDoc]] = {}
+            for d in docs:
+                by_seg.setdefault((d.shard_ord, d._seg_ord), []).append(d)
+            per_shard: Dict[int, List[ShardDoc]] = {}
+            for (sh, _so), ds in sorted(by_seg.items()):
+                ds.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
+                                       d.local_id))
+                per_shard.setdefault(sh, []).extend(ds[:k_req])
+            docs = []
+            for sh in sorted(per_shard):
+                ds = per_shard[sh]
+                ds.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
+                                       d._seg_ord, d.local_id))
+                docs.extend(ds[:k_req])
+            docs.sort(key=lambda d: (_sort_key(d.sort_values, sort_spec),
+                                     d.shard_ord, d._seg_ord, d.local_id))
+        page = docs[frm: frm + size]
+        max_score = None
+        if not sort_spec and cands:
+            max_score = max(v for v, *_ in cands)
 
-    # fetch phase per shard, then restore global order
-    by_shard: Dict[int, List[ShardDoc]] = {}
-    for d in page:
-        by_shard.setdefault(d.shard_ord, []).append(d)
-    hits: List[dict] = []
-    fetched_docs: List[ShardDoc] = []
-    for sh, ds in by_shard.items():
-        tf = time.perf_counter()
-        hits.extend(searchers[sh].fetch_phase(ds, body, svc.name))
-        searchers[sh].stats.on_fetch((time.perf_counter() - tf) * 1000,
-                                     groups=body.get("stats"))
-        fetched_docs.extend(ds)
-    order = {id(d): i for i, d in enumerate(page)}
-    hd = sorted(zip(hits, fetched_docs), key=lambda x: order[id(x[1])])
-    hits = [h for h, _ in hd]
+        # fetch phase per shard, then restore global order
+        by_shard: Dict[int, List[ShardDoc]] = {}
+        for d in page:
+            by_shard.setdefault(d.shard_ord, []).append(d)
+        hits: List[dict] = []
+        fetched_docs: List[ShardDoc] = []
+        for sh, ds in by_shard.items():
+            tf = time.perf_counter()
+            hits.extend(searchers[sh].fetch_phase(ds, body, svc.name))
+            searchers[sh].stats.on_fetch((time.perf_counter() - tf) * 1000,
+                                         groups=body.get("stats"))
+            fetched_docs.extend(ds)
+        order = {id(d): i for i, d in enumerate(page)}
+        hd = sorted(zip(hits, fetched_docs), key=lambda x: order[id(x[1])])
+        hits = [h for h, _ in hd]
 
-    response: Dict[str, Any] = {
-        "took": int((time.perf_counter() - t0) * 1000),
-        "timed_out": False,
-        "_shards": {"total": len(searchers), "successful": len(searchers),
-                    "failed": 0},
-        "hits": {
-            "total": totals,
-            "max_score": None if (sort_spec or max_score is None) else max_score,
-            "hits": hits,
-        },
-    }
+        response: Dict[str, Any] = {
+            "took": int((time.perf_counter() - t0) * 1000),
+            "timed_out": False,
+            "_shards": {"total": len(searchers), "successful": len(searchers),
+                        "failed": 0},
+            "hits": {
+                "total": totals,
+                "max_score": None if (sort_spec or max_score is None) else max_score,
+                "hits": hits,
+            },
+        }
     if aggs:
         if device_aggs:
             partial_lists, partial_shards = _agg_partials(

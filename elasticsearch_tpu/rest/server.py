@@ -22,7 +22,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from elasticsearch_tpu.node import Node
-from elasticsearch_tpu.tracing import TaskCancelledException
+from elasticsearch_tpu.tracing import TaskCancelledException, span
+from elasticsearch_tpu.tracing.tracer import tag_active
 from elasticsearch_tpu.utils.errors import (
     ElasticsearchTpuException,
     IllegalArgumentException,
@@ -164,6 +165,8 @@ class RestController:
         one sample must never fail the request it measured."""
         try:
             endpoint = self._pattern_of.get(rx, "<unregistered>")
+            # the route pattern names the request's root span too
+            tag_active(endpoint=endpoint)
             m = self.node.metrics
             m.histogram(
                 "estpu_rest_request_duration_seconds",
@@ -3043,7 +3046,8 @@ def _bulk_index(n: Node, p, b, index: str):
 # -- search handlers ----------------------------------------------------------
 
 def _search_body(p, b) -> dict:
-    body = _json(b)
+    with span("search.body_json"):
+        body = _json(b)
     if "q" in p:
         body.setdefault("query", {"query_string": {"query": p["q"]}})
     for k in ("size", "from"):
@@ -3111,9 +3115,8 @@ def _search(n: Node, p, b, index: str):
         return 200, data.search(index, _search_body(p, b))
     with n.tasks.task("indices:data/read/search",
                       description=f"indices[{index}]"):
-        with n.tracer.span("search", index=index):
-            return 200, n.search(index, _search_body(p, b),
-                                 preference=p.get("preference"))
+        return 200, n.search(index, _search_body(p, b),
+                             preference=p.get("preference"))
 
 
 def _search_typed(n: Node, p, b, index: str, type: str):
@@ -3135,14 +3138,14 @@ def _count_typed(n: Node, p, b, index: str, type: str):
 def _search_all(n: Node, p, b):
     with n.tasks.task("indices:data/read/search",
                       description="indices[_all]"):
-        with n.tracer.span("search", index="_all"):
-            return 200, n.search(None, _search_body(p, b),
-                                 preference=p.get("preference"))
+        return 200, n.search(None, _search_body(p, b),
+                             preference=p.get("preference"))
 
 
 def _msearch(n: Node, p, b, index: Optional[str] = None,
              doc_type: Optional[str] = None):
-    lines = _ndjson(b)
+    with span("search.body_json"):
+        lines = _ndjson(b)
     pairs = []
     for i in range(0, len(lines) - 1, 2):
         header = lines[i]
@@ -5477,6 +5480,14 @@ class RestServer:
             protocol_version = "HTTP/1.1"
 
             def _handle(self, method: str):
+                # the request's root span: first byte of the body read to
+                # last byte of the reply written (the request line and the
+                # headers were parsed by http.server before this)
+                with controller.node.tracer.span("rest.request",
+                                                 method=method) as root:
+                    self._traced(method, root)
+
+            def _traced(self, method: str, root):
                 parsed = urlparse(self.path)
                 params = {k: v[0] for k, v in
                           parse_qs(parsed.query,
@@ -5499,6 +5510,15 @@ class RestServer:
                 else:
                     status, payload = controller.dispatch(
                         method, parsed.path, params, body, headers=hdrs)
+                with span("rest.respond"):
+                    data = self._respond(method, parsed, params, status,
+                                         payload)
+                root.tag(status=status, bytes_in=length,
+                         bytes_out=len(data))
+
+            def _respond(self, method: str, parsed, params, status,
+                         payload) -> bytes:
+                """Serialise the payload and write the reply."""
                 ctype = "application/json; charset=UTF-8"
                 if isinstance(payload, str):
                     # text endpoints (hot_threads, _cat help): raw body
@@ -5528,6 +5548,7 @@ class RestServer:
                 self.end_headers()
                 if method != "HEAD" and data:
                     self.wfile.write(data)
+                return data
 
             def do_GET(self):
                 self._handle("GET")

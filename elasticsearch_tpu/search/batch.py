@@ -30,6 +30,7 @@ from elasticsearch_tpu.search.queries import (KnnQuery,
                                               hybrid_bm25_topk_batch,
                                               parse_query)
 from elasticsearch_tpu.search.service import ShardDoc
+from elasticsearch_tpu.tracing.tracer import span
 from elasticsearch_tpu.utils.errors import ElasticsearchTpuException
 
 _ALLOWED_KEYS = {"query", "size", "from", "_source"}
@@ -188,23 +189,26 @@ def knn_topk_fused_batch(ctx, queries, k: int):
     if any(q.tokens.shape[1] != vc.dims for q in queries):
         return None
     Q = len(queries)
-    T = pow2_bucket(max(q.tokens.shape[0] for q in queries), minimum=1)
-    toks = np.empty((Q, T, vc.dims), np.float32)
-    for i, q in enumerate(queries):
-        t = q.tokens
-        reps = -(-T // t.shape[0])
-        toks[i] = np.tile(t, (reps, 1))[:T]
-    lv = vc.exists & ctx.segment.live
+    with span("search.plan"):
+        T = pow2_bucket(max(q.tokens.shape[0] for q in queries), minimum=1)
+        toks = np.empty((Q, T, vc.dims), np.float32)
+        for i, q in enumerate(queries):
+            t = q.tokens
+            reps = -(-T // t.shape[0])
+            toks[i] = np.tile(t, (reps, 1))[:T]
+        boosts = np.asarray([q.boost for q in queries], np.float32)
     kc = int(min(max(q0.num_candidates, k), ctx.D))
-    flat = jnp.asarray(toks.reshape(Q * T, vc.dims))
-    vals, idx = knn_topk_auto(flat, vc.vecs, lv, k=kc,
-                              metric=vc.similarity, precise=True)
-    best_v, best_i, n_unique = merge_candidate_topk(
-        vals.reshape(Q, T * kc), idx.reshape(Q, T * kc), k=min(k, kc))
-    boosts = np.asarray([q.boost for q in queries], np.float32)
+    with span("device.dispatch", program="batch_knn_fused"):
+        lv = vc.exists & ctx.segment.live
+        flat = jnp.asarray(toks.reshape(Q * T, vc.dims))
+        vals, idx = knn_topk_auto(flat, vc.vecs, lv, k=kc,
+                                  metric=vc.similarity, precise=True)
+        best_v, best_i, n_unique = merge_candidate_topk(
+            vals.reshape(Q, T * kc), idx.reshape(Q, T * kc), k=min(k, kc))
     kernels.record("knn_fused_batch", n=Q)
-    return (np.asarray(best_v) * boosts[:, None], np.asarray(best_i),
-            np.asarray(n_unique).astype(np.int64))
+    with span("device.wait"):
+        return (np.asarray(best_v) * boosts[:, None], np.asarray(best_i),
+                np.asarray(n_unique).astype(np.int64))
 
 
 def execute_batch(svc, bodies: List[dict], queries: Optional[list] = None,
@@ -285,8 +289,10 @@ def execute_batch(svc, bodies: List[dict], queries: Optional[list] = None,
                 for seg in s.segments:
                     if seg.has_nested:
                         return None
-                    ctx = SegmentContext(seg, svc.mappings, svc.analysis,
-                                         index_name=svc.name)
+                    with span("search.plan"):
+                        ctx = SegmentContext(seg, svc.mappings,
+                                             svc.analysis,
+                                             index_name=svc.name)
                     # observatory: classify/record only AFTER the tier
                     # accepts — a refusal (None) ran no device program. A
                     # tier-1 refusal re-snapshots so tier 2 isn't billed
@@ -298,7 +304,9 @@ def execute_batch(svc, bodies: List[dict], queries: Optional[list] = None,
                         # hybrid tier: both engines + per-request fusion +
                         # batched top-k as ONE program (search/hybrid.py)
                         prog_name = "batch_hybrid_fused"
-                        out = hybrid_fused_topk_batch(ctx, exec_queries, kb)
+                        with span("device.dispatch", program=prog_name):
+                            out = hybrid_fused_topk_batch(ctx, exec_queries,
+                                                          kb)
                     elif all_knn:
                         # kNN/MaxSim tier: one fused per-token sweep +
                         # device dedup-by-max merge (same (vals, ids,
@@ -347,50 +355,60 @@ def execute_batch(svc, bodies: List[dict], queries: Optional[list] = None,
 
     responses = []
     for qi, body in enumerate(bodies):
-        t_resp = time.perf_counter()
-        frm, size = sizes[qi]
-        k_q = frm + size
-        # mirror the sequential path exactly: per-shard candidates order by
-        # (-score, seg_id, local) and truncate at k (query_phase), THEN the
-        # global merge orders by (-score, shard, local) (search_shards)
-        by_pos: Dict[int, list] = {}
-        for t in cands[qi]:
-            by_pos.setdefault(t[1], []).append(t)
-        lst: list = []
-        for pos in sorted(by_pos):
-            shard_lst = by_pos[pos]
-            shard_lst.sort(key=lambda t: (-t[0], t[2].seg_id, t[3]))
-            lst.extend(shard_lst[:k_q])
-        lst.sort(key=lambda t: (-t[0], t[1], t[3]))
-        page = [ShardDoc(pos, seg, local, val)
-                for val, pos, seg, local in lst[frm: frm + size]]
-        by_shard: Dict[int, List[ShardDoc]] = {}
-        for d in page:
-            by_shard.setdefault(d.shard_ord, []).append(d)
-        hits: List[dict] = []
-        fetched: List[ShardDoc] = []
-        for pos in sorted(by_shard):
-            tf = time.perf_counter()
-            hits.extend(searchers[pos].fetch_phase(by_shard[pos], body,
-                                                   svc.name))
-            searchers[pos].stats.on_fetch((time.perf_counter() - tf) * 1000)
-            fetched.extend(by_shard[pos])
-        order = {id(d): i for i, d in enumerate(page)}
-        hd = sorted(zip(hits, fetched), key=lambda x: order[id(x[1])])
-        responses.append({
-            # this request's cost: the shared query phase + its own fetch
-            # (NOT the cumulative fetch time of earlier batch members)
-            "took": int(q_ms + (time.perf_counter() - t_resp) * 1000),
-            "timed_out": False,
-            "_shards": {"total": len(searchers),
-                        "successful": len(searchers), "failed": 0},
-            "hits": {
-                "total": int(totals[qi]),
-                "max_score": lst[0][0] if lst else None,
-                "hits": [h for h, _ in hd],
-            },
-        })
+        with span("search.fetch"):
+            responses.append(_batch_response(
+                svc, searchers, body, sizes[qi], cands[qi],
+                int(totals[qi]), q_ms))
     return responses
+
+
+def _batch_response(svc, searchers, body: dict, frm_size: Tuple[int, int],
+                    cands: list, total: int, q_ms: float) -> dict:
+    """One request's response out of the batch's candidates: per-shard
+    then global merge, paging, fetch."""
+    t_resp = time.perf_counter()
+    frm, size = frm_size
+    k_q = frm + size
+    # mirror the sequential path exactly: per-shard candidates order by
+    # (-score, seg_id, local) and truncate at k (query_phase), THEN the
+    # global merge orders by (-score, shard, local) (search_shards)
+    by_pos: Dict[int, list] = {}
+    for t in cands:
+        by_pos.setdefault(t[1], []).append(t)
+    lst: list = []
+    for pos in sorted(by_pos):
+        shard_lst = by_pos[pos]
+        shard_lst.sort(key=lambda t: (-t[0], t[2].seg_id, t[3]))
+        lst.extend(shard_lst[:k_q])
+    lst.sort(key=lambda t: (-t[0], t[1], t[3]))
+    page = [ShardDoc(pos, seg, local, val)
+            for val, pos, seg, local in lst[frm: frm + size]]
+    by_shard: Dict[int, List[ShardDoc]] = {}
+    for d in page:
+        by_shard.setdefault(d.shard_ord, []).append(d)
+    hits: List[dict] = []
+    fetched: List[ShardDoc] = []
+    for pos in sorted(by_shard):
+        tf = time.perf_counter()
+        hits.extend(searchers[pos].fetch_phase(by_shard[pos], body,
+                                               svc.name))
+        searchers[pos].stats.on_fetch((time.perf_counter() - tf) * 1000)
+        fetched.extend(by_shard[pos])
+    order = {id(d): i for i, d in enumerate(page)}
+    hd = sorted(zip(hits, fetched), key=lambda x: order[id(x[1])])
+    return {
+        # this request's cost: the shared query phase + its own fetch
+        # (NOT the cumulative fetch time of earlier batch members)
+        "took": int(q_ms + (time.perf_counter() - t_resp) * 1000),
+        "timed_out": False,
+        "_shards": {"total": len(searchers),
+                    "successful": len(searchers), "failed": 0},
+        "hits": {
+            "total": total,
+            "max_score": lst[0][0] if lst else None,
+            "hits": [h for h, _ in hd],
+        },
+    }
 
 
 def try_batched_msearch(svc, bodies: List[dict],

@@ -43,6 +43,7 @@ from elasticsearch_tpu.ops.scoring import (
 )
 from elasticsearch_tpu.search.context import SegmentContext
 from elasticsearch_tpu.search.scripting import compile_script
+from elasticsearch_tpu.tracing.tracer import span, tag_active
 from elasticsearch_tpu.utils.dates import parse_date
 from elasticsearch_tpu.utils.errors import QueryParsingException
 
@@ -108,42 +109,52 @@ def _score_term_group(ctx, field, terms, boost=1.0, with_counts=False) -> Tuple[
         return z, matched, 0
     from elasticsearch_tpu.monitor import kernels
 
-    terms, weights = _dedupe_terms(terms, boost, lambda t: ctx.idf(field, t))
-    all_positive = all(w > 0 for w in weights)
-    split = inv.postings_split()
+    with span("search.plan"):
+        terms, weights = _dedupe_terms(terms, boost,
+                                       lambda t: ctx.idf(field, t))
+        all_positive = all(w > 0 for w in weights)
+        split = inv.postings_split()
+        hyb = (ctx.hybrid_slices(inv, terms, weights, need_qw=False)
+               if split is None else None)
+        if split is None and hyb is None:
+            starts, lens, ws, P, n_present = ctx.chunked_slices(
+                inv, terms, weights)
     if split is not None:
         # oversized field: postings live across the device mesh; partial
         # scores/counts/masks psum-merge (parallel/postings_shard.py)
         kernels.record("bm25_postings_sharded")
-        return split.term_group(terms, weights, with_counts=with_counts,
-                                all_positive=all_positive, D=ctx.D)
-    hyb = ctx.hybrid_slices(inv, terms, weights, need_qw=False)
+        with span("device.dispatch", program="bm25_postings_sharded"):
+            return split.term_group(terms, weights, with_counts=with_counts,
+                                    all_positive=all_positive, D=ctx.D)
     kernels.record("bm25_hybrid" if hyb is not None else "bm25_scatter")
     if hyb is not None:
         impact, _qw, _qind, starts, lens, ws, P, n_present, qrows, qrw = hyb
         # single-query path: gather ONLY the query's dense rows — the
         # matmul form reads the whole impact block per query (ops/scoring
         # bm25_score_hybrid_gather docstring has the traffic math)
-        scores = bm25_score_hybrid_gather(
-            impact, qrows, qrw, inv.doc_ids, inv.tfnorm, starts, lens, ws,
-            P=P, D=ctx.D)
+        with span("device.dispatch", program="bm25_hybrid"):
+            scores = bm25_score_hybrid_gather(
+                impact, qrows, qrw, inv.doc_ids, inv.tfnorm, starts, lens,
+                ws, P=P, D=ctx.D)
+            if with_counts:
+                matched = match_count_hybrid_gather(
+                    impact, qrows, inv.doc_ids, starts, lens, P=P, D=ctx.D)
+            elif all_positive:
+                matched = scores > 0
+            else:
+                matched = term_mask_hybrid_gather(
+                    impact, qrows, inv.doc_ids, starts, lens, P=P, D=ctx.D)
+        return scores, matched, n_present
+    with span("device.dispatch", program="bm25_scatter"):
+        scores = bm25_score_segment(inv.doc_ids, inv.tfnorm, starts, lens,
+                                    ws, P=P, D=ctx.D)
         if with_counts:
-            matched = match_count_hybrid_gather(
-                impact, qrows, inv.doc_ids, starts, lens, P=P, D=ctx.D)
+            matched = match_count_segment(inv.doc_ids, starts, lens, P=P,
+                                          D=ctx.D)
         elif all_positive:
             matched = scores > 0
         else:
-            matched = term_mask_hybrid_gather(
-                impact, qrows, inv.doc_ids, starts, lens, P=P, D=ctx.D)
-        return scores, matched, n_present
-    starts, lens, ws, P, n_present = ctx.chunked_slices(inv, terms, weights)
-    scores = bm25_score_segment(inv.doc_ids, inv.tfnorm, starts, lens, ws, P=P, D=ctx.D)
-    if with_counts:
-        matched = match_count_segment(inv.doc_ids, starts, lens, P=P, D=ctx.D)
-    elif all_positive:
-        matched = scores > 0
-    else:
-        matched = term_mask(inv.doc_ids, starts, lens, P=P, D=ctx.D)
+            matched = term_mask(inv.doc_ids, starts, lens, P=P, D=ctx.D)
     return scores, matched, n_present
 
 
@@ -160,14 +171,15 @@ def fused_bm25_topk(ctx, query, k: int):
     the generic score/mask path. Scores match bm25_score_hybrid's dense
     branch exactly (same matmul); non-matches carry score <= 0.
     """
-    e = _fused_eligible_terms(ctx, query)
-    if e is None:
-        return None
-    field, (tlist, wlist) = e
-    inv = ctx.inv(field)
-    if inv is None:
-        return None
-    hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
+    with span("search.plan"):
+        e = _fused_eligible_terms(ctx, query)
+        if e is None:
+            return None
+        field, (tlist, wlist) = e
+        inv = ctx.inv(field)
+        if inv is None:
+            return None
+        hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
     if hyb is None:
         return None  # no dense block / no dense query term
     impact, _qw, _qind, _starts, lens, _ws, _P, n_present, qrows, qrw = hyb
@@ -187,14 +199,18 @@ def fused_bm25_topk(ctx, query, k: int):
     # full block would cost an F-row HBM read per query (same traffic cut
     # as bm25_score_hybrid_gather; the [R, D] gather is a one-off
     # intermediate two orders smaller than the block)
-    sub, qvalid = gather_impact_rows(impact, jnp.asarray(qrows))
-    vals, ids = bm25_dense_topk_auto(jnp.asarray(qrw[None, :]), sub, live,
-                                     k=kk)
-    kernels.record("bm25_fused_topk")
-    total = dense_presence_count(sub, qvalid[None, :], live)
+    with span("device.dispatch", program="bm25_fused_topk"):
+        sub, qvalid = gather_impact_rows(impact, jnp.asarray(qrows))
+        vals, ids = bm25_dense_topk_auto(jnp.asarray(qrw[None, :]), sub,
+                                         live, k=kk)
+        kernels.record("bm25_fused_topk")
+        total = dense_presence_count(sub, qvalid[None, :], live)
+        packed_dev = pack_topk_result(vals[0], ids[0], total)
     # ONE packed pull — three tiny arrays would cost three device
     # round-trips (network-attached chips: ~5-20 ms each)
-    packed = np.asarray(pack_topk_result(vals[0], ids[0], total))
+    with span("device.wait"):
+        packed = np.asarray(packed_dev)
+        tag_active(bytes=packed.nbytes)
     return unpack_topk_result(packed, kk)
 
 
@@ -254,6 +270,35 @@ def fused_bm25_topk_batch(ctx, queries: List[Query], k: int):
     is the product path behind `_msearch` batching — the per-query
     equivalent of fused_bm25_topk, amortizing dispatch across the batch.
     """
+    with span("search.plan"):
+        planned = _plan_fused_batch(ctx, queries)
+    if planned is None:
+        return None
+    impact, qw, qind = planned
+    Q = len(queries)
+    from elasticsearch_tpu.monitor import kernels
+    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
+    from elasticsearch_tpu.ops.scoring import dense_presence_count_batch
+
+    jnp = _jnp()
+    live = ctx.segment.live
+    D = ctx.D
+    with span("device.dispatch", program="batch_bm25_fused"):
+        vals, ids = bm25_dense_topk_auto(jnp.asarray(qw), impact, live,
+                                         k=min(k, D))
+        kernels.record("bm25_fused_topk", Q)
+        chunk = D if D < (1 << 15) else (1 << 15)
+        totals = _tier_program("batch_presence_count",
+                               dense_presence_count_batch)(
+            impact, jnp.asarray(qind), live, chunk=chunk)
+    with span("device.wait"):
+        return np.asarray(vals), np.asarray(ids), np.asarray(totals)
+
+
+def _plan_fused_batch(ctx, queries: List[Query]):
+    """Host half of :func:`fused_bm25_topk_batch`: (impact, qw[Q, F],
+    qind[Q, F]) when every query is a pure-dense term group on one
+    field, else None."""
     field = None
     rows = []
     for q in queries:
@@ -285,21 +330,7 @@ def fused_bm25_topk_batch(ctx, queries: List[Query], k: int):
             qind = np.zeros((Q, row_qw.shape[0]), np.float32)
         qw[qi] = row_qw
         qind[qi] = row_qind
-    from elasticsearch_tpu.monitor import kernels
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
-    from elasticsearch_tpu.ops.scoring import dense_presence_count_batch
-
-    jnp = _jnp()
-    live = ctx.segment.live
-    D = ctx.D
-    vals, ids = bm25_dense_topk_auto(jnp.asarray(qw), impact, live,
-                                     k=min(k, D))
-    kernels.record("bm25_fused_topk", Q)
-    chunk = D if D < (1 << 15) else (1 << 15)
-    totals = _tier_program("batch_presence_count",
-                           dense_presence_count_batch)(
-        impact, jnp.asarray(qind), live, chunk=chunk)
-    return np.asarray(vals), np.asarray(ids), np.asarray(totals)
+    return impact, qw, qind
 
 
 def hybrid_bm25_topk_batch(ctx, queries: List[Query], k: int,
@@ -313,6 +344,75 @@ def hybrid_bm25_topk_batch(ctx, queries: List[Query], k: int,
 
     Returns (vals [Q, k], ids [Q, k], totals [Q]) or None (caller falls
     back to sequential execution). Counter: bm25_hybrid per query."""
+    with span("search.plan"):
+        planned = _plan_hybrid_batch(ctx, queries)
+    if planned is None:
+        return None
+    inv, impact, qw, starts, lens, ws, P = planned
+    Q = len(queries)
+    from elasticsearch_tpu.monitor import kernels
+    from elasticsearch_tpu.ops.scoring import (
+        bm25_hybrid_candidates_topk_batch, bm25_hybrid_topk_batch,
+        tail_mode_batch)
+
+    jnp = _jnp()
+    live = ctx.segment.live
+    kk = min(k, ctx.D)
+    from elasticsearch_tpu.ops.scoring import (impact_precision,
+                                               topk_block_config)
+
+    blk = topk_block_config()  # once per batch: every chunk must compile
+    # against the SAME static block even if the env flips mid-batch
+    _prec = impact_precision()
+    # tail dispatch, once per batch: the scatter-free candidate form on
+    # TPU (the vmapped scatter serializes Q·T·P slots), scatter elsewhere
+    scatter_free = tail_mode_batch()
+    batch_fn = (_tier_program("batch_bm25_hybrid_cand",
+                              bm25_hybrid_candidates_topk_batch)
+                if scatter_free
+                else _tier_program("batch_bm25_hybrid",
+                                   bm25_hybrid_topk_batch))
+    def run_chunk(fn, q0, q1):
+        with span("device.dispatch", program="batch_bm25_hybrid"):
+            got = fn(
+                impact, jnp.asarray(qw[q0:q1]), inv.doc_ids, inv.tfnorm,
+                jnp.asarray(starts[q0:q1]), jnp.asarray(lens[q0:q1]),
+                jnp.asarray(ws[q0:q1]), live, P=P, D=ctx.D, k=kk,
+                topk_block=blk, prec=_prec)
+        with span("device.wait"):
+            return tuple(np.asarray(a) for a in got)
+
+    out_v, out_i, out_t = [], [], []
+    for q0 in range(0, Q, chunk_q):
+        q1 = min(q0 + chunk_q, Q)
+        try:
+            # the pull is INSIDE the insurance try: async dispatch can
+            # surface a device execution error only at the host pull
+            # (the executor's device_get-in-try discipline) — it must
+            # trigger the same scatter fallback as an eager failure
+            vals, ids, tot = run_chunk(batch_fn, q0, q1)
+        except Exception:
+            if not scatter_free:
+                raise
+            # candidates-form insurance (first real-TPU run): fall back
+            # to the scatter form for this and remaining chunks
+            kernels.record("tail_scatter_free_failed")
+            scatter_free = False
+            batch_fn = _tier_program("batch_bm25_hybrid",
+                                     bm25_hybrid_topk_batch)
+            vals, ids, tot = run_chunk(batch_fn, q0, q1)
+        out_v.append(vals)
+        out_i.append(ids)
+        out_t.append(tot)
+    kernels.record("bm25_hybrid", Q)
+    return (np.concatenate(out_v), np.concatenate(out_i),
+            np.concatenate(out_t))
+
+
+def _plan_hybrid_batch(ctx, queries: List[Query]):
+    """Host half of :func:`hybrid_bm25_topk_batch`: (inv, impact,
+    qw[Q, F], starts[Q, T], lens, ws, P) when every query is a
+    same-field term group with a dense block, else None."""
     field = None
     rows = []
     for q in queries:
@@ -350,65 +450,7 @@ def hybrid_bm25_topk_batch(ctx, queries: List[Query], k: int,
         starts[qi, : st.shape[0]] = st
         lens[qi, : ln.shape[0]] = ln
         ws[qi, : w.shape[0]] = w
-    from elasticsearch_tpu.monitor import kernels
-    from elasticsearch_tpu.ops.scoring import (
-        bm25_hybrid_candidates_topk_batch, bm25_hybrid_topk_batch,
-        tail_mode_batch)
-
-    jnp = _jnp()
-    live = ctx.segment.live
-    kk = min(k, ctx.D)
-    from elasticsearch_tpu.ops.scoring import (impact_precision,
-                                               topk_block_config)
-
-    blk = topk_block_config()  # once per batch: every chunk must compile
-    # against the SAME static block even if the env flips mid-batch
-    _prec = impact_precision()
-    # tail dispatch, once per batch: the scatter-free candidate form on
-    # TPU (the vmapped scatter serializes Q·T·P slots), scatter elsewhere
-    scatter_free = tail_mode_batch()
-    batch_fn = (_tier_program("batch_bm25_hybrid_cand",
-                              bm25_hybrid_candidates_topk_batch)
-                if scatter_free
-                else _tier_program("batch_bm25_hybrid",
-                                   bm25_hybrid_topk_batch))
-    out_v, out_i, out_t = [], [], []
-    for q0 in range(0, Q, chunk_q):
-        q1 = min(q0 + chunk_q, Q)
-        try:
-            vals, ids, tot = batch_fn(
-                impact, jnp.asarray(qw[q0:q1]), inv.doc_ids, inv.tfnorm,
-                jnp.asarray(starts[q0:q1]), jnp.asarray(lens[q0:q1]),
-                jnp.asarray(ws[q0:q1]), live, P=P, D=ctx.D, k=kk,
-                topk_block=blk, prec=_prec)
-            # materialize INSIDE the insurance try: async dispatch can
-            # surface a device execution error only at this host pull
-            # (the executor's device_get-in-try discipline) — it must
-            # trigger the same scatter fallback as an eager failure
-            vals, ids, tot = (np.asarray(vals), np.asarray(ids),
-                              np.asarray(tot))
-        except Exception:
-            if not scatter_free:
-                raise
-            # candidates-form insurance (first real-TPU run): fall back
-            # to the scatter form for this and remaining chunks
-            kernels.record("tail_scatter_free_failed")
-            scatter_free = False
-            batch_fn = _tier_program("batch_bm25_hybrid",
-                                     bm25_hybrid_topk_batch)
-            vals, ids, tot = batch_fn(
-                impact, jnp.asarray(qw[q0:q1]), inv.doc_ids, inv.tfnorm,
-                jnp.asarray(starts[q0:q1]), jnp.asarray(lens[q0:q1]),
-                jnp.asarray(ws[q0:q1]), live, P=P, D=ctx.D, k=kk,
-                topk_block=blk, prec=_prec)
-            vals, ids, tot = (np.asarray(vals), np.asarray(ids),
-                              np.asarray(tot))
-        out_v.append(vals)
-        out_i.append(ids)
-        out_t.append(tot)
-    kernels.record("bm25_hybrid", Q)
-    return (np.concatenate(out_v), np.concatenate(out_i),
-            np.concatenate(out_t))
+    return inv, impact, qw, starts, lens, ws, P
 
 
 def _terms_filter_mask(ctx, field, terms):

@@ -108,16 +108,29 @@ class ShardSearcher:
         # their cost is the snapshot, not the phases.
         from contextlib import nullcontext
 
+        from elasticsearch_tpu.tracing.tracer import span, tag_active
+
         prof = None
         if body.get("profile") and not collect_full:
             from elasticsearch_tpu.tracing.profiler import PhaseTimer
 
             prof = PhaseTimer()
 
-        def _p(name: str):
-            return prof.phase(name) if prof is not None else nullcontext()
+        def _p(span_name: Optional[str], phase: Optional[str] = None,
+               **tags):
+            """The tracer span of a boundary and, under ?profile=true,
+            the same duration filed under the profile's phase: one set
+            of clocks. A phase with no span (aggs, rerank) keeps the
+            timer's own."""
+            if span_name is None:
+                return prof.phase(phase) if prof is not None \
+                    else nullcontext()
+            sp = span(span_name, **tags)
+            if prof is None or phase is None:
+                return sp
+            return prof.span_phase(sp, phase)
 
-        with _p("rewrite"):
+        with _p("search.rewrite", "rewrite"):
             query = parse_query(body.get("query"))
             prepare_tree(query, self.segments, self.mappings, self.analysis,
                          global_stats)
@@ -197,7 +210,7 @@ class ShardSearcher:
                 if terminate_after is not None and total >= terminate_after:
                     terminated_early = True
                     break
-                with _p("executor_build"):
+                with _p("search.plan", "executor_build"):
                     ctx = SegmentContext(seg, self.mappings, self.analysis,
                                          global_stats,
                                          all_segments=self.segments,
@@ -256,22 +269,25 @@ class ShardSearcher:
                         lambda: query.score_or_mask(ctx))
                 else:
                     scores, mask = query.score_or_mask(ctx)
-                mask = mask & seg.live
-                if seg.has_nested:
-                    # top-level hits are root docs only; nested children are
-                    # reachable solely through nested queries/aggs (reference:
-                    # Lucene block-join — nested docs hidden from root searches)
-                    mask = mask & seg.roots_dev
-                if min_score is not None:
-                    mask = mask & (scores >= float(min_score))
-                tot_dev = jnp.sum(mask.astype(jnp.int32))
+                # eager mask ops: each one its own enqueue
+                with _p("device.dispatch", program="mask_ops"):
+                    mask = mask & seg.live
+                    if seg.has_nested:
+                        # top-level hits are root docs only; nested children
+                        # are reachable solely through nested queries/aggs
+                        # (reference: Lucene block-join — nested docs hidden
+                        # from root searches)
+                        mask = mask & seg.roots_dev
+                    if min_score is not None:
+                        mask = mask & (scores >= float(min_score))
+                    tot_dev = jnp.sum(mask.astype(jnp.int32))
                 if aggs:
-                    with _p("aggs"):
+                    with _p(None, "aggs"):
                         agg_partials.append(run_aggs(aggs, ctx, mask))
                 if sort_spec:
                     total += int(tot_dev)
                     seg_k = seg.max_docs if collect_full else k
-                    with _p("topk"):
+                    with _p(None, "topk"):
                         seg_docs = self._sorted_candidates(ctx, scores, mask,
                                                            sort_spec, seg_k,
                                                            search_after)
@@ -300,20 +316,21 @@ class ShardSearcher:
                         pack_topk_result, unpack_topk_result)
 
                     kk = min(k, seg.max_docs)
-                    if prof is not None:
-                        vals, idx = prof.device_call(
-                            lambda: topk_with_mask(scores, mask, k=kk),
-                            bucket="topk")
-                        packed_dev = prof.device_call(
-                            lambda: pack_topk_result(vals, idx, tot_dev))
-                        with prof.phase("host_sync"):
-                            packed = np.asarray(packed_dev)
-                    else:
-                        vals, idx = topk_with_mask(scores, mask, k=kk)
-                        # ONE host transfer: per-array pulls each pay a fixed
-                        # device round-trip (network-attached chips: ~5-20 ms)
-                        packed = np.asarray(pack_topk_result(vals, idx,
-                                                             tot_dev))
+                    with _p("device.dispatch", program="topk_with_mask"):
+                        if prof is not None:
+                            vals, idx = prof.device_call(
+                                lambda: topk_with_mask(scores, mask, k=kk),
+                                bucket="topk")
+                            packed_dev = prof.device_call(
+                                lambda: pack_topk_result(vals, idx, tot_dev))
+                        else:
+                            vals, idx = topk_with_mask(scores, mask, k=kk)
+                            packed_dev = pack_topk_result(vals, idx, tot_dev)
+                    # ONE host transfer: per-array pulls each pay a fixed
+                    # device round-trip (network-attached chips: ~5-20 ms)
+                    with _p("device.wait", "host_sync"):
+                        packed = np.asarray(packed_dev)
+                        tag_active(bytes=packed.nbytes)
                     vals, idx, tot = unpack_topk_result(packed, kk)
                     total += tot
                     seg_docs = [
@@ -341,7 +358,7 @@ class ShardSearcher:
             # stage-1 score untouched (apply_hybrid_rerank catches it).
             from elasticsearch_tpu.search.hybrid import apply_hybrid_rerank
 
-            with _p("rerank"):
+            with _p(None, "rerank"):
                 hybrid_status = apply_hybrid_rerank(
                     docs, query, self.mappings, self.analysis)
             max_score = max((d.score for d in docs
@@ -771,35 +788,45 @@ def search_shards(
     else:
         page = all_docs[frm : frm + size]
 
-    by_shard: Dict[int, List[ShardDoc]] = {}
-    for d in page:
-        by_shard.setdefault(d.shard_ord, []).append(d)
-    hits: List[dict] = []
-    for shard_ord, docs in by_shard.items():
-        tf = time.perf_counter()
-        hits.extend(searchers[shard_ord].fetch_phase(docs, body, index_name))
-        f_ms = (time.perf_counter() - tf) * 1000
-        searchers[shard_ord].stats.on_fetch(f_ms, groups=body.get("stats"))
-        if profile and shard_ord < len(shard_profiles):
-            shard_profiles[shard_ord]["fetch"] = {"time_in_nanos": int(f_ms * 1e6)}
-    # restore global order after per-shard fetch
-    order = {(d.shard_ord, id(d.seg), d.local_id): i for i, d in enumerate(page)}
-    hits_docs = list(zip(hits, [d for docs in by_shard.values() for d in docs]))
-    hits_docs.sort(key=lambda hd: order[(hd[1].shard_ord, id(hd[1].seg), hd[1].local_id)])
-    hits = [h for h, _ in hits_docs]
+    from elasticsearch_tpu.tracing.tracer import span
 
-    response: Dict[str, Any] = {
-        "took": int((time.perf_counter() - t0) * 1000),
-        "timed_out": any(r.timed_out for r in results),
-        "_shards": {"total": len(searchers),
-                    "successful": len(searchers) - len(shard_failures),
-                    "failed": len(shard_failures)},
-        "hits": {
-            "total": total,
-            "max_score": None if (max_score == float("-inf") or sort_spec) else max_score,
-            "hits": hits,
-        },
-    }
+    with span("search.fetch"):
+        by_shard: Dict[int, List[ShardDoc]] = {}
+        for d in page:
+            by_shard.setdefault(d.shard_ord, []).append(d)
+        hits: List[dict] = []
+        for shard_ord, docs in by_shard.items():
+            tf = time.perf_counter()
+            hits.extend(searchers[shard_ord].fetch_phase(docs, body,
+                                                         index_name))
+            f_ms = (time.perf_counter() - tf) * 1000
+            searchers[shard_ord].stats.on_fetch(f_ms,
+                                                groups=body.get("stats"))
+            if profile and shard_ord < len(shard_profiles):
+                shard_profiles[shard_ord]["fetch"] = {
+                    "time_in_nanos": int(f_ms * 1e6)}
+        # restore global order after per-shard fetch
+        order = {(d.shard_ord, id(d.seg), d.local_id): i
+                 for i, d in enumerate(page)}
+        hits_docs = list(zip(hits, [d for docs in by_shard.values()
+                                    for d in docs]))
+        hits_docs.sort(key=lambda hd: order[(hd[1].shard_ord, id(hd[1].seg),
+                                             hd[1].local_id)])
+        hits = [h for h, _ in hits_docs]
+
+        response: Dict[str, Any] = {
+            "took": int((time.perf_counter() - t0) * 1000),
+            "timed_out": any(r.timed_out for r in results),
+            "_shards": {"total": len(searchers),
+                        "successful": len(searchers) - len(shard_failures),
+                        "failed": len(shard_failures)},
+            "hits": {
+                "total": total,
+                "max_score": (None if (max_score == float("-inf")
+                                       or sort_spec) else max_score),
+                "hits": hits,
+            },
+        }
     if shard_failures:
         response["_shards"]["failures"] = shard_failures
     # hybrid stage-2 status: a breaker decline on ANY shard marks the whole

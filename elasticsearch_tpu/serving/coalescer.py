@@ -35,8 +35,13 @@ Drain policy (adaptive):
 
 Integration with the production substrate (PRs 3–7):
 
-- queue-wait is a ``serving.queue_wait`` tracer span (child of the REST
-  search span), and a ``coalescer`` section under ``?profile=true``;
+- a parked request's time is two tracer spans on its own thread,
+  children of its ``search`` span: ``serving.queue_wait`` (park → claim)
+  and ``serving.batch_wait`` (claim → done); the drain thread's fused
+  execution is a ``serving.batch`` span, a root of its own that names
+  its first member's trace (its members' ``batch_wait`` already covers
+  the same interval); plus a ``coalescer`` section under
+  ``?profile=true``;
 - every parked request registers a *pending* TaskRegistry child task —
   ``POST /_tasks/{id}/_cancel`` evicts it from the queue before it ever
   reaches the device;
@@ -60,6 +65,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from elasticsearch_tpu.tracing.tracer import current_context, span
+
 #: body keys a parked request may carry; `profile` parks too (its queue
 #: wait must be attributed honestly) but executes sequentially at flush
 PARK_KEYS = frozenset({"query", "size", "from", "_source", "profile"})
@@ -74,7 +81,7 @@ class _Entry:
 
     __slots__ = ("svc", "body", "query", "claimed", "done",
                  "result", "error", "task", "enqueued", "claimed_at",
-                 "batch_size", "flush_reason")
+                 "batch_size", "flush_reason", "trace_id")
 
     def __init__(self, svc, body: dict, query):
         self.svc = svc
@@ -89,6 +96,9 @@ class _Entry:
         self.claimed_at: Optional[float] = None
         self.batch_size = 0
         self.flush_reason = ""
+        # the parking request's trace, for the batch span that serves it
+        ctx = current_context()
+        self.trace_id = ctx.trace_id if ctx is not None else ""
 
     def resolve(self, result: Any = None,
                 error: Optional[BaseException] = None) -> None:
@@ -226,10 +236,12 @@ class QueryCoalescer:
         from elasticsearch_tpu.search.queries import parse_query
 
         try:
-            query = parse_query(body.get("query"))
+            with span("search.rewrite"):
+                query = parse_query(body.get("query"))
         except Exception:
             return None  # the normal path reports the typed error
-        field = batch_field(svc, query)
+        with span("search.plan"):
+            field = batch_field(svc, query)
         if field is None:
             return None
         return _Entry(svc, body, query), field
@@ -255,9 +267,9 @@ class QueryCoalescer:
                     q.append(entry)
                     self._ensure_thread()
                     self._cv.notify_all()
-            # queue wait as a span: child of the REST search span (same
-            # thread of execution), closed at CLAIM — execution time is
-            # the executor's, not the queue's
+            # queue wait as a span: child of the request's search span
+            # (same thread of execution), closed at CLAIM — what follows
+            # is the batch's execution, waited out under batch_wait
             with self.node.tracer.span("serving.queue_wait",
                                        index=entry.svc.name, bucket=field):
                 while not entry.claimed.wait(timeout=0.05):
@@ -266,8 +278,9 @@ class QueryCoalescer:
                                 or not self._thread.is_alive())
                     if dead and self._reclaim(entry, key):
                         break
-            while not entry.done.wait(timeout=0.05):
-                pass
+            with self.node.tracer.span("serving.batch_wait"):
+                while not entry.done.wait(timeout=0.05):
+                    pass
             queue_s = ((entry.claimed_at or entry.enqueued)
                        - entry.enqueued)
             self._m_wait.observe(queue_s)
@@ -460,9 +473,13 @@ class QueryCoalescer:
         if len(fused) >= 2:
             svc = fused[0].svc
             try:
-                responses = execute_batch(
-                    svc, [e.body for e in fused],
-                    queries=[e.query for e in fused], pad_pow2=True)
+                with self.node.tracer.span(
+                        "serving.batch", batch_size=len(fused),
+                        flush_reason=reason,
+                        first_trace_id=fused[0].trace_id):
+                    responses = execute_batch(
+                        svc, [e.body for e in fused],
+                        queries=[e.query for e in fused], pad_pow2=True)
             except Exception:
                 responses = None  # sequential fallback below
                 self._m_bypass.labels("batch_error").inc()

@@ -3,8 +3,12 @@
 One substrate, four consumers:
 
 - ``tracer``   — monotonic-clock spans with parent/child links,
-                 contextvar propagation, Chrome-trace dump
-                 (``GET /_nodes/_local/trace``).
+                 self time and thread CPU, contextvar propagation
+                 (pool workers included), a profiler annotation per
+                 open span, Chrome-trace dump
+                 (``GET /_nodes/_local/trace``). Deep layers open
+                 spans with the module-level :func:`span`, which is a
+                 shared no-op when the flow has no active span.
 - ``tasks``    — node-level task registry with cooperative cancellation
                  and cross-node parent links (``GET/POST /_tasks``).
 - ``profiler`` — ``?profile=true`` per-shard phase timings splitting
@@ -30,10 +34,10 @@ from elasticsearch_tpu.tracing import tracer as _tracer
 from elasticsearch_tpu.tracing.tasks import (TaskCancelledException,
                                              TaskRegistry, check_cancelled,
                                              current_task)
-from elasticsearch_tpu.tracing.tracer import Span, Tracer
+from elasticsearch_tpu.tracing.tracer import Span, Tracer, span
 
 __all__ = [
-    "Tracer", "Span", "TaskRegistry", "TaskCancelledException",
+    "Tracer", "Span", "span", "TaskRegistry", "TaskCancelledException",
     "check_cancelled", "current_task", "wire_context",
     "adopt_wire_context",
 ]

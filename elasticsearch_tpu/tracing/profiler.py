@@ -39,6 +39,7 @@ from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, Iterator, Optional
 
 from elasticsearch_tpu.tracing import retrace
+from elasticsearch_tpu.tracing.tracer import NOOP
 
 PHASES = ("rewrite", "executor_build", "device_compile", "device_execute",
           "topk", "host_sync", "aggs", "rehydrate", "fuse", "rerank")
@@ -108,6 +109,23 @@ class PhaseTimer:
         finally:
             self.nanos[name] = self.nanos.get(name, 0) + int(
                 (time.perf_counter() - t0) * 1e9)
+
+    @contextmanager
+    def span_phase(self, sp, name: str) -> Iterator[None]:
+        """Open the tracer span ``sp`` and file ITS duration under phase
+        ``name``: one pair of clock reads serves the span and the
+        profile. With the no-op span (no tracer on this flow) the timer
+        reads its own clock."""
+        if sp is NOOP:
+            with self.phase(name):
+                yield
+            return
+        try:
+            with sp:
+                yield
+        finally:
+            self.nanos[name] = self.nanos.get(name, 0) + int(
+                sp.duration * 1e9)
 
     def device_call(self, fn: Callable[[], Any],
                     bucket: Optional[str] = None) -> Any:

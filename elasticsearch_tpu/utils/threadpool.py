@@ -16,13 +16,20 @@ CONCURRENT REQUESTS (host prep + dispatch), not device occupancy.
 """
 from __future__ import annotations
 
+import contextvars
 import os
 import queue
 import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
+from elasticsearch_tpu.tracing import tracer
 from elasticsearch_tpu.utils.errors import ElasticsearchTpuException
+
+#: the span a submitter files for the time its work sat in the queue
+#: (REST dispatch is the only submitter: the wait is the head of
+#: ``rest.request``, before the handler's spans open on the worker)
+POOL_WAIT_SPAN = "rest.pool_wait"
 
 
 class EsRejectedExecutionException(ElasticsearchTpuException):
@@ -32,7 +39,7 @@ class EsRejectedExecutionException(ElasticsearchTpuException):
 
 class _Work:
     __slots__ = ("fn", "args", "kwargs", "done", "result", "error",
-                 "enqueued")
+                 "enqueued", "claimed", "ctx")
 
     def __init__(self, fn, args, kwargs):
         self.fn = fn
@@ -41,10 +48,16 @@ class _Work:
         self.done = threading.Event()
         self.result: Any = None
         self.error: Optional[BaseException] = None
-        # monotonic enqueue time: the watchdog's starvation detector
+        # the submitter's context rides with the work: the active span
+        # and task opened on the connection thread parent what the
+        # worker opens (tracing/tracer.py, tracing/tasks.py)
+        self.ctx = contextvars.copy_context()
+        # perf_counter enqueue time: the watchdog's starvation detector
         # reads queue AGE (how long the head has waited), which queue
-        # depth alone can't distinguish from a healthy burst
-        self.enqueued = time.monotonic()
+        # depth alone can't distinguish from a healthy burst; the
+        # worker stamps ``claimed`` and the two bound the pool-wait span
+        self.enqueued = time.perf_counter()
+        self.claimed: Optional[float] = None
 
 
 class FixedThreadPool:
@@ -75,11 +88,13 @@ class FixedThreadPool:
             work = self._q.get()
             if work is None:  # shutdown sentinel
                 return
+            work.claimed = time.perf_counter()
             with self._lock:
                 self.active += 1
                 self.largest = max(self.largest, self.active)
             try:
-                work.result = work.fn(*work.args, **work.kwargs)
+                work.result = work.ctx.run(work.fn, *work.args,
+                                           **work.kwargs)
             except BaseException as e:  # delivered to the submitter
                 work.error = e
             finally:
@@ -109,6 +124,10 @@ class FixedThreadPool:
                     f"rejected execution on thread pool [{self.name}] "
                     f"(queue capacity {self.queue_size})")
         work.done.wait()
+        # filed after the fact, on the submitter's thread: no ``with``
+        # block can straddle the hop to the worker
+        tracer.record(POOL_WAIT_SPAN, work.enqueued,
+                      work.claimed - work.enqueued, pool=self.name)
         if work.error is not None:
             raise work.error
         return work.result
@@ -124,7 +143,7 @@ class FixedThreadPool:
         t0 = getattr(head, "enqueued", None)
         if t0 is None:
             return None
-        return time.monotonic() - t0
+        return time.perf_counter() - t0
 
     def stats(self) -> dict:
         with self._lock:
