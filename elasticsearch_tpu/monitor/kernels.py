@@ -13,6 +13,11 @@ Names:
   bm25_scatter        pure scatter-add postings scoring (host or mesh)
   bm25_hybrid         dense-impact MXU matmul + scatter tail
   bm25_fused_topk     Pallas streaming dense top-k (no [Q, D] intermediate)
+  bm25_one_program    a host-loop search segment whose term group was scored,
+                      masked, counted, top-k'd and packed by ONE program fed
+                      by ONE packed argument (ops/scoring.
+                      bm25_term_group_topk); counted BESIDE the
+                      bm25_hybrid / bm25_scatter count of the same segment
   bm25_postings_sharded  oversized field scored via the cross-device
                       postings split + psum merge (parallel/postings_shard)
   knn_fused_topk      fused scores+mask+topk (Pallas on TPU, XLA elsewhere);

@@ -753,6 +753,75 @@ def unpack_topk_result(packed_np, k: int):
 
 
 # ---------------------------------------------------------------------------
+# one program a search segment (the host loop's query phase)
+# ---------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("k", "topk_block", "with_mask"))
+def finish_topk(scores, mask, live, roots=None, min_score=None, *, k: int,
+                topk_block: int, with_mask: bool = False):
+    """Everything the query phase does after a query's score program, as
+    ONE program: ``mask & live (& roots) (& scores >= min_score)`` →
+    exact total → masked top-k → the packed i32[2k+1] of
+    :func:`pack_topk_result`. Run eagerly these are five or six enqueues
+    a segment, each leaving and re-taking the interpreter's lock.
+
+    ``roots`` (bool[D], a segment with nested documents) and
+    ``min_score`` (f32 scalar) are None when the request has none; being
+    there or not is part of the program's key, their values are not.
+    Returns (packed, final mask when ``with_mask`` — aggregations collect
+    over it — else None). Composed of the staged jits themselves, so
+    values, indices and tie order are theirs bit for bit."""
+    mask = mask & live
+    if roots is not None:
+        mask = mask & roots
+    if min_score is not None:
+        mask = mask & (scores >= min_score)
+    total = jnp.sum(mask.astype(jnp.int32))
+    vals, idx = _topk_with_mask_jit(scores, mask, k=k, topk_block=topk_block)
+    return pack_topk_result(vals, idx, total), (mask if with_mask else None)
+
+
+def pack_term_group_words(qrows, qrw, starts, lens, ws):
+    """A term group's small host tables as ONE i32 word buffer
+    ``qrows | qrw | starts | lens | ws`` (floats reinterpreted, all
+    4-byte): one host→device copy a search where five numpy arguments
+    were five. ``qrows``/``qrw`` are None for a scatter-only group.
+    :func:`bm25_term_group_topk` slices it back at static offsets."""
+    parts = [starts, lens, ws.view(np.int32)]
+    if qrows is not None:
+        parts = [qrows, qrw.view(np.int32)] + parts
+    return np.concatenate(parts)
+
+
+@partial(jax.jit, static_argnames=("R", "T", "P", "D", "k", "topk_block"))
+def bm25_term_group_topk(dense_impact, doc_ids, tfnorm, live, roots, words,
+                         *, R: int, T: int, P: int, D: int, k: int,
+                         topk_block: int):
+    """A pure disjunctive term group, scored and finished in ONE program
+    fed by ONE packed argument: ``finish_topk ∘ score``.
+
+    ``words`` i32[2R + 3T] is :func:`pack_term_group_words`' buffer;
+    ``R`` > 0 (with ``dense_impact``) scores through
+    :func:`bm25_score_hybrid_gather`, ``R`` = 0 (``dense_impact`` None)
+    through :func:`bm25_score_segment`; with all-positive weights
+    ``scores > 0`` is the match mask. The same traced functions in the
+    same order as the staged sequence, so every score and tie is the
+    same. Returns the packed i32[2k+1]."""
+    f32 = partial(lax.bitcast_convert_type, new_dtype=jnp.float32)
+    tail = words[2 * R:]
+    starts, lens, ws = tail[:T], tail[T: 2 * T], f32(tail[2 * T:])
+    if R:
+        scores = bm25_score_hybrid_gather(
+            dense_impact, words[:R], f32(words[R: 2 * R]), doc_ids, tfnorm,
+            starts, lens, ws, P=P, D=D)
+    else:
+        scores = bm25_score_segment(doc_ids, tfnorm, starts, lens, ws,
+                                    P=P, D=D)
+    return finish_topk(scores, scores > 0, live, roots, k=k,
+                       topk_block=topk_block)[0]
+
+
+# ---------------------------------------------------------------------------
 # per-field segment reductions (aggregation building blocks)
 # ---------------------------------------------------------------------------
 

@@ -26,7 +26,7 @@ from __future__ import annotations
 import fnmatch
 import re
 from bisect import bisect_left
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -158,33 +158,60 @@ def _score_term_group(ctx, field, terms, boost=1.0, with_counts=False) -> Tuple[
     return scores, matched, n_present
 
 
-def fused_bm25_topk(ctx, query, k: int):
-    """Fused dense-impact BM25 top-k fast path (the Pallas streaming kernel
-    on TPU via ops.pallas_kernels.bm25_dense_topk_auto — no [Q, D] or [D]
-    score intermediate in HBM).
+class TermGroupPlan(NamedTuple):
+    """Host half of a plain search over a pure disjunctive term group on
+    one segment — analysis, term lookup and the slice tables — built ONCE
+    by :func:`plan_term_group` and handed to whichever single-program
+    path serves it."""
 
-    Eligible when the query is a pure disjunctive term group (match with
-    operator:or / term on a text field, positive boost) whose present terms
-    ALL map to dense impact rows — then top-k comes straight off the
-    impact[F, D] matmul and `hits.total` from one presence matvec.
-    Returns (vals f32[k], ids i32[k], total int) or None to fall through to
-    the generic score/mask path. Scores match bm25_score_hybrid's dense
-    branch exactly (same matmul); non-matches carry score <= 0.
-    """
+    inv: Any
+    impact: Any  # None (with qrows, qrw): no query term has a dense row
+    qrows: Any
+    qrw: Any
+    starts: Any
+    lens: Any
+    ws: Any
+    P: int
+    # every present term rides a dense row (no scatter tail): the shape
+    # fused_bm25_topk serves
+    all_dense: bool
+
+
+def plan_term_group(ctx, query) -> Optional[TermGroupPlan]:
+    """The plan when `query` is a pure disjunctive term group
+    (:func:`_fused_eligible_terms`) on a field this device holds whole,
+    else None (the caller runs the query tree)."""
     with span("search.plan"):
         e = _fused_eligible_terms(ctx, query)
         if e is None:
             return None
         field, (tlist, wlist) = e
         inv = ctx.inv(field)
-        if inv is None:
+        if inv is None or inv.postings_split() is not None:
             return None
         hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
-    if hyb is None:
-        return None  # no dense block / no dense query term
-    impact, _qw, _qind, _starts, lens, _ws, _P, n_present, qrows, qrw = hyb
-    if n_present == 0 or int(np.sum(lens)) > 0:
-        return None  # tail terms present — not a pure-dense group
+        if hyb is None:  # no dense block / no dense query term
+            starts, lens, ws, P, _n = ctx.chunked_slices(inv, tlist, wlist)
+            return TermGroupPlan(inv, None, None, None, starts, lens, ws, P,
+                                 False)
+        impact, _qw, _qind, starts, lens, ws, P, n_present, qrows, qrw = hyb
+        return TermGroupPlan(inv, impact, qrows, qrw, starts, lens, ws, P,
+                             n_present > 0 and int(np.sum(lens)) == 0)
+
+
+def fused_bm25_topk(ctx, plan: TermGroupPlan, k: int):
+    """Fused dense-impact BM25 top-k fast path (the Pallas streaming kernel
+    on TPU via ops.pallas_kernels.bm25_dense_topk_auto — no [Q, D] or [D]
+    score intermediate in HBM).
+
+    For an ``all_dense`` plan: a pure disjunctive term group (match with
+    operator:or / term on a text field, positive boost) whose present terms
+    ALL map to dense impact rows — then top-k comes straight off the
+    impact[F, D] matmul and `hits.total` from one presence matvec.
+    Returns (vals f32[k], ids i32[k], total int). Scores match
+    bm25_score_hybrid's dense branch exactly (same matmul); non-matches
+    carry score <= 0.
+    """
     from elasticsearch_tpu.monitor import kernels
     from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
 
@@ -200,8 +227,8 @@ def fused_bm25_topk(ctx, query, k: int):
     # as bm25_score_hybrid_gather; the [R, D] gather is a one-off
     # intermediate two orders smaller than the block)
     with span("device.dispatch", program="bm25_fused_topk"):
-        sub, qvalid = gather_impact_rows(impact, jnp.asarray(qrows))
-        vals, ids = bm25_dense_topk_auto(jnp.asarray(qrw[None, :]), sub,
+        sub, qvalid = gather_impact_rows(plan.impact, jnp.asarray(plan.qrows))
+        vals, ids = bm25_dense_topk_auto(jnp.asarray(plan.qrw[None, :]), sub,
                                          live, k=kk)
         kernels.record("bm25_fused_topk")
         total = dense_presence_count(sub, qvalid[None, :], live)
@@ -212,6 +239,34 @@ def fused_bm25_topk(ctx, query, k: int):
         packed = np.asarray(packed_dev)
         tag_active(bytes=packed.nbytes)
     return unpack_topk_result(packed, kk)
+
+
+def term_group_topk(ctx, plan: TermGroupPlan, k: int):
+    """Enqueue score, mask, count, top-k and pack of a planned term group
+    as ONE device program fed by ONE packed host argument
+    (ops.scoring.bm25_term_group_topk). Returns the packed device
+    i32[2k+1] for the caller's one pull; non-hits carry -inf."""
+    from elasticsearch_tpu.monitor import kernels
+    from elasticsearch_tpu.ops.scoring import (bm25_term_group_topk,
+                                               pack_term_group_words,
+                                               topk_block_config)
+
+    seg, inv = ctx.segment, plan.inv
+    kernels.record("bm25_hybrid" if plan.impact is not None
+                   else "bm25_scatter")
+    kernels.record("bm25_one_program")
+    words = pack_term_group_words(plan.qrows, plan.qrw, plan.starts,
+                                  plan.lens, plan.ws)
+    with span("device.dispatch", program="bm25_term_group_topk"):
+        # R and T are the pow2 buckets hybrid_slices / chunked_slices pad
+        # the row list and the chunk table to: the staged programs' own
+        # shape classes  # tpulint: bucketed
+        return bm25_term_group_topk(
+            plan.impact, inv.doc_ids, inv.tfnorm, seg.live,
+            seg.roots_dev if seg.has_nested else None, words,
+            R=0 if plan.impact is None else plan.qrows.shape[0],
+            T=plan.starts.shape[0], P=plan.P, D=ctx.D, k=min(k, ctx.D),
+            topk_block=topk_block_config())
 
 
 _TIER_PROGRAMS: dict = {}

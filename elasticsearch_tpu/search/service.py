@@ -20,11 +20,15 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from elasticsearch_tpu.ops.scoring import topk_with_mask
+from elasticsearch_tpu.ops.scoring import (finish_topk, topk_block_config,
+                                           topk_with_mask,
+                                           unpack_topk_result)
 from elasticsearch_tpu.search.aggregations import parse_aggs, reduce_aggs, run_aggs
 from elasticsearch_tpu.search.context import GlobalStats, SegmentContext
 from elasticsearch_tpu.search.highlight import extract_query_terms, highlight_field
-from elasticsearch_tpu.search.queries import parse_query
+from elasticsearch_tpu.search.queries import (fused_bm25_topk, parse_query,
+                                              plan_term_group,
+                                              term_group_topk)
 from elasticsearch_tpu.utils.errors import SearchParseException
 
 
@@ -130,6 +134,11 @@ class ShardSearcher:
                 return sp
             return prof.span_phase(sp, phase)
 
+        def _dev(fn, bucket: Optional[str] = None):
+            """A device call, timed into the profile's compile/execute
+            split (and ``bucket``) under ?profile=true."""
+            return fn() if prof is None else prof.device_call(fn, bucket)
+
         with _p("search.rewrite", "rewrite"):
             query = parse_query(body.get("query"))
             prepare_tree(query, self.segments, self.mappings, self.analysis,
@@ -189,11 +198,15 @@ class ShardSearcher:
         t_begin = time.perf_counter()
         terminated_early = False
         timed_out = False
-        # fused dense-impact top-k fast path: eligible request shapes skip
-        # the [D] score row entirely (queries.fused_bm25_topk)
-        fused_ok = (not aggs and not sort_spec and min_score is None
+        # plain: score-ordered top-k, no snapshot of every match — one
+        # finishing program a segment (ops.scoring.finish_topk)
+        plain = not sort_spec and full_snap is None
+        # single-program paths: eligible request shapes hand the whole
+        # segment to one program (hybrid_fused_topk, fused_bm25_topk,
+        # term_group_topk)
+        fused_ok = (plain and not aggs and min_score is None
                     and search_after is None and not rescore_specs
-                    and full_snap is None and not collect_full)
+                    and not collect_full)
         from elasticsearch_tpu.search.hybrid import HybridQuery
         # attach the profile timer for the duration of segment execution
         # so fielddata rehydrations (resources/residency.py) file under
@@ -217,6 +230,7 @@ class ShardSearcher:
                                          index_name=self.index_name)
                 if prof is not None:
                     prof.segments += 1
+                kk = min(k, seg.max_docs)
                 if fused_ok and not seg.has_nested \
                         and isinstance(query, HybridQuery):
                     # hybrid stage 1: BOTH engines + fusion + top-k as ONE
@@ -226,14 +240,8 @@ class ShardSearcher:
                     # padding beyond the match count.
                     from elasticsearch_tpu.search.hybrid import hybrid_fused_topk
 
-                    if prof is not None:
-                        fused = prof.device_call(
-                            lambda: hybrid_fused_topk(ctx, query,
-                                                      min(k, seg.max_docs)),
-                            bucket="fuse")
-                    else:
-                        fused = hybrid_fused_topk(ctx, query,
-                                                  min(k, seg.max_docs))
+                    fused = _dev(lambda: hybrid_fused_topk(ctx, query, kk),
+                                 "fuse")
                     if fused is not None:
                         vals, ids, seg_total = fused
                         total += seg_total
@@ -243,44 +251,54 @@ class ShardSearcher:
                                 docs.append(ShardDoc(self.shard_ord, seg,
                                                      int(i), float(v)))
                         continue
-                if fused_ok and not seg.has_nested:
-                    from elasticsearch_tpu.search.queries import fused_bm25_topk
-
-                    if prof is not None:
-                        fused = prof.device_call(
-                            lambda: fused_bm25_topk(ctx, query,
-                                                    min(k, seg.max_docs)),
-                            bucket="topk")
-                    else:
-                        fused = fused_bm25_topk(ctx, query, min(k, seg.max_docs))
-                    if fused is not None:
-                        vals, ids, seg_total = fused
-                        total += seg_total
-                        for v, i in zip(vals, ids):
-                            # matches score strictly > 0; the live mask maps
-                            # non-matches to -inf or a 0.0 dense row
-                            if np.isfinite(v) and v > 0:
-                                max_score = max(max_score, float(v))
-                                docs.append(ShardDoc(self.shard_ord, seg,
-                                                     int(i), float(v)))
-                        continue
-                if prof is not None:
-                    scores, mask = prof.device_call(
-                        lambda: query.score_or_mask(ctx))
+                # a pure disjunctive term group under a plain request is
+                # planned ONCE and served by a single-program path
+                plan = plan_term_group(ctx, query) if fused_ok else None
+                if plan is not None and plan.all_dense \
+                        and not seg.has_nested:
+                    vals, ids, seg_total = _dev(
+                        lambda: fused_bm25_topk(ctx, plan, kk), "topk")
+                    total += seg_total
+                    for v, i in zip(vals, ids):
+                        # matches score strictly > 0; the live mask maps
+                        # non-matches to -inf or a 0.0 dense row
+                        if np.isfinite(v) and v > 0:
+                            max_score = max(max_score, float(v))
+                            docs.append(ShardDoc(self.shard_ord, seg,
+                                                 int(i), float(v)))
+                    continue
+                if plan is not None:
+                    packed_dev = _dev(lambda: term_group_topk(ctx, plan, kk),
+                                      "topk")
                 else:
-                    scores, mask = query.score_or_mask(ctx)
-                # eager mask ops: each one its own enqueue
-                with _p("device.dispatch", program="mask_ops"):
-                    mask = mask & seg.live
-                    if seg.has_nested:
-                        # top-level hits are root docs only; nested children
-                        # are reachable solely through nested queries/aggs
-                        # (reference: Lucene block-join — nested docs hidden
-                        # from root searches)
-                        mask = mask & seg.roots_dev
-                    if min_score is not None:
-                        mask = mask & (scores >= float(min_score))
-                    tot_dev = jnp.sum(mask.astype(jnp.int32))
+                    scores, mask = _dev(lambda: query.score_or_mask(ctx))
+                    if plain:
+                        # mask ops, count, top-k and pack as ONE program
+                        # after the query's own (ops.scoring.finish_topk)
+                        with _p("device.dispatch", program="finish_topk"):
+                            packed_dev, mask = _dev(lambda: finish_topk(
+                                scores, mask, seg.live,
+                                seg.roots_dev if seg.has_nested else None,
+                                None if min_score is None
+                                else float(min_score),
+                                k=kk, topk_block=topk_block_config(),
+                                with_mask=bool(aggs)), "topk")
+                    else:
+                        # the sorted and scroll-snapshot branches go on
+                        # composing [D] vectors: eager mask ops, each its
+                        # own enqueue
+                        with _p("device.dispatch", program="mask_ops"):
+                            mask = mask & seg.live
+                            if seg.has_nested:
+                                # top-level hits are root docs only; nested
+                                # children are reachable solely through
+                                # nested queries/aggs (reference: Lucene
+                                # block-join — nested docs hidden from root
+                                # searches)
+                                mask = mask & seg.roots_dev
+                            if min_score is not None:
+                                mask = mask & (scores >= float(min_score))
+                            tot_dev = jnp.sum(mask.astype(jnp.int32))
                 if aggs:
                     with _p(None, "aggs"):
                         agg_partials.append(run_aggs(aggs, ctx, mask))
@@ -312,20 +330,6 @@ class ShardSearcher:
                             for i in order[: min(k, order.size)]
                         ]
                 else:
-                    from elasticsearch_tpu.ops.scoring import (
-                        pack_topk_result, unpack_topk_result)
-
-                    kk = min(k, seg.max_docs)
-                    with _p("device.dispatch", program="topk_with_mask"):
-                        if prof is not None:
-                            vals, idx = prof.device_call(
-                                lambda: topk_with_mask(scores, mask, k=kk),
-                                bucket="topk")
-                            packed_dev = prof.device_call(
-                                lambda: pack_topk_result(vals, idx, tot_dev))
-                        else:
-                            vals, idx = topk_with_mask(scores, mask, k=kk)
-                            packed_dev = pack_topk_result(vals, idx, tot_dev)
                     # ONE host transfer: per-array pulls each pay a fixed
                     # device round-trip (network-attached chips: ~5-20 ms)
                     with _p("device.wait", "host_sync"):

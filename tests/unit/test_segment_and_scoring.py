@@ -806,3 +806,210 @@ def test_hybrid_lookup_matches_hybrid_gather():
     got_m = np.asarray(term_mask_hybrid_lookup(
         impact, qrows, d_doc, starts, lens, P=P, D=D))
     np.testing.assert_array_equal(got_m, want_m)
+
+
+# -- one program a search segment (finish_topk, bm25_term_group_topk) --------
+
+def _term_group_corpus(ties: bool):
+    """A synthetic segment with dense rows and a CSR tail; with ``ties``
+    every posting carries the same tf norm, so whole runs of documents tie
+    on score and the top-k's tie order shows."""
+    from elasticsearch_tpu.index.segment import build_dense_impact
+
+    rng = np.random.default_rng(53)
+    n_docs, vocab = 512, 64
+    D = pow2_bucket(n_docs)
+    doc_lists = [
+        np.sort(rng.choice(n_docs, size=max(1, n_docs // (t + 1)),
+                           replace=False))
+        for t in range(vocab)
+    ]
+    df = np.array([len(d) for d in doc_lists], np.int32)
+    offsets = np.zeros(vocab + 1, np.int64)
+    offsets[1:] = np.cumsum(df)
+    nnz = int(df.sum())
+    u_doc = np.concatenate(doc_lists).astype(np.int32)
+    tfn = (np.ones(nnz, np.float32) if ties
+           else rng.random(nnz).astype(np.float32) + 0.5)
+    dense_rows, impact = build_dense_impact(u_doc, tfn, offsets, df, D,
+                                            df_threshold=64)
+    nnz_pad = pow2_bucket(nnz)
+    d_doc = np.full(nnz_pad, D, np.int32)
+    d_doc[:nnz] = u_doc
+    d_tfn = np.zeros(nnz_pad, np.float32)
+    d_tfn[:nnz] = tfn
+    return dict(D=D, n_docs=n_docs, dense_rows=dense_rows, impact=impact,
+                offsets=offsets, df=df, d_doc=d_doc, d_tfn=d_tfn)
+
+
+def _term_group_tables(c, qterms, dense: bool):
+    """(qrows, qrw, starts, lens, ws, P) as context.hybrid_slices /
+    chunked_slices build them; ``dense`` False sends every term down the
+    scatter tail (qrows, qrw None)."""
+    from elasticsearch_tpu.ops.scoring import pack_dense_rows
+    from elasticsearch_tpu.search.context import split_runs
+
+    row_w, runs = {}, []
+    for i, t in enumerate(qterms):
+        w = 1.0 + 0.5 * i
+        row = int(c["dense_rows"][t]) if dense else -1
+        if row >= 0:
+            row_w[row] = row_w.get(row, 0.0) + w
+        else:
+            runs.append((int(c["offsets"][t]), int(c["df"][t]), w))
+    st, ln, ws_, mx = split_runs(runs) if runs else ([], [], [], 1)
+    T = pow2_bucket(max(len(st), 1))
+    starts = np.zeros(T, np.int32)
+    lens = np.zeros(T, np.int32)
+    ws = np.zeros(T, np.float32)
+    starts[:len(st)], lens[:len(ln)], ws[:len(ws_)] = st, ln, ws_
+    qrows, qrw = pack_dense_rows(row_w) if row_w else (None, None)
+    return qrows, qrw, starts, lens, ws, pow2_bucket(mx)
+
+
+TERM_GROUP_CASES = {
+    # name: (query terms, dense rows used, k, deleted docs, nested roots,
+    #        tied scores)
+    "hybrid": ([0, 1, 2, 40, 63], True, 10, 0, False, False),
+    "scatter_only": ([30, 40, 63], False, 10, 0, False, False),
+    "all_dense_empty_tail": ([0, 1], True, 10, 0, False, False),
+    "k_larger_than_the_matches": ([60, 63], False, 64, 0, False, False),
+    "deleted_documents": ([0, 1, 40, 63], True, 10, 200, False, False),
+    "nested_documents": ([0, 2, 40, 50], True, 10, 40, True, False),
+    "ties": ([0, 1, 40, 63], True, 32, 0, False, True),
+    "ties_scatter_only": ([5, 40, 63], False, 32, 17, False, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TERM_GROUP_CASES))
+def test_term_group_topk_is_bitwise_the_staged_sequence(case):
+    """bm25_term_group_topk (one program, one packed argument) returns
+    the very words of the staged sequence: score program → ``> 0`` →
+    ``& live`` (``& roots``) → sum → topk_with_mask → pack_topk_result."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.scoring import (
+        bm25_score_hybrid_gather, bm25_score_segment, bm25_term_group_topk,
+        pack_term_group_words, pack_topk_result, topk_block_config,
+        topk_with_mask, unpack_topk_result)
+
+    qterms, dense, k, n_deleted, nested, ties = TERM_GROUP_CASES[case]
+    c = _term_group_corpus(ties)
+    D = c["D"]
+    qrows, qrw, starts, lens, ws, P = _term_group_tables(c, qterms, dense)
+    rng = np.random.default_rng(59)
+    live = np.zeros(D, bool)
+    live[:c["n_docs"]] = True
+    live[rng.choice(c["n_docs"], size=n_deleted, replace=False)] = False
+    roots = None
+    if nested:
+        roots = np.zeros(D, bool)
+        roots[:c["n_docs"]:3] = True  # two children behind every root
+    live_dev = jnp.asarray(live)
+    roots_dev = None if roots is None else jnp.asarray(roots)
+
+    if qrows is not None:
+        scores = bm25_score_hybrid_gather(
+            c["impact"], qrows, qrw, c["d_doc"], c["d_tfn"], starts, lens,
+            ws, P=P, D=D)
+    else:
+        scores = bm25_score_segment(c["d_doc"], c["d_tfn"], starts, lens,
+                                    ws, P=P, D=D)
+    mask = (scores > 0) & live_dev
+    if roots_dev is not None:
+        mask = mask & roots_dev
+    tot = jnp.sum(mask.astype(jnp.int32))
+    vals, idx = topk_with_mask(scores, mask, k=k)
+    want = np.asarray(pack_topk_result(vals, idx, tot))
+
+    words = pack_term_group_words(qrows, qrw, starts, lens, ws)
+    R = 0 if qrows is None else qrows.shape[0]
+    assert words.dtype == np.int32 and words.shape == (2 * R + 3 * len(ws),)
+    got = np.asarray(bm25_term_group_topk(
+        c["impact"] if qrows is not None else None, c["d_doc"], c["d_tfn"],
+        live_dev, roots_dev, words, R=R, T=len(ws), P=P, D=D, k=k,
+        topk_block=topk_block_config()))
+    np.testing.assert_array_equal(got, want)  # i32 words: bitwise
+
+    # the case is what its name says
+    v, i, total = unpack_topk_result(got, k)
+    hits = np.isfinite(v)
+    assert total == int(np.asarray(mask).sum()) > 0
+    assert hits.sum() == min(k, total)
+    assert live[i[hits]].all()
+    if case == "k_larger_than_the_matches":
+        assert total < k and not hits.all()
+    if nested:
+        assert roots[i[hits]].all()
+        assert total < int(np.asarray((scores > 0) & live_dev).sum())
+    if n_deleted:
+        assert total < int(np.asarray(scores > 0).sum())
+    if ties:
+        # tied scores come back lowest document first
+        for a in range(k - 1):
+            if hits[a + 1] and v[a] == v[a + 1]:
+                assert i[a] < i[a + 1]
+        assert len(set(v[hits].tolist())) < hits.sum()
+
+
+@pytest.mark.parametrize("with_min_score", [False, True],
+                         ids=["no_min_score", "min_score"])
+@pytest.mark.parametrize("with_roots", [False, True],
+                         ids=["no_roots", "roots"])
+def test_finish_topk_equals_the_eager_sequence(with_roots, with_min_score):
+    """finish_topk = mask & live (& roots) (& scores >= min_score) → sum →
+    topk_with_mask → pack_topk_result, -inf for what is masked out, and
+    the final mask when asked for."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.scoring import (
+        finish_topk, pack_topk_result, topk_block_config, topk_with_mask,
+        unpack_topk_result)
+
+    rng = np.random.default_rng(61)
+    D, k = 256, 16
+    # few distinct values: ties, zeros (filter-only hits) and negatives
+    scores = jnp.asarray(rng.integers(-2, 6, size=D).astype(np.float32) / 2)
+    mask = jnp.asarray(rng.random(D) < 0.6)
+    live = jnp.asarray(rng.random(D) < 0.9)
+    roots = jnp.asarray(rng.random(D) < 0.5) if with_roots else None
+    min_score = 0.5 if with_min_score else None
+
+    m = mask & live
+    if roots is not None:
+        m = m & roots
+    if min_score is not None:
+        m = m & (scores >= float(min_score))
+    tot = jnp.sum(m.astype(jnp.int32))
+    vals, idx = topk_with_mask(scores, m, k=k)
+    want = np.asarray(pack_topk_result(vals, idx, tot))
+
+    packed, out_mask = finish_topk(scores, mask, live, roots, min_score,
+                                   k=k, topk_block=topk_block_config(),
+                                   with_mask=True)
+    np.testing.assert_array_equal(np.asarray(packed), want)
+    np.testing.assert_array_equal(np.asarray(out_mask), np.asarray(m))
+    packed2, no_mask = finish_topk(scores, mask, live, roots, min_score,
+                                   k=k, topk_block=topk_block_config())
+    assert no_mask is None
+    np.testing.assert_array_equal(np.asarray(packed2), want)
+    v, i, total = unpack_topk_result(np.asarray(packed), k)
+    assert total == int(np.asarray(m).sum())
+    assert np.asarray(m)[i[np.isfinite(v)]].all()
+    if with_min_score:
+        assert (v[np.isfinite(v)] >= 0.5).all()
+
+
+def test_finish_topk_pads_with_neg_inf_beyond_the_matches():
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.scoring import finish_topk, unpack_topk_result
+
+    scores = jnp.asarray(np.array([0.0, 3.0, 1.0, 2.0], np.float32))
+    mask = jnp.asarray(np.array([True, True, False, True]))
+    live = jnp.asarray(np.array([True, False, True, True]))
+    packed, _ = finish_topk(scores, mask, live, k=4, topk_block=0)
+    v, i, total = unpack_topk_result(np.asarray(packed), 4)
+    assert total == 2
+    assert v.tolist() == [2.0, 0.0, -np.inf, -np.inf]  # 0.0 is a real hit
+    assert i[:2].tolist() == [3, 0]
