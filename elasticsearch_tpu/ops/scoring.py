@@ -209,6 +209,58 @@ def bm25_score_hybrid(
     return dense + bm25_score_segment(doc_ids, tfnorm, starts, lens, weights, P=P, D=D)
 
 
+def _fold_dense_rows(dense_impact, qrows, init, step):
+    """``step(acc, r, row_r)`` folded over the query's dense rows in
+    ascending r — the ONE way a single-query program reads
+    ``dense_impact[F, D]``. Row r is the ``[D]`` slice of the block at
+    ``max(qrows[r], 0)``, read inside the loop that consumes it (no
+    ``[R, D]`` copy), and the loop ends behind the last real row:
+    ``pack_dense_rows`` sorts the -1 padding to the back, and on a TPU a
+    padding row is no cheaper than a real one. (A stray -1 before that
+    reads row 0; callers weight it 0 or mask it by ``qrows >= 0``.)
+
+    What a row costs there (v5e, f32, D 2^22; PERF.md §6, PR 28): the
+    block is tiled ``(8, 128)``, a one-row slice streams its whole 8-row
+    tile group — 134 MB, 0.19 ms — so a query of n rows moves n/8 of a
+    64-row block, not n/64. The advanced-index form ``dense_impact[rows]``
+    this replaced lowered to a pass over ALL F rows (128 column pieces
+    ``f32[64,32768]``, a small gather a piece, 128 update-slices)."""
+    R = qrows.shape[0]
+    last = jnp.max(jnp.where(qrows >= 0,
+                             jnp.arange(1, R + 1, dtype=jnp.int32), 0))
+
+    def body(r, acc):
+        row = lax.dynamic_slice_in_dim(dense_impact,
+                                       jnp.maximum(qrows[r], 0), 1, axis=0)
+        return step(acc, r, row[0])
+
+    return lax.fori_loop(0, last, body, init)
+
+
+def _dense_row_score(dense_impact, qrows, qrw):
+    """``Σ_r qrw[r] · row_r`` in f32, summed in ascending r (a bf16 block
+    is upcast a row at a time). f32[D]."""
+    return _fold_dense_rows(
+        dense_impact, qrows, jnp.zeros(dense_impact.shape[1], jnp.float32),
+        lambda acc, r, row: acc + qrw[r] * row.astype(jnp.float32))
+
+
+def _dense_row_count(dense_impact, qrows):
+    """i32[D]: how many of the query's dense rows (padding excluded) have
+    a non-zero impact at each doc."""
+    return _fold_dense_rows(
+        dense_impact, qrows, jnp.zeros(dense_impact.shape[1], jnp.int32),
+        lambda acc, r, row: acc + ((row != 0)
+                                   & (qrows[r] >= 0)).astype(jnp.int32))
+
+
+def _dense_row_mask(dense_impact, qrows):
+    """bool[D]: docs where any of the query's dense rows is non-zero."""
+    return _fold_dense_rows(
+        dense_impact, qrows, jnp.zeros(dense_impact.shape[1], bool),
+        lambda acc, r, row: acc | ((row != 0) & (qrows[r] >= 0)))
+
+
 @partial(jax.jit, static_argnames=("P", "D"))
 def bm25_score_hybrid_gather(dense_impact, qrows, qrw, doc_ids, tfnorm,
                              starts, lens, weights, *, P: int, D: int):
@@ -217,16 +269,16 @@ def bm25_score_hybrid_gather(dense_impact, qrows, qrw, doc_ids, tfnorm,
     ``qrows`` i32[R] are the query's dense-row indices (-1 padding),
     ``qrw`` f32[R] the matching idf*boost weights (0 padding). The matmul
     form (`bm25_score_hybrid`) reads the WHOLE impact[F, D] block per
-    query — ~1 GB at the 1M-doc bench shape — where this gathers R << F
-    contiguous rows (~16 MB), a ~F/R traffic cut that measures ~14x
-    end-to-end on the product's single-query path. Accumulation is f32
-    over the gathered rows (at R <= F terms, at least as precise as the
-    matvec's bf16-pass emulation), so scores agree with the matmul form
-    to fp rounding. Row 0 stands in for padding via clamp; its weight is
-    0 so it contributes nothing."""
-    rows = dense_impact[jnp.maximum(qrows, 0)]  # [R, D]
-    dense = jnp.einsum("r,rd->d", qrw, rows.astype(jnp.float32),
-                       precision=lax.Precision.HIGHEST)
+    query — 1 GiB at F 64, D 2^22 — where this reads the query's real
+    rows one by one (:func:`_fold_dense_rows`: no ``[R, D]`` copy, no
+    read for a padding row). Measured on a v5e at that shape (PERF.md
+    §6, PR 28): 0.19 ms a real row — its 8-row tile group, 134 MB — so
+    ~0.4 ms for the average query's 2 rows, where the
+    ``dense_impact[rows]`` + einsum form this replaced took ~3 ms
+    whatever the query. The dense part is the in-order f32 sum
+    ``Σ_r qrw[r]·row_r`` — plain f32 multiplies and adds, so it agrees
+    with the matmul form's multi-pass emulation to fp rounding."""
+    dense = _dense_row_score(dense_impact, qrows, qrw)
     return dense + bm25_score_segment(doc_ids, tfnorm, starts, lens,
                                       weights, P=P, D=D)
 
@@ -253,34 +305,35 @@ def pack_dense_rows(row_w: dict):
 
 @jax.jit
 def gather_impact_rows(dense_impact, qrows):
-    """(impact[qrows] [R, D], valid f32[R]) for feeding batched kernels a
-    compact per-query block: padding rows (-1) clamp to row 0 and carry
-    validity 0 so presence counts ignore them."""
-    sub = dense_impact[jnp.maximum(qrows, 0)]
+    """(the query's rows [R, D], valid f32[R]) for feeding batched kernels
+    a compact per-query block: row r of the result is the block's row
+    ``qrows[r]``, copied by :func:`_fold_dense_rows` (the real rows are
+    read, never the whole block); the padding rows behind them stay zero
+    and carry validity 0 so presence counts ignore them."""
+    R, D = qrows.shape[0], dense_impact.shape[1]
+    sub = _fold_dense_rows(
+        dense_impact, qrows, jnp.zeros((R, D), dense_impact.dtype),
+        lambda acc, r, row: lax.dynamic_update_slice_in_dim(
+            acc, row[None], r, axis=0))
     return sub, (qrows >= 0).astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("P", "D"))
 def match_count_hybrid_gather(dense_impact, qrows, doc_ids, starts, lens,
                               *, P: int, D: int):
-    """Matched-term count via gathered dense rows (row-gather analogue of
+    """Matched-term count via the query's dense rows (row-read analogue of
     match_count_hybrid; padding rows are masked by qrows >= 0)."""
-    valid = (qrows >= 0)[:, None]
-    present = (dense_impact[jnp.maximum(qrows, 0)] != 0) & valid  # [R, D]
-    dcount = jnp.sum(present.astype(jnp.int32), axis=0)
-    tail = match_count_segment(doc_ids, starts, lens, P=P, D=D)
-    return dcount + tail
+    return (_dense_row_count(dense_impact, qrows)
+            + match_count_segment(doc_ids, starts, lens, P=P, D=D))
 
 
 @partial(jax.jit, static_argnames=("P", "D"))
 def term_mask_hybrid_gather(dense_impact, qrows, doc_ids, starts, lens,
                             *, P: int, D: int):
-    """Any-term match mask via gathered dense rows (row-gather analogue
+    """Any-term match mask via the query's dense rows (row-read analogue
     of term_mask_hybrid)."""
-    valid = (qrows >= 0)[:, None]
-    dmask = jnp.any((dense_impact[jnp.maximum(qrows, 0)] != 0) & valid,
-                    axis=0)
-    return dmask | term_mask(doc_ids, starts, lens, P=P, D=D)
+    return (_dense_row_mask(dense_impact, qrows)
+            | term_mask(doc_ids, starts, lens, P=P, D=D))
 
 
 @partial(jax.jit, static_argnames=("P", "D", "prec"))
@@ -415,7 +468,7 @@ def bm25_hybrid_candidates_topk(dense_impact, qrows, qrw, doc_ids, tfnorm,
     real. This computes the same top-k Lucene-style instead: only the
     docs the tail actually TOUCHES are scored.
 
-      1. dense[D] = qrw @ impact[qrows]   (row gather, no scatter)
+      1. dense[D] = Σ_r qrw[r]·impact[qrows[r]]   (row reads, no scatter)
       2. tail windows → (doc, contrib) pairs [W = T·P], sort by doc
          (vectorized bitonic), segment-sum equal-doc runs via cumsum
       3. tail candidates = run ends; their TOTAL score adds dense[doc]
@@ -430,10 +483,8 @@ def bm25_hybrid_candidates_topk(dense_impact, qrows, qrw, doc_ids, tfnorm,
     row: the final merge sorts by (-score, doc id). Returns
     (vals f32[k], idx i32[k], total i32).
     """
-    # 1. dense scores (gather form), masked
-    rows = dense_impact[jnp.maximum(qrows, 0)]
-    dense = jnp.einsum("r,rd->d", qrw, rows.astype(jnp.float32),
-                       precision=lax.Precision.HIGHEST)
+    # 1. dense scores (the query's rows only), masked
+    dense = _dense_row_score(dense_impact, qrows, qrw)
     dense_m = jnp.where(live, dense, 0.0)
 
     # 2. tail windows → flat (doc, contrib); padding → doc D, contrib 0
@@ -565,10 +616,8 @@ def term_mask_lookup(doc_ids, starts, lens, *, P: int, D: int):
 @partial(jax.jit, static_argnames=("P", "D"))
 def bm25_score_hybrid_lookup(dense_impact, qrows, qrw, doc_ids, tfnorm,
                              starts, lens, weights, *, P: int, D: int):
-    """Row-gather dense + lookup tail (scatter-free hybrid scores)."""
-    rows = dense_impact[jnp.maximum(qrows, 0)]
-    dense = jnp.einsum("r,rd->d", qrw, rows.astype(jnp.float32),
-                       precision=lax.Precision.HIGHEST)
+    """Dense rows + lookup tail (scatter-free hybrid scores)."""
+    dense = _dense_row_score(dense_impact, qrows, qrw)
     return dense + bm25_score_segment_lookup(doc_ids, tfnorm, starts,
                                              lens, weights, P=P, D=D)
 
@@ -576,21 +625,17 @@ def bm25_score_hybrid_lookup(dense_impact, qrows, qrw, doc_ids, tfnorm,
 @partial(jax.jit, static_argnames=("P", "D"))
 def match_count_hybrid_lookup(dense_impact, qrows, doc_ids, starts, lens,
                               *, P: int, D: int):
-    """Gathered dense presence + lookup tail counts (scatter-free)."""
-    valid = (qrows >= 0)[:, None]
-    present = (dense_impact[jnp.maximum(qrows, 0)] != 0) & valid
-    return (jnp.sum(present.astype(jnp.int32), axis=0)
+    """Dense-row presence + lookup tail counts (scatter-free)."""
+    return (_dense_row_count(dense_impact, qrows)
             + match_count_segment_lookup(doc_ids, starts, lens, P=P, D=D))
 
 
 @partial(jax.jit, static_argnames=("P", "D"))
 def term_mask_hybrid_lookup(dense_impact, qrows, doc_ids, starts, lens,
                             *, P: int, D: int):
-    """Gathered dense presence | lookup tail mask (scatter-free)."""
-    valid = (qrows >= 0)[:, None]
-    dmask = jnp.any((dense_impact[jnp.maximum(qrows, 0)] != 0) & valid,
-                    axis=0)
-    return dmask | term_mask_lookup(doc_ids, starts, lens, P=P, D=D)
+    """Dense-row presence | lookup tail mask (scatter-free)."""
+    return (_dense_row_mask(dense_impact, qrows)
+            | term_mask_lookup(doc_ids, starts, lens, P=P, D=D))
 
 
 @partial(jax.jit, static_argnames=("P", "D", "k", "topk_block", "prec"))

@@ -192,8 +192,8 @@ class HybridTGroupPrim(DataPrim):
     shard has no dense block — its terms all fall to the tail),
     qrows [S, R] / qrw [S, R] (the query's dense-row indices and idf*boost
     weights, -1/0 padded) — the DSL path is per-request (Q=1), so scoring
-    GATHERS only those R << F rows instead of multiplying the whole block
-    (bm25_score_hybrid_gather's traffic math) — and starts/lens/ws [S, T]
+    reads only those rows instead of multiplying the whole block
+    (ops/scoring._fold_dense_rows) — and starts/lens/ws [S, T]
     tail chunk tables. Per-shard F/dense_rows variability is data; the
     emit tree stays identical on every shard."""
 
@@ -799,11 +799,11 @@ class ETermGroup(Emit):
 
 
 class ETermGroupHybrid(Emit):
-    """ETermGroup over the hybrid dense-impact path: a row GATHER of the
+    """ETermGroup over the hybrid dense-impact path: a read of the
     query's dense rows + scatter for the tail (mirror of
     _score_term_group's hybrid branch — the per-request DSL path is Q=1,
-    where gathering R << F rows beats multiplying the whole block by the
-    traffic ratio F/R; see ops/scoring.bm25_score_hybrid_gather). Same
+    where reading the query's own rows beats multiplying the whole
+    block; ops/scoring._fold_dense_rows has what a row costs). Same
     three modes as ETermGroup."""
 
     def __init__(self, prim: int, post: int, mode: str, n: int, boost: float,
