@@ -25,7 +25,7 @@ process owns the chip at any time.
   a 1,048,576 x 128 slab with bench.py's vectorised loader (``loaded_by:
   segment_loader``), serves them from a RestServer in that process, answers
   match / ``_msearch`` / kNN over the socket against the same reference, and
-  compiles each of the four Pallas kernels, not interpreted, at the tiles its
+  compiles each of the three Pallas kernels, not interpreted, at the tiles its
   dispatcher picks for these shapes, comparing with the XLA twin.
 
 Stdout is two lines: ``SMOKE_REPORT {...}`` (sizes, seconds, counters,
@@ -59,14 +59,14 @@ TOP_K = 10
 FULL_DOCS = 1 << 20        # bench.py's product size
 # score tolerance: the unit oracles compare device scores to numpy at
 # rtol 1e-5..2e-5 (tests/unit/test_segment_and_scoring.py); the batched
-# tiers may score through the bf16 fused kernel on a TPU, which the unit
+# kNN tier scores through the bf16 Pallas kernel on a TPU, which the unit
 # tests hold to top-1 agreement only
 RTOL_SINGLE = 5e-5
 RTOL_BATCHED = 2e-2
 
 # counters that must stay at zero / must move, by family
-ZERO_KERNELS = ("bm25_pallas_failed", "adc_pallas_failed",
-                "maxsim_pallas_failed", "tail_scatter_free_failed",
+ZERO_KERNELS = ("adc_pallas_failed", "maxsim_pallas_failed",
+                "tail_scatter_free_failed",
                 "mesh_fallback_total", "mesh_build_failed",
                 "dist_mesh_fallback")
 ZERO_CACHE = ("call_fallback", "deserialize_error", "store_error",
@@ -988,7 +988,7 @@ def judge_serve(result: dict):
 def width_child(args) -> int:
     """Runs in its own process: the 1M-doc segment and 1M x 128 slab
     through bench.py's vectorised loader, served by a RestServer here,
-    checked over the socket; then the four Pallas kernels against their
+    checked over the socket; then the three Pallas kernels against their
     XLA twins. Prints one ``WIDTH_RESULT {...}`` line."""
     sys.path.insert(0, HERE)
     import bench
@@ -1023,7 +1023,7 @@ def width_child(args) -> int:
                                         doc_len, n, VOCAB)
     block = seg.inverted["body"].dense_block()
     require(block is not None, "width: no dense impact block was built")
-    dense_rows, impact = block
+    _dense_rows, impact = block
     sift, sift_seg, vecs = bench.make_sift_node(n, DIMS, args.seed)
     jax.block_until_ready(impact)
     out["segment_seconds"] = round(time.monotonic() - t0, 2)
@@ -1113,39 +1113,8 @@ def width_child(args) -> int:
     for s in servers:
         s.stop()
 
-    # -- the four kernels, at the dispatcher's tiles, against their twins --
+    # -- the three kernels, at the dispatcher's tiles, against their twins --
     kern: dict = {}
-    D = int(impact.shape[1])
-    F = int(impact.shape[0])
-    live = jnp.asarray(np.arange(D) < n)
-    dense_tids = np.nonzero(dense_rows >= 0)[0]
-    for Q in (8, 256):
-        q_tile, tile = pk.bm25_dense_tiles_for(Q, F, D)
-        require(q_tile and D >= 2 * tile,
-                f"bm25 kernel gates out at Q={Q} F={F} D={D}")
-        qw = np.zeros((Q, F), np.float32)
-        for qi in range(Q):
-            for t in rng.choice(dense_tids, 3, replace=False):
-                qw[qi, dense_rows[t]] = idf[t]
-        t0 = time.monotonic()
-        pv, pi = pk.bm25_dense_topk_pallas(
-            jnp.asarray(qw), impact, live, k=TOP_K, tile=tile,
-            q_tile=q_tile, interpret=interpret)
-        pv, pi = np.asarray(pv), np.asarray(pi)
-        secs = time.monotonic() - t0
-        xs = jnp.where(live[None, :], jnp.dot(
-            jnp.asarray(qw), impact, precision="highest"), -jnp.inf)
-        xv, xi = jax.lax.top_k(xs, TOP_K)
-        xv, xi = np.asarray(xv), np.asarray(xi)
-        # the kernel multiplies in bf16: hold scores to bf16 rounding and
-        # every returned doc to its exact score
-        exact = np.take_along_axis(np.asarray(xs), pi, axis=1)
-        require(np.allclose(pv, exact, rtol=RTOL_BATCHED)
-                and np.allclose(pv[:, 0], xv[:, 0], rtol=RTOL_BATCHED),
-                f"bm25 kernel disagrees with its XLA twin at Q={Q}")
-        kern[f"bm25_dense_topk Q={Q}"] = {
-            "tile": [q_tile, tile], "first_call_seconds": round(secs, 2),
-            "top1_agreement": float(np.mean(pi[:, 0] == xi[:, 0]))}
 
     from elasticsearch_tpu.ops.knn import knn_topk
 

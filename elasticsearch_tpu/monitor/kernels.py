@@ -12,7 +12,9 @@ org/elasticsearch/index/search/stats/SearchStats.java:1-120).
 Names:
   bm25_scatter        pure scatter-add postings scoring (host or mesh)
   bm25_hybrid         dense-impact MXU matmul + scatter tail
-  bm25_fused_topk     Pallas streaming dense top-k (no [Q, D] intermediate)
+  bm25_fused_topk     a query of an all-dense batch served by the batched
+                      tier's qw[Q, F] @ impact[F, D] top-k
+                      (queries.fused_bm25_topk_batch)
   bm25_one_program    a host-loop search segment whose term group was scored,
                       masked, counted, top-k'd and packed by ONE program fed
                       by ONE packed argument (ops/scoring.
@@ -55,8 +57,8 @@ Names:
   executor_data_hit   a segment-round device-data group was reused
   executor_data_miss  a segment-round device-data group was built+uploaded
 
-The executor cache counters feed bench.py's ``metrics_delta`` and the
-``estpu_kernel_dispatch_total`` Prometheus family (monitor/metrics.py).
+The executor cache counters feed the ``estpu_kernel_dispatch_total``
+Prometheus family (monitor/metrics.py).
 """
 from __future__ import annotations
 

@@ -452,8 +452,8 @@ def process_counters() -> Dict[str, float]:
     distinguishable from zero in this snapshot map and renders as a
     typed ``None`` once it flows through :func:`counters_delta`),
     residency evictions/rehydrations, breaker trips, and the
-    SHARED registry's counters. ``bench.py`` snapshots this before/after
-    a run and emits the delta as ``metrics_delta``."""
+    SHARED registry's counters. The watchdog (monitor/watchdog.py)
+    snapshots this every tick and reads the delta."""
     out: Dict[str, float] = {}
     from elasticsearch_tpu.monitor import kernels
 

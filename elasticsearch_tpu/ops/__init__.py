@@ -9,11 +9,8 @@ _retrace.ensure_installed()
 from elasticsearch_tpu.ops.scoring import (
     bm25_score_segment,
     bm25_score_batch,
-    bm25_score_hybrid,
     bm25_score_hybrid_batch,
-    match_count_hybrid,
     term_mask,
-    term_mask_hybrid,
     topk_with_mask,
     range_mask_f32,
     range_mask_i64pair,
@@ -23,11 +20,8 @@ from elasticsearch_tpu.ops.knn import knn_scores, knn_topk
 __all__ = [
     "bm25_score_segment",
     "bm25_score_batch",
-    "bm25_score_hybrid",
     "bm25_score_hybrid_batch",
-    "match_count_hybrid",
     "term_mask",
-    "term_mask_hybrid",
     "topk_with_mask",
     "range_mask_f32",
     "range_mask_i64pair",

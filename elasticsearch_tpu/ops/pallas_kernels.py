@@ -170,161 +170,6 @@ def knn_topk_pallas(queries, vecs, mask, *, k: int, metric: str = "cosine",
     return out_v, out_i
 
 
-@partial(jax.jit, static_argnames=("k", "tile", "q_tile", "interpret"))
-def bm25_dense_topk_pallas(qw, impact, mask, *, k: int, tile: int = 2048,
-                           q_tile: int = 256, interpret: bool = False):
-    """Fused batched BM25 over the dense impact block with in-kernel top-k.
-
-    The XLA hybrid path (ops/scoring.bm25_score_hybrid_batch) materializes
-    the full [Q, D] score matrix in HBM and runs a separate top-k pass —
-    at bench scale (Q=2048, D=1M) that is an 8 GB round trip. This kernel
-    streams impact[F, tile] tiles HBM→VMEM, runs the qw @ tile matmul on
-    the MXU, applies the live mask on the VPU, and maintains the running
-    top-k in the output block across grid steps — [Q, D] never exists.
-
-    qw:     f32[Q, F]  idf*boost per dense term per query (0 = absent)
-    impact: f32[F, D]  index-time impact block (idf folded at query time
-                       via qw; rows are tfnorm impacts)
-    mask:   bool[D]    live-doc mask
-    Returns ([Q, k] scores, [Q, k] int32 doc ids) — same contract as
-    topk_batch(bm25_score_hybrid_batch(...)).
-
-    Scoring matches the XLA path modulo bf16 matmul rounding (the XLA
-    hybrid uses f32-HIGHEST; tests assert top-1 agreement).
-    """
-    from jax.experimental import pallas as pl
-
-    Q, F = qw.shape
-    D = impact.shape[1]
-    assert D % tile == 0, "impact block must be padded to a tile multiple"
-    assert Q % q_tile == 0, "queries must be padded to a q_tile multiple"
-    n_tiles = D // tile
-    n_q = Q // q_tile
-    qh = qw.astype(jnp.bfloat16)
-    QT = q_tile
-
-    def kernel(q_ref, imp_ref, m_ref, out_v_ref, out_i_ref):
-        step = pl.program_id(1)  # d-tile sweep is the inner grid axis
-
-        @pl.when(step == 0)
-        def _init():
-            out_v_ref[:] = jnp.full((QT, k), NEG_INF, dtype=jnp.float32)
-            out_i_ref[:] = jnp.zeros((QT, k), dtype=jnp.int32)
-
-        s = jax.lax.dot_general(
-            q_ref[:], imp_ref[:].astype(jnp.bfloat16),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [QT, tile]
-        s = jnp.where(m_ref[:], s, NEG_INF)  # mask block is [1, tile]
-        base = step * tile
-        tile_ids = base + jax.lax.broadcasted_iota(jnp.int32, (QT, tile), 1)
-
-        # Early-exit selection: the running top-k lives UNSORTED in the
-        # output refs; each pass extracts the tile's per-row max and
-        # replaces the row's current minimum where it improves, looping
-        # only while SOME row can still improve. In the steady state a
-        # tile improves ~0-1 entries per row (top-k insertions over a
-        # random-order sweep total ~k·ln(D/k) per query), so this runs
-        # ~1 pass where the old fixed fori_loop always paid k — the
-        # kernel's dominant VPU cost at large Q. A tile can contribute at
-        # most k entries per row, so k iterations bound the loop. Tie
-        # discipline: equal scores never displace an incumbent (m > rmin
-        # strict), and within a tile argmax picks the lowest doc id; the
-        # host-side wrapper re-sorts the unsorted buffer with an explicit
-        # (-value, doc id) key to match lax.top_k tie order exactly.
-        # the tile max `m` rides in the carry: cond/body can't CSE across
-        # a while_loop, and the [QT, tile] reductions ARE the kernel's
-        # dominant VPU cost — the non-improving steady state must pay
-        # exactly ONE full-width pass (the pre-loop max) per tile
-        def cond(carry):
-            cv, bv, bi, m, it = carry
-            return (it < k) & jnp.any(m > jnp.min(bv, axis=1))
-
-        def body(carry):
-            cv, bv, bi, m, it = carry
-            am = jnp.argmax(cv, axis=1)
-            knock = (jax.lax.broadcasted_iota(jnp.int32, (QT, tile), 1)
-                     == am[:, None])
-            picked_i = jnp.max(jnp.where(knock, tile_ids, jnp.int32(-1)),
-                               axis=1)
-            rmin = jnp.min(bv, axis=1)
-            amin = jnp.argmin(bv, axis=1)
-            improve = m > rmin
-            upd = improve[:, None] & (
-                jax.lax.broadcasted_iota(jnp.int32, (QT, k), 1)
-                == amin[:, None])
-            bv = jnp.where(upd, m[:, None], bv)
-            bi = jnp.where(upd, picked_i[:, None], bi)
-            cv = jnp.where(knock, NEG_INF, cv)
-            return cv, bv, bi, jnp.max(cv, axis=1), it + 1
-
-        _, bv, bi, _, _ = jax.lax.while_loop(
-            cond, body,
-            (s, out_v_ref[:], out_i_ref[:], jnp.max(s, axis=1), 0))
-        out_v_ref[:] = bv
-        out_i_ref[:] = bi
-
-    out_v, out_i = pl.pallas_call(
-        kernel,
-        grid=(n_q, n_tiles),
-        in_specs=[
-            pl.BlockSpec((QT, F), lambda qi, di: (qi, 0)),     # query block
-            pl.BlockSpec((F, tile), lambda qi, di: (0, di)),   # impact tile
-            # mask rides as [1, D] (see knn_topk_pallas)
-            pl.BlockSpec((1, tile), lambda qi, di: (0, di)),
-        ],
-        out_specs=[
-            pl.BlockSpec((QT, k), lambda qi, di: (qi, 0)),
-            pl.BlockSpec((QT, k), lambda qi, di: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
-        ],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(qh, impact, mask[None, :])
-    # the kernel's buffer is unsorted: order by (-value, doc id) — id
-    # ascending FIRST, then a stable value top_k, so equal scores rank by
-    # lowest doc id exactly like lax.top_k over the dense score row
-    order = jnp.argsort(out_i, axis=1)
-    v2 = jnp.take_along_axis(out_v, order, axis=1)
-    i2 = jnp.take_along_axis(out_i, order, axis=1)
-    vals, pos = jax.lax.top_k(v2, k)
-    ids = jnp.take_along_axis(i2, pos, axis=1)
-    return vals, ids
-
-
-def bm25_dense_tiles_for(Q: int, F: int, D: int):
-    """(q_tile, tile) keeping the working set under the VMEM budget:
-    double-buffered qw block (bf16), impact tile (f32) and [q_tile, k]
-    outputs, the tile's bf16 copy, and ~4 live [q_tile, tile] 4-byte
-    intermediates (scores, ids, the selection loop's candidate copies)."""
-    for q_tile in (512, 256, 128, 64, 32, 16, 8):
-        if Q % q_tile:
-            continue
-        for tile in (4096, 2048, 1024, 512):
-            if D % tile:
-                continue
-            est = (2 * q_tile * _lanes(F) * 2 + 2 * F * tile * 4
-                   + F * tile * 2 + 4 * q_tile * tile * 4
-                   + 4 * q_tile * 128 * 4)
-            if est <= _VMEM_BUDGET_BYTES:
-                return q_tile, tile
-    return 0, 0
-
-
-# sticky failure latch for the fused BM25 kernel (list so the traced-free
-# eager dispatcher can flip it in place). Latches ONLY on deterministic
-# compile/lowering failures — a transient runtime error (momentary device
-# OOM, transfer hiccup) falls back per-call and the kernel retries, up to
-# a bounded run of consecutive failures so a persistently-broken device
-# can't pay a fresh kernel attempt on every batch until restart.
-_BM25_PALLAS_BROKEN = [False]
-_BM25_TRANSIENT_FAILS = [0]
-_BM25_TRANSIENT_LIMIT = 8
-
 # error shapes that mean "this kernel will NEVER compile/lower here" —
 # deterministic, so one failure latches. That includes a tile over the
 # scoped VMEM limit, which Mosaic reports at compile time as
@@ -343,100 +188,6 @@ def _is_compile_error(e: BaseException) -> bool:
         return True
     text = f"{type(e).__name__}: {e}".lower()
     return any(m in text for m in _COMPILE_ERR_MARKERS)
-
-
-def bm25_dense_topk_auto(qw, impact, mask, *, k: int):
-    """Dispatch: fused Pallas kernel on TPU when static shape gates hold,
-    XLA hybrid matmul + topk_batch otherwise (same gate discipline as
-    knn_topk_auto — no runtime fallback illusions).
-
-    Q below the sublane multiple (a single REST query is Q=1) pads up to 8
-    with zero query rows and slices the result — without this no single
-    query could ever pass the q_tile gate and every request would fall to
-    the XLA path that materializes the [Q, D] row this kernel avoids (the
-    same regression knn_topk_auto documents from round 1)."""
-    Q, F = qw.shape
-    D = impact.shape[1]
-    qpad = ((Q + 7) // 8) * 8
-    q_tile, tile = bm25_dense_tiles_for(qpad, F, D)
-    # ESTPU_BM25_BATCH_KERNEL: auto (default) | pallas | xla — the A/B
-    # knob for the large-Q batch path (the kernel's in-kernel selection is
-    # VPU-bound at k passes per tile; XLA's chunked matmul+top_k rides the
-    # MXU + its tuned sort). Read eagerly here, like the other knobs.
-    pref = os.environ.get("ESTPU_BM25_BATCH_KERNEL", "auto").lower()
-    gates_ok = (not _BM25_PALLAS_BROKEN[0] and _on_tpu() and k <= 64
-                and F % 8 == 0 and q_tile and D >= 2 * tile)
-    if pref == "pallas" and not gates_ok:
-        # a forced-pallas A/B must never SILENTLY measure the XLA side
-        import warnings
-
-        warnings.warn("ESTPU_BM25_BATCH_KERNEL=pallas but the kernel's "
-                      "shape gates reject this call "
-                      f"(on_tpu={_on_tpu()}, k={k}, F={F}, q_tile={q_tile},"
-                      f" D={D}, tile={tile}) — falling back to XLA")
-    if pref != "xla" and gates_ok:
-        # this dispatcher runs EAGERLY, so a Mosaic lowering/compile
-        # failure (first real-TPU run of the early-exit selection) is
-        # catchable here — fall through to the XLA path with a warning
-        # instead of failing the batch
-        try:
-            if qpad != Q:
-                qp = jnp.concatenate(
-                    [qw, jnp.zeros((qpad - Q, F), qw.dtype)], axis=0)
-                vals, idx = bm25_dense_topk_pallas(qp, impact, mask, k=k,
-                                                   tile=tile, q_tile=q_tile)
-                _BM25_TRANSIENT_FAILS[0] = 0
-                return vals[:Q], idx[:Q]
-            out = bm25_dense_topk_pallas(qw, impact, mask, k=k, tile=tile,
-                                         q_tile=q_tile)
-            _BM25_TRANSIENT_FAILS[0] = 0
-            return out
-        except Exception as e:
-            import warnings
-
-            from elasticsearch_tpu.monitor import kernels
-
-            kernels.record("bm25_pallas_failed")
-            if _is_compile_error(e):
-                # sticky: a deterministic Mosaic lowering failure must not
-                # pay a fresh trace/compile attempt on every batch
-                _BM25_PALLAS_BROKEN[0] = True
-                warnings.warn(f"fused BM25 kernel failed ({type(e).__name__}"
-                              f": {str(e)[:200]}); serving via the XLA path "
-                              f"from now on")
-            else:
-                # transient (device OOM mid-burst, transfer error): fall
-                # back for THIS call only; a bounded run of consecutive
-                # failures latches anyway (every retry costs a batch)
-                _BM25_TRANSIENT_FAILS[0] += 1
-                if _BM25_TRANSIENT_FAILS[0] >= _BM25_TRANSIENT_LIMIT:
-                    _BM25_PALLAS_BROKEN[0] = True
-                    warnings.warn(
-                        f"fused BM25 kernel failed {_BM25_TRANSIENT_FAILS[0]}"
-                        f" consecutive times ({type(e).__name__}: "
-                        f"{str(e)[:200]}); latching to the XLA path")
-                else:
-                    warnings.warn(
-                        f"fused BM25 kernel transient failure "
-                        f"({type(e).__name__}: {str(e)[:200]}); XLA "
-                        f"fallback for this batch")
-    from elasticsearch_tpu.ops.scoring import (impact_precision, topk_auto,
-                                               topk_block_config)
-
-    # XLA fallback, Q-chunked: one unchunked [Q, D] score matrix at msearch
-    # batch scale (Q=2048, D=1M) would be an 8 GB intermediate. This
-    # dispatcher runs EAGERLY, so reading the configs here is safe.
-    outs = []
-    step = min(Q, 256)
-    blk = topk_block_config()
-    prec = impact_precision()  # jax canonicalizes the precision string
-    for q0 in range(0, Q, step):
-        scores = jnp.dot(qw[q0:q0 + step], impact, precision=prec)
-        masked = jnp.where(mask[None, :], scores, NEG_INF)
-        outs.append(topk_auto(masked, k, blk))
-    vals = jnp.concatenate([v for v, _ in outs], axis=0)
-    idx = jnp.concatenate([i for _, i in outs], axis=0)
-    return vals, idx.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -482,7 +233,7 @@ def adc_scores_pallas(codes, lut, *, tile: int = 2048,
             pl.BlockSpec((M, K), lambda i: (0, 0)),      # LUT: resident
         ],
         # 1-D i32/f32 blocks can hit XLA/Mosaic layout mismatches at
-        # small tiles (same note as the BM25 mask input) — ride as [1, W]
+        # small tiles (same note as knn_topk_pallas' mask) — ride as [1, W]
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, W), jnp.float32),
         compiler_params=_compiler_params(),
@@ -499,9 +250,12 @@ def adc_scores_pallas(codes, lut, *, tile: int = 2048,
 _UNROLL_ROW_BYTES = 512
 
 
-# sticky failure latch for the ADC kernel — same discipline as the fused
-# BM25 kernel above: deterministic compile/lowering failures latch on the
-# first hit; transients fall back per-call up to a bounded run.
+# sticky failure latch for the ADC kernel (list so the traced-free eager
+# dispatcher can flip it in place). Latches on the first deterministic
+# compile/lowering failure; a transient runtime error (momentary device
+# OOM, transfer hiccup) falls back per-call and the kernel retries, up to
+# a bounded run of consecutive failures so a persistently-broken device
+# can't pay a fresh kernel attempt on every batch until restart.
 _ADC_PALLAS_BROKEN = [False]
 _ADC_TRANSIENT_FAILS = [0]
 _ADC_TRANSIENT_LIMIT = 8
@@ -688,7 +442,7 @@ def maxsim_adc_pallas(codes, luts, *, t_real: int, tile: int = 2048,
     return out[0]
 
 
-# sticky failure latch — same discipline as the BM25/ADC kernels above:
+# sticky failure latch — same discipline as the ADC kernel's above:
 # deterministic compile/lowering failures latch on the first hit;
 # transients fall back per-call up to a bounded run.
 _MAXSIM_PALLAS_BROKEN = [False]
@@ -753,15 +507,15 @@ def _maxsim_adc_xla(codes, luts):
 
 def maxsim_adc_auto(codes, luts):
     """Dispatch: fused Pallas MaxSim-ADC kernel on TPU when static shape
-    gates hold, XLA gather form otherwise. Runs EAGERLY (same contract
-    as bm25_dense_topk_auto — a Mosaic failure is catchable here).
+    gates hold, XLA gather form otherwise. Runs EAGERLY (a Mosaic
+    failure is catchable here).
 
     codes: i32[W, M] PQ code rows of the candidates (gathered upstream)
     luts:  f32[T, M, K] per-token ADC tables (ops.pq.adc_lut per token)
     Returns f32[W] MaxSim scores (max over tokens of the table-sum).
 
     ESTPU_MAXSIM_KERNEL: auto (default) | pallas | xla — the A/B knob
-    for the re-rank stage, mirroring ESTPU_BM25_BATCH_KERNEL.
+    for the re-rank stage.
     """
     from elasticsearch_tpu.utils.shapes import round_up
 

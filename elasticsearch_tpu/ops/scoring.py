@@ -159,54 +159,21 @@ def topk_auto(x, k: int, block: int = 0):
     return exact_topk(x, k, block) if block else lax.top_k(x, k)
 
 
-_PRECS = {"highest": lax.Precision.HIGHEST, "high": lax.Precision.HIGH,
-          "default": lax.Precision.DEFAULT}
-_PREC_WARNED = False
-
-
-def impact_precision() -> str:
-    """f32 impact-matmul precision knob (``ESTPU_IMPACT_PRECISION``):
-    "highest" (default — exactness tests rely on it; on TPU it is the
-    multi-pass f32 emulation), "high" (3-pass), or "default" (native
-    bf16 MXU pass — fastest, ranking-grade). Read OUTSIDE jit and plumbed
-    as a static arg / program-cache key, exactly like topk_block_config —
-    an env read inside traced code would be frozen by the first trace."""
-    v = os.environ.get("ESTPU_IMPACT_PRECISION", "highest").lower()
-    if v in _PRECS:
-        return v
-    global _PREC_WARNED
-    if not _PREC_WARNED:
-        import warnings
-
-        warnings.warn(f"ESTPU_IMPACT_PRECISION={v!r} is not one of "
-                      f"{sorted(_PRECS)}; using 'highest'")
-        _PREC_WARNED = True
-    return "highest"
-
-
 def _dense_dot(qw, dense_impact, prec: str = "highest"):
     """qw @ impact with dtype-aware MXU mapping: an f32 block multiplies at
-    the configured precision (HIGHEST by default — exactness tests rely on
-    it); a bf16 block (segment's ESTPU_IMPACT_BF16 storage) takes the
+    HIGHEST precision (the index states float32 BM25; exactness tests rely
+    on it); a bf16 block (segment's ESTPU_IMPACT_BF16 storage) takes the
     native bf16 MXU path with f32 accumulation — no upcast copy of the
-    block in HBM."""
+    block in HBM. ``prec`` admits only "highest", the one precision there
+    is: the yardstick's compile test
+    (tests/bench_harness/test_bench_tpu_compile.py) passes it by position,
+    and only a `benchmark` PR may edit that file."""
+    if prec != "highest":
+        raise ValueError(f"impact products are f32-exact; got prec={prec!r}")
     if dense_impact.dtype == jnp.bfloat16:
         return jnp.dot(qw.astype(jnp.bfloat16), dense_impact,
                        preferred_element_type=jnp.float32)
-    return jnp.dot(qw, dense_impact,
-                   precision=_PRECS.get(prec, lax.Precision.HIGHEST))
-
-
-@partial(jax.jit, static_argnames=("P", "D", "prec"))
-def bm25_score_hybrid(
-    dense_impact, qw, doc_ids, tfnorm, starts, lens, weights, *, P: int,
-    D: int, prec: str = "highest"
-):
-    """Single-query hybrid BM25: qw f32[F] (idf*boost per dense term) scores
-    frequent terms via one matvec; starts/lens/weights i32/f32[T] are the
-    short-run tail. Returns f32[D]."""
-    dense = _dense_dot(qw, dense_impact, prec)
-    return dense + bm25_score_segment(doc_ids, tfnorm, starts, lens, weights, P=P, D=D)
+    return jnp.dot(qw, dense_impact, precision=lax.Precision.HIGHEST)
 
 
 def _fold_dense_rows(dense_impact, qrows, init, step):
@@ -267,17 +234,17 @@ def bm25_score_hybrid_gather(dense_impact, qrows, qrw, doc_ids, tfnorm,
     """Single-query hybrid BM25 reading ONLY the query's dense rows.
 
     ``qrows`` i32[R] are the query's dense-row indices (-1 padding),
-    ``qrw`` f32[R] the matching idf*boost weights (0 padding). The matmul
-    form (`bm25_score_hybrid`) reads the WHOLE impact[F, D] block per
-    query — 1 GiB at F 64, D 2^22 — where this reads the query's real
-    rows one by one (:func:`_fold_dense_rows`: no ``[R, D]`` copy, no
+    ``qrw`` f32[R] the matching idf*boost weights (0 padding). A matmul
+    ``qw[F] @ impact[F, D]`` reads the WHOLE block per query — 1 GiB at
+    F 64, D 2^22 — where this reads the query's real rows one by one
+    (:func:`_fold_dense_rows`: no ``[R, D]`` copy, no
     read for a padding row). Measured on a v5e at that shape (PERF.md
     §6, PR 28): 0.19 ms a real row — its 8-row tile group, 134 MB — so
     ~0.4 ms for the average query's 2 rows, where the
     ``dense_impact[rows]`` + einsum form this replaced took ~3 ms
     whatever the query. The dense part is the in-order f32 sum
     ``Σ_r qrw[r]·row_r`` — plain f32 multiplies and adds, so it agrees
-    with the matmul form's multi-pass emulation to fp rounding."""
+    with the batched matmul's multi-pass emulation to fp rounding."""
     dense = _dense_row_score(dense_impact, qrows, qrw)
     return dense + bm25_score_segment(doc_ids, tfnorm, starts, lens,
                                       weights, P=P, D=D)
@@ -303,26 +270,14 @@ def pack_dense_rows(row_w: dict):
     return qrows, qrw
 
 
-@jax.jit
-def gather_impact_rows(dense_impact, qrows):
-    """(the query's rows [R, D], valid f32[R]) for feeding batched kernels
-    a compact per-query block: row r of the result is the block's row
-    ``qrows[r]``, copied by :func:`_fold_dense_rows` (the real rows are
-    read, never the whole block); the padding rows behind them stay zero
-    and carry validity 0 so presence counts ignore them."""
-    R, D = qrows.shape[0], dense_impact.shape[1]
-    sub = _fold_dense_rows(
-        dense_impact, qrows, jnp.zeros((R, D), dense_impact.dtype),
-        lambda acc, r, row: lax.dynamic_update_slice_in_dim(
-            acc, row[None], r, axis=0))
-    return sub, (qrows >= 0).astype(jnp.float32)
-
-
 @partial(jax.jit, static_argnames=("P", "D"))
 def match_count_hybrid_gather(dense_impact, qrows, doc_ids, starts, lens,
                               *, P: int, D: int):
-    """Matched-term count via the query's dense rows (row-read analogue of
-    match_count_hybrid; padding rows are masked by qrows >= 0)."""
+    """Matched-term count: the query's dense rows with a non-zero impact
+    (padding rows are masked by qrows >= 0) + the scatter tail's count.
+    Only conjunctive queries (operator:and / minimum_should_match) pay
+    for this second pass over the rows — disjunctions derive their mask
+    from scores directly."""
     return (_dense_row_count(dense_impact, qrows)
             + match_count_segment(doc_ids, starts, lens, P=P, D=D))
 
@@ -330,71 +285,38 @@ def match_count_hybrid_gather(dense_impact, qrows, doc_ids, starts, lens,
 @partial(jax.jit, static_argnames=("P", "D"))
 def term_mask_hybrid_gather(dense_impact, qrows, doc_ids, starts, lens,
                             *, P: int, D: int):
-    """Any-term match mask via the query's dense rows (row-read analogue
-    of term_mask_hybrid)."""
+    """bool[D] any-of mask across the query's dense rows + CSR tail."""
     return (_dense_row_mask(dense_impact, qrows)
             | term_mask(doc_ids, starts, lens, P=P, D=D))
 
 
-@partial(jax.jit, static_argnames=("P", "D", "prec"))
+@partial(jax.jit, static_argnames=("P", "D"))
 def bm25_score_hybrid_batch(
     dense_impact, qw, doc_ids, tfnorm, starts, lens, weights, *, P: int,
-    D: int, prec: str = "highest"
+    D: int
 ):
     """Batched hybrid BM25: ONE MXU matmul ``qw[Q, F] @ impact[F, D]`` for
     frequent terms (replacing what would be millions of scatter-adds for long
     postings runs) + the scatter kernel on the [Q, T] tail. Returns f32[Q, D]."""
-    dense = _dense_dot(qw, dense_impact, prec)
+    dense = _dense_dot(qw, dense_impact)
     return dense + bm25_score_batch(doc_ids, tfnorm, starts, lens, weights, P=P, D=D)
 
 
-@partial(jax.jit, static_argnames=("P", "D", "k", "topk_block", "prec"))
+@partial(jax.jit, static_argnames=("P", "D", "k", "topk_block"))
 def bm25_hybrid_topk_batch(dense_impact, qw, doc_ids, tfnorm, starts, lens,
                            weights, live, *, P: int, D: int, k: int,
-                           topk_block: int = 0, prec: str = "highest"):
+                           topk_block: int = 0):
     """Batched hybrid top-k: scores via bm25_score_hybrid_batch, then the
     per-query masked top-k and exact totals in the SAME program, so the
     [Q, D] score block never leaves the device. For all-positive
     disjunctive term groups, score > 0 is exactly 'matched'. Returns
     (vals f32[Q, k], idx i32[Q, k], totals i32[Q])."""
     scores = bm25_score_hybrid_batch(dense_impact, qw, doc_ids, tfnorm,
-                                     starts, lens, weights, P=P, D=D,
-                                     prec=prec)
+                                     starts, lens, weights, P=P, D=D)
     m = (scores > 0) & live[None, :]
     masked = jnp.where(m, scores, NEG_INF)
     vals, idx = topk_auto(masked, k, topk_block)
     return vals, idx.astype(jnp.int32), jnp.sum(m.astype(jnp.int32), axis=1)
-
-
-@partial(jax.jit, static_argnames=("P", "D"))
-def match_count_hybrid(dense_impact, qind, doc_ids, starts, lens, *, P: int, D: int):
-    """Matched-term count: qind f32[F] is the 1.0 indicator of dense query
-    terms; dense count = qind @ (impact != 0). Only conjunctive queries
-    (operator:and / minimum_should_match) pay for this second pass over the
-    impact block — disjunctions derive their mask from scores directly."""
-    present = (dense_impact != 0).astype(jnp.float32)
-    dcount = jnp.dot(qind, present, precision=lax.Precision.HIGHEST)
-    tail = match_count_segment(doc_ids, starts, lens, P=P, D=D)
-    return jnp.rint(dcount).astype(jnp.int32) + tail
-
-
-@partial(jax.jit, static_argnames=("P", "D"))
-def term_mask_hybrid(dense_impact, qind, doc_ids, starts, lens, *, P: int, D: int):
-    """bool[D] any-of mask across dense rows (qind indicator) + CSR tail."""
-    present = (dense_impact != 0).astype(jnp.float32)
-    dmask = jnp.dot(qind, present, precision=lax.Precision.DEFAULT) > 0
-    return dmask | term_mask(doc_ids, starts, lens, P=P, D=D)
-
-
-@jax.jit
-def dense_presence_count(impact, qind, live):
-    """Exact hit count for a pure-dense term group: docs where ANY dense
-    query row (qind f32[1, F] indicator) has a non-zero impact, ANDed with
-    the live mask. One [1, F] @ [F, D] matvec — the fused top-k fast path
-    uses this for `hits.total` without materializing per-doc scores twice."""
-    present = (impact != 0).astype(jnp.float32)
-    m = (jnp.dot(qind, present, precision=lax.Precision.DEFAULT) > 0)[0] & live
-    return jnp.sum(m.astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -638,12 +560,11 @@ def term_mask_hybrid_lookup(dense_impact, qrows, doc_ids, starts, lens,
             | term_mask_lookup(doc_ids, starts, lens, P=P, D=D))
 
 
-@partial(jax.jit, static_argnames=("P", "D", "k", "topk_block", "prec"))
+@partial(jax.jit, static_argnames=("P", "D", "k", "topk_block"))
 def bm25_hybrid_candidates_topk_batch(dense_impact, qw, doc_ids, tfnorm,
                                       starts, lens, weights, live, *,
                                       P: int, D: int, k: int,
-                                      topk_block: int = 0,
-                                      prec: str = "highest"):
+                                      topk_block: int = 0):
     """Batched hybrid top-k with a scatter-free tail (batch analogue of
     bm25_hybrid_candidates_topk; same contract as bm25_hybrid_topk_batch).
 
@@ -655,7 +576,7 @@ def bm25_hybrid_candidates_topk_batch(dense_impact, qw, doc_ids, tfnorm,
     vectorized. Returns (vals [Q, k], idx [Q, k], totals [Q]).
     """
     Q, T = starts.shape
-    dense = _dense_dot(qw, dense_impact, prec)  # [Q, D]
+    dense = _dense_dot(qw, dense_impact)  # [Q, D]
     dense_m = jnp.where(live[None, :], dense, 0.0)
 
     def window(starts_q, lens_q, ws_q):
@@ -768,6 +689,19 @@ def topk_batch(scores, mask, *, k: int):
                                topk_block=topk_block_config())
 
 
+def dense_topk_batch(qw, dense_impact, live, *, k: int, chunk_q: int = 256):
+    """Top-k of a batch of all-dense term groups: ``qw[Q, F] @
+    impact[F, D]`` → live mask → top-k, Q swept in ``chunk_q`` slices so
+    the transient [chunk, D] score block stays bounded (Q=2048, D=1M in
+    one piece would be 8 GB). Non-live docs carry -inf. Returns
+    (vals f32[Q, k], idx i32[Q, k])."""
+    outs = [topk_batch(_dense_dot(qw[q0:q0 + chunk_q], dense_impact), live,
+                       k=k)
+            for q0 in range(0, qw.shape[0], chunk_q)]
+    return (jnp.concatenate([v for v, _ in outs], axis=0),
+            jnp.concatenate([i for _, i in outs], axis=0))
+
+
 @jax.jit
 def count_mask(mask):
     return jnp.sum(mask.astype(jnp.int32))
@@ -801,27 +735,37 @@ def unpack_topk_result(packed_np, k: int):
 # one program a search segment (the host loop's query phase)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("k", "topk_block", "with_mask"))
-def finish_topk(scores, mask, live, roots=None, min_score=None, *, k: int,
-                topk_block: int, with_mask: bool = False):
-    """Everything the query phase does after a query's score program, as
-    ONE program: ``mask & live (& roots) (& scores >= min_score)`` →
-    exact total → masked top-k → the packed i32[2k+1] of
-    :func:`pack_topk_result`. Run eagerly these are five or six enqueues
-    a segment, each leaving and re-taking the interpreter's lock.
+@jax.jit
+def hit_mask(scores, mask, live, roots=None, min_score=None):
+    """The query phase's mask rule, written once: ``mask & live (& roots)
+    (& scores >= min_score)`` and its exact count.
 
-    ``roots`` (bool[D], a segment with nested documents) and
-    ``min_score`` (f32 scalar) are None when the request has none; being
-    there or not is part of the program's key, their values are not.
-    Returns (packed, final mask when ``with_mask`` — aggregations collect
-    over it — else None). Composed of the staged jits themselves, so
-    values, indices and tie order are theirs bit for bit."""
+    ``roots`` (bool[D], a segment with nested documents: top-level hits
+    are root docs only — nested children are reachable solely through
+    nested queries/aggs, like Lucene's block-join) and ``min_score`` (f32
+    scalar) are None when the request has none; being there or not is
+    part of the program's key, their values are not. Returns
+    (mask bool[D], total i32)."""
     mask = mask & live
     if roots is not None:
         mask = mask & roots
     if min_score is not None:
         mask = mask & (scores >= min_score)
-    total = jnp.sum(mask.astype(jnp.int32))
+    return mask, jnp.sum(mask.astype(jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("k", "topk_block", "with_mask"))
+def finish_topk(scores, mask, live, roots=None, min_score=None, *, k: int,
+                topk_block: int, with_mask: bool = False):
+    """Everything the query phase does after a query's score program, as
+    ONE program: :func:`hit_mask` → masked top-k → the packed i32[2k+1]
+    of :func:`pack_topk_result`. Run eagerly these are five or six
+    enqueues a segment, each leaving and re-taking the interpreter's lock.
+
+    Returns (packed, final mask when ``with_mask`` — aggregations collect
+    over it — else None). Composed of the staged jits themselves, so
+    values, indices and tie order are theirs bit for bit."""
+    mask, total = hit_mask(scores, mask, live, roots, min_score)
     vals, idx = _topk_with_mask_jit(scores, mask, k=k, topk_block=topk_block)
     return pack_topk_result(vals, idx, total), (mask if with_mask else None)
 

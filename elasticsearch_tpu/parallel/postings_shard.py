@@ -38,7 +38,8 @@ for an oversized field — InvertedField's lazy accessors keep the padded
 host mirrors and only device_put on explicit access by a fallback path
 (phrase/positional programs, terms aggs over the field). Pure-dense
 disjunctive queries may still serve via the budget-capped dense impact
-block (fused_bm25_topk), which never materializes the postings arrays.
+block (the batched tier, fused_bm25_topk_batch), which never materializes
+the postings arrays.
 
 Reference behavior analogue: an ES shard too big for one node is split by
 _reindexing_ into more shards; a TPU segment too big for one chip is

@@ -33,7 +33,6 @@ import numpy as np
 from elasticsearch_tpu.ops.scoring import (
     bm25_score_hybrid_gather,
     bm25_score_segment,
-    dense_presence_count,
     match_count_hybrid_gather,
     match_count_segment,
     range_mask_f32,
@@ -43,7 +42,7 @@ from elasticsearch_tpu.ops.scoring import (
 )
 from elasticsearch_tpu.search.context import SegmentContext
 from elasticsearch_tpu.search.scripting import compile_script
-from elasticsearch_tpu.tracing.tracer import span, tag_active
+from elasticsearch_tpu.tracing.tracer import span
 from elasticsearch_tpu.utils.dates import parse_date
 from elasticsearch_tpu.utils.errors import QueryParsingException
 
@@ -161,8 +160,8 @@ def _score_term_group(ctx, field, terms, boost=1.0, with_counts=False) -> Tuple[
 class TermGroupPlan(NamedTuple):
     """Host half of a plain search over a pure disjunctive term group on
     one segment — analysis, term lookup and the slice tables — built ONCE
-    by :func:`plan_term_group` and handed to whichever single-program
-    path serves it."""
+    by :func:`plan_term_group` for :func:`term_group_topk`. An all-dense
+    group is the same shape with an empty tail (T 1, lens 0)."""
 
     inv: Any
     impact: Any  # None (with qrows, qrw): no query term has a dense row
@@ -172,9 +171,6 @@ class TermGroupPlan(NamedTuple):
     lens: Any
     ws: Any
     P: int
-    # every present term rides a dense row (no scatter tail): the shape
-    # fused_bm25_topk serves
-    all_dense: bool
 
 
 def plan_term_group(ctx, query) -> Optional[TermGroupPlan]:
@@ -192,53 +188,9 @@ def plan_term_group(ctx, query) -> Optional[TermGroupPlan]:
         hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
         if hyb is None:  # no dense block / no dense query term
             starts, lens, ws, P, _n = ctx.chunked_slices(inv, tlist, wlist)
-            return TermGroupPlan(inv, None, None, None, starts, lens, ws, P,
-                                 False)
-        impact, _qw, _qind, starts, lens, ws, P, n_present, qrows, qrw = hyb
-        return TermGroupPlan(inv, impact, qrows, qrw, starts, lens, ws, P,
-                             n_present > 0 and int(np.sum(lens)) == 0)
-
-
-def fused_bm25_topk(ctx, plan: TermGroupPlan, k: int):
-    """Fused dense-impact BM25 top-k fast path (the Pallas streaming kernel
-    on TPU via ops.pallas_kernels.bm25_dense_topk_auto — no [Q, D] or [D]
-    score intermediate in HBM).
-
-    For an ``all_dense`` plan: a pure disjunctive term group (match with
-    operator:or / term on a text field, positive boost) whose present terms
-    ALL map to dense impact rows — then top-k comes straight off the
-    impact[F, D] matmul and `hits.total` from one presence matvec.
-    Returns (vals f32[k], ids i32[k], total int). Scores match
-    bm25_score_hybrid's dense branch exactly (same matmul); non-matches
-    carry score <= 0.
-    """
-    from elasticsearch_tpu.monitor import kernels
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
-
-    from elasticsearch_tpu.ops.scoring import (gather_impact_rows,
-                                               pack_topk_result,
-                                               unpack_topk_result)
-
-    jnp = _jnp()
-    live = ctx.segment.live
-    kk = min(k, ctx.D)
-    # stream only the query's R << F dense rows through the kernel — the
-    # full block would cost an F-row HBM read per query (same traffic cut
-    # as bm25_score_hybrid_gather; the [R, D] gather is a one-off
-    # intermediate two orders smaller than the block)
-    with span("device.dispatch", program="bm25_fused_topk"):
-        sub, qvalid = gather_impact_rows(plan.impact, jnp.asarray(plan.qrows))
-        vals, ids = bm25_dense_topk_auto(jnp.asarray(plan.qrw[None, :]), sub,
-                                         live, k=kk)
-        kernels.record("bm25_fused_topk")
-        total = dense_presence_count(sub, qvalid[None, :], live)
-        packed_dev = pack_topk_result(vals[0], ids[0], total)
-    # ONE packed pull — three tiny arrays would cost three device
-    # round-trips (network-attached chips: ~5-20 ms each)
-    with span("device.wait"):
-        packed = np.asarray(packed_dev)
-        tag_active(bytes=packed.nbytes)
-    return unpack_topk_result(packed, kk)
+            return TermGroupPlan(inv, None, None, None, starts, lens, ws, P)
+        impact, _qw, _qind, starts, lens, ws, P, _n, qrows, qrw = hyb
+        return TermGroupPlan(inv, impact, qrows, qrw, starts, lens, ws, P)
 
 
 def term_group_topk(ctx, plan: TermGroupPlan, k: int):
@@ -322,8 +274,8 @@ def fused_bm25_topk_batch(ctx, queries: List[Query], k: int):
 
     Returns (vals f32[Q, k], ids i32[Q, k], totals i32[Q]) or None when any
     query can't batch (the caller falls back to per-query execution). This
-    is the product path behind `_msearch` batching — the per-query
-    equivalent of fused_bm25_topk, amortizing dispatch across the batch.
+    is the product path behind `_msearch` batching, amortizing dispatch
+    across the batch.
     """
     with span("search.plan"):
         planned = _plan_fused_batch(ctx, queries)
@@ -332,15 +284,15 @@ def fused_bm25_topk_batch(ctx, queries: List[Query], k: int):
     impact, qw, qind = planned
     Q = len(queries)
     from elasticsearch_tpu.monitor import kernels
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
-    from elasticsearch_tpu.ops.scoring import dense_presence_count_batch
+    from elasticsearch_tpu.ops.scoring import (dense_presence_count_batch,
+                                               dense_topk_batch)
 
     jnp = _jnp()
     live = ctx.segment.live
     D = ctx.D
     with span("device.dispatch", program="batch_bm25_fused"):
-        vals, ids = bm25_dense_topk_auto(jnp.asarray(qw), impact, live,
-                                         k=min(k, D))
+        vals, ids = dense_topk_batch(jnp.asarray(qw), impact, live,
+                                     k=min(k, D))
         kernels.record("bm25_fused_topk", Q)
         chunk = D if D < (1 << 15) else (1 << 15)
         totals = _tier_program("batch_presence_count",
@@ -413,12 +365,10 @@ def hybrid_bm25_topk_batch(ctx, queries: List[Query], k: int,
     jnp = _jnp()
     live = ctx.segment.live
     kk = min(k, ctx.D)
-    from elasticsearch_tpu.ops.scoring import (impact_precision,
-                                               topk_block_config)
+    from elasticsearch_tpu.ops.scoring import topk_block_config
 
     blk = topk_block_config()  # once per batch: every chunk must compile
     # against the SAME static block even if the env flips mid-batch
-    _prec = impact_precision()
     # tail dispatch, once per batch: the scatter-free candidate form on
     # TPU (the vmapped scatter serializes Q·T·P slots), scatter elsewhere
     scatter_free = tail_mode_batch()
@@ -433,7 +383,7 @@ def hybrid_bm25_topk_batch(ctx, queries: List[Query], k: int,
                 impact, jnp.asarray(qw[q0:q1]), inv.doc_ids, inv.tfnorm,
                 jnp.asarray(starts[q0:q1]), jnp.asarray(lens[q0:q1]),
                 jnp.asarray(ws[q0:q1]), live, P=P, D=ctx.D, k=kk,
-                topk_block=blk, prec=_prec)
+                topk_block=blk)
         with span("device.wait"):
             return tuple(np.asarray(a) for a in got)
 

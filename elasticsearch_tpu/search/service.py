@@ -20,14 +20,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from elasticsearch_tpu.ops.scoring import (finish_topk, topk_block_config,
-                                           topk_with_mask,
+from elasticsearch_tpu.ops.scoring import (finish_topk, hit_mask,
+                                           topk_block_config, topk_with_mask,
                                            unpack_topk_result)
 from elasticsearch_tpu.search.aggregations import parse_aggs, reduce_aggs, run_aggs
 from elasticsearch_tpu.search.context import GlobalStats, SegmentContext
 from elasticsearch_tpu.search.highlight import extract_query_terms, highlight_field
-from elasticsearch_tpu.search.queries import (fused_bm25_topk, parse_query,
-                                              plan_term_group,
+from elasticsearch_tpu.search.queries import (parse_query, plan_term_group,
                                               term_group_topk)
 from elasticsearch_tpu.utils.errors import SearchParseException
 
@@ -104,7 +103,6 @@ class ShardSearcher:
 
     def query_phase(self, body: dict, global_stats: Optional[GlobalStats] = None,
                     collect_full: bool = False) -> QueryPhaseResult:
-        jnp = _jnp()
         from elasticsearch_tpu.search.joins import prepare_tree
 
         # ?profile=true: per-phase timing with device compile/execute
@@ -202,8 +200,7 @@ class ShardSearcher:
         # finishing program a segment (ops.scoring.finish_topk)
         plain = not sort_spec and full_snap is None
         # single-program paths: eligible request shapes hand the whole
-        # segment to one program (hybrid_fused_topk, fused_bm25_topk,
-        # term_group_topk)
+        # segment to one program (hybrid_fused_topk, term_group_topk)
         fused_ok = (plain and not aggs and min_score is None
                     and search_after is None and not rescore_specs
                     and not collect_full)
@@ -254,19 +251,6 @@ class ShardSearcher:
                 # a pure disjunctive term group under a plain request is
                 # planned ONCE and served by a single-program path
                 plan = plan_term_group(ctx, query) if fused_ok else None
-                if plan is not None and plan.all_dense \
-                        and not seg.has_nested:
-                    vals, ids, seg_total = _dev(
-                        lambda: fused_bm25_topk(ctx, plan, kk), "topk")
-                    total += seg_total
-                    for v, i in zip(vals, ids):
-                        # matches score strictly > 0; the live mask maps
-                        # non-matches to -inf or a 0.0 dense row
-                        if np.isfinite(v) and v > 0:
-                            max_score = max(max_score, float(v))
-                            docs.append(ShardDoc(self.shard_ord, seg,
-                                                 int(i), float(v)))
-                    continue
                 if plan is not None:
                     packed_dev = _dev(lambda: term_group_topk(ctx, plan, kk),
                                       "topk")
@@ -285,20 +269,14 @@ class ShardSearcher:
                                 with_mask=bool(aggs)), "topk")
                     else:
                         # the sorted and scroll-snapshot branches go on
-                        # composing [D] vectors: eager mask ops, each its
-                        # own enqueue
+                        # composing [D] vectors: the mask rule and its
+                        # count as one program, no top-k
                         with _p("device.dispatch", program="mask_ops"):
-                            mask = mask & seg.live
-                            if seg.has_nested:
-                                # top-level hits are root docs only; nested
-                                # children are reachable solely through
-                                # nested queries/aggs (reference: Lucene
-                                # block-join — nested docs hidden from root
-                                # searches)
-                                mask = mask & seg.roots_dev
-                            if min_score is not None:
-                                mask = mask & (scores >= float(min_score))
-                            tot_dev = jnp.sum(mask.astype(jnp.int32))
+                            mask, tot_dev = hit_mask(
+                                scores, mask, seg.live,
+                                seg.roots_dev if seg.has_nested else None,
+                                None if min_score is None
+                                else float(min_score))
                 if aggs:
                     with _p(None, "aggs"):
                         agg_partials.append(run_aggs(aggs, ctx, mask))

@@ -66,5 +66,5 @@ def test_cpu_rehearsal_passes_end_to_end(tmp_path):
     width = result["width"]
     assert width["loaded_by"] == "segment_loader" and width["interpreted"]
     assert set(width["kernels"]) == {
-        "bm25_dense_topk Q=8", "bm25_dense_topk Q=256", "knn_topk Q=8 k=64",
-        "knn_topk Q=256 k=40", "adc_scores", "maxsim_adc"}
+        "knn_topk Q=8 k=64", "knn_topk Q=256 k=40", "adc_scores",
+        "maxsim_adc"}

@@ -9,8 +9,7 @@ block (ops/scoring.py ``_dense_rows``; PR 28).
     The topology is described inside a fixture, never at import.
 (b) On the CPU: the in-order f32 row sum against a float64 numpy sum, and the
     one program's packed words against the staged sequence, bit for bit.
-(c) The selecting and comparing sites give what the index form gave, exactly
-    (but for the padding rows of ``gather_impact_rows``: zero, not row 0).
+(c) The selecting and comparing sites give what the index form gave, exactly.
 """
 import os
 
@@ -199,11 +198,9 @@ def test_one_program_is_bitwise_the_staged_row_read(R, n_real, dtype):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("R,n_real", ROW_CASES)
 def test_selecting_sites_equal_the_index_form(R, n_real, dtype):
-    """gather_impact_rows, match_count_hybrid_gather and
-    term_mask_hybrid_gather only select and compare: what
-    ``impact[max(qrows, 0)]`` gave, they give."""
-    from elasticsearch_tpu.ops.scoring import (gather_impact_rows,
-                                               match_count_hybrid_gather,
+    """match_count_hybrid_gather and term_mask_hybrid_gather only select
+    and compare: what ``impact[max(qrows, 0)]`` gave, they give."""
+    from elasticsearch_tpu.ops.scoring import (match_count_hybrid_gather,
                                                match_count_segment,
                                                term_mask,
                                                term_mask_hybrid_gather)
@@ -213,15 +210,6 @@ def test_selecting_sites_equal_the_index_form(R, n_real, dtype):
     doc_ids, _tfn, starts, lens, _ws, P = _tail(seed=n_real)
     old_rows = np.asarray(impact)[np.maximum(qrows, 0)]  # [R, D]
     old_present = (old_rows != 0) & (qrows >= 0)[:, None]
-
-    # the index form filled the padding rows with copies of row 0 (validity
-    # 0); the row read leaves them zero and does not read for them
-    sub, valid = gather_impact_rows(impact, qrows)
-    assert sub.dtype == impact.dtype and sub.shape == (R, D)
-    np.testing.assert_array_equal(np.asarray(sub)[:n_real], old_rows[:n_real])
-    assert not np.asarray(sub)[n_real:].astype(np.float32).any()
-    np.testing.assert_array_equal(np.asarray(valid),
-                                  (qrows >= 0).astype(np.float32))
 
     tail_count = np.asarray(match_count_segment(doc_ids, starts, lens,
                                                 P=P, D=D))

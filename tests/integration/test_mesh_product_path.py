@@ -305,25 +305,25 @@ def test_mesh_hybrid_path_actually_used(dense_node):
     assert snap.get("bm25_hybrid", 0) >= 1, snap
 
 
-def test_host_fused_bm25_topk_used(dense_node):
-    """With the mesh off, a pure-dense term group must serve through the
-    fused Pallas/XLA top-k (queries.fused_bm25_topk) — and agree with the
-    mesh answer (mesh_vs_host above covers the equivalence)."""
+def test_host_all_dense_group_takes_the_one_program(dense_node):
+    """With the mesh off, a pure-dense term group is served by the same
+    one program as a group with a sparse tail term
+    (queries.term_group_topk) — and agrees with the mesh answer
+    (mesh_vs_host above covers the equivalence)."""
     from elasticsearch_tpu.monitor import kernels
 
     os.environ["ESTPU_DISABLE_MESH"] = "1"
     try:
-        kernels.reset()
-        r = dense_node.search("dn", {"query": {"term": {"body": "common"}}})
-        assert r["hits"]["total"] == 1536
-        snap = kernels.snapshot()
-        assert snap.get("bm25_fused_topk", 0) >= 1, snap
-        # a query with a sparse tail term must fall through to the generic
-        # score/mask path (not the fused kernel)
-        kernels.reset()
-        r = dense_node.search("dn", {"query": {"match": {"body": "common emu"}}})
-        assert r["hits"]["total"] == 1536
-        assert kernels.snapshot().get("bm25_fused_topk", 0) == 0
+        for query in ({"term": {"body": "common"}},
+                      {"match": {"body": "common emu"}}):
+            kernels.reset()
+            r = dense_node.search("dn", {"query": query})
+            assert r["hits"]["total"] == 1536
+            snap = kernels.snapshot()
+            assert snap.get("bm25_one_program", 0) >= 1, snap
+            assert snap.get("bm25_one_program") == snap.get("bm25_hybrid")
+            # (the batched tier's count: no single search raises it)
+            assert snap.get("bm25_fused_topk", 0) == 0, snap
     finally:
         del os.environ["ESTPU_DISABLE_MESH"]
 

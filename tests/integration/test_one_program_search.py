@@ -89,6 +89,8 @@ def test_the_index_has_several_segments(node):
 
 
 TERM_GROUPS = {
+    # every present term has a dense row: the tail is empty (T 1, lens 0)
+    "all_dense": {"match": {"body": "alpha beta"}},
     "hybrid": {"match": {"body": "alpha rare3"}},
     "hybrid_three_terms": {"match": {"body": "beta rare3 rare7"}},
     "scatter_only": {"match": {"body": "rare3 rare5"}},
@@ -130,13 +132,16 @@ def test_a_term_group_is_one_dispatch_and_one_pull_a_segment(node, name):
         <= {h["_id"] for h in by_n["hits"]["hits"]}
 
 
-def test_the_counter_is_the_prometheus_series(node):
+@pytest.mark.parametrize("index,name", [("op", "hybrid"),
+                                        ("op", "all_dense"),
+                                        ("nest", "all_dense")])
+def test_the_counter_is_the_prometheus_series(node, index, name):
     # (the series appears with its first count: make sure it has one)
-    _search(node, "op", {"query": TERM_GROUPS["hybrid"]})
+    _search(node, index, {"query": TERM_GROUPS[name]})
     text = node.metrics.expose()
     before = [ln for ln in text.splitlines() if ln.startswith(
         'estpu_kernel_dispatch_total{kernel="bm25_one_program"}')]
-    _search(node, "op", {"query": TERM_GROUPS["hybrid"]})
+    _search(node, index, {"query": TERM_GROUPS[name]})
     after = [ln for ln in node.metrics.expose().splitlines()
              if ln.startswith(
                  'estpu_kernel_dispatch_total{kernel="bm25_one_program"}')]
@@ -147,8 +152,6 @@ def test_the_counter_is_the_prometheus_series(node):
 
 OTHER_SHAPES = {
     # name: (body, programs a segment)
-    "all_dense_match": ({"query": {"match": {"body": "alpha beta"}}},
-                        ["bm25_fused_topk"]),
     "bool": ({"query": {"bool": {
         "must": [{"match": {"body": "alpha"}}],
         "filter": [{"range": {"n": {"gte": 10}}}]}}},
@@ -185,7 +188,7 @@ def test_other_shapes_do_not_count_as_one_program(node, name):
         assert out["hits"]["total"] < loose["hits"]["total"]
 
 
-@pytest.mark.parametrize("name", ["hybrid", "scatter_only"])
+@pytest.mark.parametrize("name", ["all_dense", "hybrid", "scatter_only"])
 def test_nested_segments_take_the_one_program_with_their_roots(node, name):
     body = {"query": TERM_GROUPS[name], "size": 9}
     out, spans, rise = _search(node, "nest", body)
@@ -207,8 +210,10 @@ def test_a_child_field_term_group_finds_no_root(node):
     assert rise == N_SEGMENTS and out["hits"]["total"] == 0
 
 
-def test_a_deleted_document_leaves_the_one_program_result(node):
-    body = {"query": {"match": {"body": "gamma rare2"}}, "size": 5}
+@pytest.mark.parametrize("text", ["gamma rare2", "gamma delta"],
+                         ids=["hybrid", "all_dense"])
+def test_a_deleted_document_leaves_the_one_program_result(node, text):
+    body = {"query": {"match": {"body": text}}, "size": 5}
     out, _s, _r = _search(node, "op", body)
     top = out["hits"]["hits"][0]["_id"]
     svc = node.indices["op"]
@@ -245,7 +250,7 @@ def test_repeating_a_shape_compiles_nothing(node):
     assert retrace.traces_since(snap) == 0
 
 
-@pytest.mark.parametrize("name", ["hybrid", "scatter_only"])
+@pytest.mark.parametrize("name", ["all_dense", "hybrid", "scatter_only"])
 def test_the_one_program_takes_one_host_argument(node, name, monkeypatch):
     """Everything but the packed word buffer is already on the device:
     one host→device copy a search segment (five before)."""
@@ -267,9 +272,40 @@ def test_the_one_program_takes_one_host_argument(node, name, monkeypatch):
     for args, statics in calls:
         host = [a for a in args if isinstance(a, np.ndarray)]
         assert len(host) == 1 and host[0].dtype == np.int32
-        assert host[0].shape == (2 * statics["R"] + 3 * statics["T"],)
+        R, T = statics["R"], statics["T"]
+        assert host[0].shape == (2 * R + 3 * T,)
+        if name == "all_dense" and R:
+            assert T == 1 and not host[0][2 * R + T: 2 * R + 2 * T].any()
         assert all(a is None or isinstance(a, jax.Array)
                    for a in args if a is not host[0])
     # (such a one-document segment has no dense block)
     dense = sum(statics["R"] > 0 for _a, statics in calls)
     assert dense == (0 if name == "scatter_only" else N_SEGMENTS)
+
+
+def test_no_switch_chooses_the_arithmetic():
+    """A plain term-group search has one arithmetic: the two environment
+    variables that chose a less exact one are read nowhere in the
+    package, and ops/pallas_kernels.py holds no BM25 kernel whose use an
+    environment variable could decide."""
+    import os
+    import re
+
+    import elasticsearch_tpu
+
+    root = os.path.dirname(elasticsearch_tpu.__file__)
+    gone = re.compile("ESTPU_BM25_BATCH_KERNEL|ESTPU_IMPACT_PRECISION")
+    found = []
+    for dirpath, _dirs, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(dirpath, f)
+                with open(path) as fh:
+                    found += [(path, n) for n, line in enumerate(fh, 1)
+                              if gone.search(line)]
+    assert not found
+    with open(os.path.join(root, "ops", "pallas_kernels.py")) as fh:
+        text = fh.read()
+    assert "bm25" not in text.lower()
+    reads = re.findall(r"os\.environ[^\n]*", text)
+    assert reads and all("ESTPU_MAXSIM_KERNEL" in r for r in reads)

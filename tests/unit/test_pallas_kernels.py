@@ -73,55 +73,6 @@ def test_auto_dispatch_falls_back_on_cpu():
     assert vals.shape == (2, 3) and idx.shape == (2, 3)
 
 
-# -- fused dense-impact BM25 kernel (round-2) ---------------------------------
-
-def test_pallas_bm25_dense_topk_matches_xla():
-    import jax.numpy as jnp
-    from jax import lax
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_pallas
-
-    rng = np.random.default_rng(5)
-    Q, F, D, k = 8, 64, 4096, 10
-    # sparse nonneg impacts (tfnorm-like), sparse query weights (idf*boost)
-    impact = (rng.random((F, D)) < 0.05).astype(np.float32) * rng.random((F, D)).astype(np.float32) * 2.5
-    qw = np.zeros((Q, F), np.float32)
-    for i in range(Q):
-        terms = rng.choice(F, size=4, replace=False)
-        qw[i, terms] = rng.random(4) * 3.0
-    mask = rng.random(D) > 0.05
-
-    pv, pi = bm25_dense_topk_pallas(jnp.asarray(qw), jnp.asarray(impact),
-                                    jnp.asarray(mask), k=k, tile=1024,
-                                    q_tile=8, interpret=True)
-    scores = jnp.dot(jnp.asarray(qw), jnp.asarray(impact),
-                     precision=lax.Precision.HIGHEST)
-    masked = jnp.where(jnp.asarray(mask)[None, :], scores, -jnp.inf)
-    ev, ei = lax.top_k(masked, k)
-    pv, pi, ev, ei = map(np.asarray, (pv, pi, ev, ei))
-    np.testing.assert_allclose(pv, ev, rtol=5e-3, atol=5e-3)
-    recall = np.mean([len(set(pi[i]) & set(ei[i])) / k for i in range(Q)])
-    assert recall >= 0.95
-    assert not np.isin(pi, np.nonzero(~mask)[0]).any()
-    assert (np.diff(pv, axis=1) <= 1e-6).all()
-
-
-def test_bm25_dense_topk_auto_xla_fallback():
-    # CPU (no TPU): auto path must take XLA and give exact results
-    import jax.numpy as jnp
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_auto
-
-    rng = np.random.default_rng(6)
-    Q, F, D, k = 3, 16, 512, 5
-    impact = rng.random((F, D)).astype(np.float32)
-    qw = rng.random((Q, F)).astype(np.float32)
-    mask = np.ones(D, bool)
-    vals, idx = bm25_dense_topk_auto(jnp.asarray(qw), jnp.asarray(impact),
-                                     jnp.asarray(mask), k=k)
-    exact = np.asarray(qw @ impact)
-    want = np.argsort(-exact, axis=1)[:, :k]
-    assert (np.asarray(idx) == want).all()
-
-
 def test_knn_auto_pads_small_q():
     # Q=1 must not crash on the padded path (CPU takes XLA anyway; this
     # asserts the pad/slice contract via the pallas kernel in interpret)
@@ -139,37 +90,6 @@ def test_knn_auto_pads_small_q():
                              tile=2048, interpret=True)
     ev, ei = _exact_topk(q, v, mask, k, "cosine")
     assert len(set(np.asarray(pi)[0]) & set(ei[0])) >= 4
-
-
-def test_bm25_dense_topk_early_exit_tie_parity():
-    """The early-exit while-loop selection must match lax.top_k over the
-    dense bf16 score row exactly — including id order under heavy exact
-    ties (quantized impacts), fully-masked regions, and a dense cluster
-    competing for every slot late in the sweep."""
-    import jax.numpy as jnp
-    import numpy as np
-    from jax import lax
-
-    from elasticsearch_tpu.ops.pallas_kernels import bm25_dense_topk_pallas
-
-    rng = np.random.default_rng(3)
-    Q, F, D, k = 16, 16, 4096, 10
-    for quant in (0.05, 1.0, 0.5):  # 1.0 → near-total tie rows
-        qw = (rng.random((Q, F)) * 2).astype(np.float32)
-        impact = rng.random((F, D)).astype(np.float32)
-        impact = (impact / quant).round() * quant
-        mask = rng.random(D) > 0.3
-        mask[:600] = False
-        v, i = bm25_dense_topk_pallas(
-            jnp.asarray(qw), jnp.asarray(impact), jnp.asarray(mask),
-            k=k, tile=512, q_tile=8, interpret=True)
-        sc = np.asarray(jnp.dot(jnp.asarray(qw).astype(jnp.bfloat16),
-                                jnp.asarray(impact).astype(jnp.bfloat16),
-                                preferred_element_type=jnp.float32))
-        sc = np.where(mask[None, :], sc, -np.inf)
-        wv, wi = lax.top_k(jnp.asarray(sc), k)
-        np.testing.assert_allclose(np.asarray(v), np.asarray(wv), rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(i), np.asarray(wi))
 
 
 def test_scoped_vmem_exhaustion_latches_as_a_compile_error():

@@ -220,16 +220,17 @@ def test_knn_l2_and_dot():
 
 
 def test_hybrid_dense_sparse_matches_pure_scatter():
-    """Hybrid (dense matmul + scatter tail) == pure scatter == numpy oracle
-    on a synthetic corpus large enough to produce dense rows."""
+    """Hybrid (dense rows / dense matmul + scatter tail) == pure scatter ==
+    numpy oracle on a synthetic corpus large enough to produce dense rows."""
     from elasticsearch_tpu.index.segment import build_dense_impact
     from elasticsearch_tpu.ops.scoring import (
-        bm25_score_hybrid,
         bm25_score_hybrid_batch,
+        bm25_score_hybrid_gather,
         bm25_score_segment,
-        match_count_hybrid,
+        match_count_hybrid_gather,
+        pack_dense_rows,
         term_mask,
-        term_mask_hybrid,
+        term_mask_hybrid_gather,
     )
 
     rng = np.random.default_rng(7)
@@ -262,15 +263,16 @@ def test_hybrid_dense_sparse_matches_pure_scatter():
     weights = [1.5, 0.7, 2.0, 1.1]
     F = impact.shape[0]
     qw = np.zeros(F, np.float32)
-    qind = np.zeros(F, np.float32)
+    row_w = {}
     runs = []
     for t, w in zip(qterms, weights):
         row = int(dense_rows[t])
         if row >= 0:
             qw[row] += w
-            qind[row] = 1.0
+            row_w[row] = row_w.get(row, 0.0) + w
         else:
             runs.append((int(offsets[t]), int(df[t]), w))
+    qrows, qrw = pack_dense_rows(row_w)
     P = pow2_bucket(max((ln for _, ln, _ in runs), default=1))
     T = pow2_bucket(max(len(runs), 1))
     starts = np.zeros(T, np.int32)
@@ -285,9 +287,10 @@ def test_hybrid_dense_sparse_matches_pure_scatter():
         s, e = int(offsets[t]), int(offsets[t + 1])
         want[u_doc[s:e]] += w * tfn[s:e]
 
-    got_h = bm25_score_hybrid(
-        impact, qw, d_doc, d_tfn, starts, lens, ws, P=P, D=D)
-    counts = match_count_hybrid(impact, qind, d_doc, starts, lens, P=P, D=D)
+    got_h = bm25_score_hybrid_gather(
+        impact, qrows, qrw, d_doc, d_tfn, starts, lens, ws, P=P, D=D)
+    counts = match_count_hybrid_gather(impact, qrows, d_doc, starts, lens,
+                                       P=P, D=D)
     np.testing.assert_allclose(np.asarray(got_h), want, rtol=1e-5, atol=1e-5)
 
     got_b = bm25_score_hybrid_batch(
@@ -311,7 +314,8 @@ def test_hybrid_dense_sparse_matches_pure_scatter():
     np.testing.assert_array_equal(np.asarray(counts), want_counts)
 
     # any-of mask
-    got_m = term_mask_hybrid(impact, qind, d_doc, starts, lens, P=P, D=D)
+    got_m = term_mask_hybrid_gather(impact, qrows, d_doc, starts, lens,
+                                    P=P, D=D)
     np.testing.assert_array_equal(np.asarray(got_m), want_counts > 0)
     got_m2 = term_mask(d_doc, st2, ln2, P=P2, D=D)
     np.testing.assert_array_equal(np.asarray(got_m2), want_counts > 0)
@@ -399,57 +403,16 @@ def test_blocked_topk_env_product_equivalence(monkeypatch):
         == [(h["_id"], round(h["_score"], 5)) for h in r2["hits"]["hits"]]
 
 
-def test_impact_precision_knob(monkeypatch):
-    """ESTPU_IMPACT_PRECISION plumbs as a static arg (cache-key safe) and
-    serves identical results on CPU, where precision hints are no-ops;
-    a bad value warns once and falls back to highest."""
-    import warnings
-
-    from elasticsearch_tpu.node import Node
-    from elasticsearch_tpu.ops import scoring
-
-    monkeypatch.setattr(scoring, "_PREC_WARNED", False)
-    monkeypatch.setenv("ESTPU_IMPACT_PRECISION", "turbo")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert scoring.impact_precision() == "highest"
-        assert len(w) == 1 and "turbo" in str(w[0].message)
-
-    import random
-
-    rng = random.Random(7)
-    docs = {str(i): {"body": " ".join(rng.choices(
-        ["ant", "bee", "cat", "dog"], k=6))} for i in range(300)}
-    results = []
-    for prec in ("highest", "default"):
-        monkeypatch.setenv("ESTPU_IMPACT_PRECISION", prec)
-        n = Node()
-        try:
-            n.create_index("ip", {"mappings": {"properties": {
-                "body": {"type": "text"}}}})
-            for i, src in docs.items():
-                n.indices["ip"].index_doc(i, src)
-            n.indices["ip"].refresh()
-            r = n.search("ip", {"query": {"match": {"body": "ant bee"}},
-                                "size": 10})
-            results.append([(h["_id"], round(h["_score"], 5))
-                            for h in r["hits"]["hits"]])
-        finally:
-            n.close()
-    assert results[0] == results[1]
-
-
-def test_gather_hybrid_matches_matmul_hybrid():
-    """The row-gather single-query forms (bm25_score_hybrid_gather /
+def test_gather_hybrid_matches_scatter_over_all_terms():
+    """The row-read single-query forms (bm25_score_hybrid_gather /
     match_count_hybrid_gather / term_mask_hybrid_gather) produce the same
-    scores/counts/masks as the full-block matmul forms — they read only
-    the query's R dense rows where the matmul reads all F (the r5
-    single-query latency lever)."""
+    scores/counts/masks as the scatter forms run over ALL the query's
+    terms' postings — a reference that never touches the dense block."""
     from elasticsearch_tpu.index.segment import build_dense_impact
     from elasticsearch_tpu.ops.scoring import (
-        bm25_score_hybrid, bm25_score_hybrid_gather, match_count_hybrid,
-        match_count_hybrid_gather, pack_dense_rows, term_mask_hybrid,
-        term_mask_hybrid_gather)
+        bm25_score_hybrid_gather, bm25_score_segment,
+        match_count_hybrid_gather, match_count_segment, pack_dense_rows,
+        term_mask, term_mask_hybrid_gather)
 
     rng = np.random.default_rng(11)
     n_docs, vocab = 512, 64
@@ -475,16 +438,11 @@ def test_gather_hybrid_matches_matmul_hybrid():
 
     qterms = [0, 1, 2, 40, 63]
     weights = [1.5, 0.7, 0.9, 2.0, 1.1]
-    F = impact.shape[0]
-    qw = np.zeros(F, np.float32)
-    qind = np.zeros(F, np.float32)
     row_w = {}
     runs = []
     for t, w in zip(qterms, weights):
         row = int(dense_rows[t])
         if row >= 0:
-            qw[row] += w
-            qind[row] = 1.0
             row_w[row] = row_w.get(row, 0.0) + w
         else:
             runs.append((int(offsets[t]), int(df[t]), w))
@@ -499,23 +457,60 @@ def test_gather_hybrid_matches_matmul_hybrid():
     for i, (s, ln, w) in enumerate(runs):
         starts[i], lens[i], ws[i] = s, ln, w
 
-    want = np.asarray(bm25_score_hybrid(
-        impact, qw, d_doc, d_tfn, starts, lens, ws, P=P, D=D))
+    # the reference: every term, dense or not, as a postings run
+    st2 = np.array([offsets[t] for t in qterms], np.int32)
+    ln2 = np.array([df[t] for t in qterms], np.int32)
+    ws2 = np.array(weights, np.float32)
+    P2 = pow2_bucket(int(ln2.max()))
+
+    want = np.asarray(bm25_score_segment(d_doc, d_tfn, st2, ln2, ws2,
+                                         P=P2, D=D))
     got = np.asarray(bm25_score_hybrid_gather(
         impact, qrows, qrw, d_doc, d_tfn, starts, lens, ws, P=P, D=D))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    want_c = np.asarray(match_count_hybrid(
-        impact, qind, d_doc, starts, lens, P=P, D=D))
+    want_c = np.asarray(match_count_segment(d_doc, st2, ln2, P=P2, D=D))
     got_c = np.asarray(match_count_hybrid_gather(
         impact, qrows, d_doc, starts, lens, P=P, D=D))
     np.testing.assert_array_equal(got_c, want_c)
 
-    want_m = np.asarray(term_mask_hybrid(
-        impact, qind, d_doc, starts, lens, P=P, D=D))
+    want_m = np.asarray(term_mask(d_doc, st2, ln2, P=P2, D=D))
     got_m = np.asarray(term_mask_hybrid_gather(
         impact, qrows, d_doc, starts, lens, P=P, D=D))
     np.testing.assert_array_equal(got_m, want_m)
+
+
+@pytest.mark.parametrize("block_dtype", ["float32", "bfloat16"])
+def test_dense_topk_batch_matches_numpy(block_dtype):
+    """The batched tier's all-dense top-k (qw[Q, F] @ impact[F, D] → live
+    mask → top-k, Q swept in chunks) against a float64 numpy product:
+    same ids in the same order, dead docs never returned, every chunk
+    (the last one ragged) in its place. A bf16-stored block multiplies in
+    bf16 with f32 sums, like every other reader of it."""
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.scoring import dense_topk_batch
+
+    rng = np.random.default_rng(6)
+    Q, F, D, k = 5, 16, 512, 5
+    impact = jnp.asarray(rng.random((F, D)).astype(np.float32),
+                         dtype=block_dtype)
+    qw = rng.random((Q, F)).astype(np.float32)
+    live = rng.random(D) > 0.1
+    vals, idx = dense_topk_batch(jnp.asarray(qw), impact, jnp.asarray(live),
+                                 k=k, chunk_q=2)
+    vals, idx = np.asarray(vals), np.asarray(idx)
+    assert vals.shape == idx.shape == (Q, k) and idx.dtype == np.int32
+    lhs = qw if block_dtype == "float32" else np.asarray(
+        jnp.asarray(qw).astype(jnp.bfloat16).astype(jnp.float32))
+    exact = lhs.astype(np.float64) @ np.asarray(
+        impact.astype(jnp.float32)).astype(np.float64)
+    exact = np.where(live[None, :], exact, -np.inf)
+    want = np.argsort(-exact, axis=1, kind="stable")[:, :k]
+    np.testing.assert_array_equal(idx, want)
+    np.testing.assert_allclose(vals, np.take_along_axis(exact, want, axis=1),
+                               rtol=1e-6)
+    assert live[idx].all()
 
 
 def test_candidates_topk_matches_scatter_path():
