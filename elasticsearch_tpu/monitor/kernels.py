@@ -20,6 +20,11 @@ Names:
                       by ONE packed argument (ops/scoring.
                       bm25_term_group_topk); counted BESIDE the
                       bm25_hybrid / bm25_scatter count of the same segment
+  tail_window_slots   slots of the [T, P] postings windows those programs
+                      scattered, valid or not (T·P a segment): what the
+                      tail costs the device
+  tail_window_postings  real postings in those windows (Σ lens): what the
+                      tail is for; postings / slots is the window's fill
   bm25_postings_sharded  oversized field scored via the cross-device
                       postings split + psum merge (parallel/postings_shard)
   knn_fused_topk      fused scores+mask+topk (Pallas on TPU, XLA elsewhere);

@@ -105,6 +105,17 @@ class NumDocsPrim(DataPrim):
         return cache(key, fill), ()
 
 
+def stacked_nnz(seg_row, field: str) -> int:
+    """Length of the stacked [S, nnz] postings of one field: the pow2
+    bucket of the longest shard's padded postings."""
+    nnz = 1
+    for seg in seg_row:
+        inv = seg.inverted.get(field) if seg is not None else None
+        if inv is not None:
+            nnz = max(nnz, inv.nnz_pad)
+    return pow2_bucket(nnz)
+
+
 class PostingsPrim(DataPrim):
     """Stacked postings of one field: doc_ids [S, nnz] (pad → D sentinel),
     tfnorm [S, nnz]."""
@@ -115,12 +126,7 @@ class PostingsPrim(DataPrim):
         self.field = field
 
     def build(self, seg_row, ctxs, D, S, cache):
-        nnz = 1
-        for seg in seg_row:
-            inv = seg.inverted.get(self.field) if seg is not None else None
-            if inv is not None:
-                nnz = max(nnz, inv.nnz_pad)
-        nnz = pow2_bucket(nnz)
+        nnz = stacked_nnz(seg_row, self.field)
 
         def fill():
             h_doc = np.full((S, nnz), D, np.int32)
@@ -155,10 +161,9 @@ class TGroupPrim(DataPrim):
         self.terms_fn = terms_fn
 
     def build(self, seg_row, ctxs, D, S, cache):
-        from elasticsearch_tpu.search.context import split_runs
+        from elasticsearch_tpu.search.context import stack_chunk_tables
 
         per_shard = []
-        Pmax, Tmax = 1, 1
         for seg, ctx in zip(seg_row, ctxs):
             inv = seg.inverted.get(self.field) if seg is not None else None
             runs = []
@@ -167,19 +172,10 @@ class TGroupPrim(DataPrim):
                 for t, w in zip(terms, weights):
                     s, ln = inv.term_slice(t)
                     runs.append((s, ln, w))
-            starts, lens, ws, max_len = split_runs(runs) if runs else ([], [], [], 1)
-            Pmax = max(Pmax, pow2_bucket(max_len))
-            Tmax = max(Tmax, len(starts))
-            per_shard.append((starts, lens, ws))
-        T = pow2_bucket(Tmax, minimum=1) if Tmax else 1
-        h_starts = np.zeros((S, T), np.int32)
-        h_lens = np.zeros((S, T), np.int32)
-        h_ws = np.zeros((S, T), np.float32)
-        for si, (st, ln, ws) in enumerate(per_shard):
-            h_starts[si, : len(st)] = st
-            h_lens[si, : len(ln)] = ln
-            h_ws[si, : len(ws)] = ws
-        return [h_starts, h_lens, h_ws], (Pmax,)
+            per_shard.append(runs)
+        h_starts, h_lens, h_ws, P = stack_chunk_tables(
+            per_shard, stacked_nnz(seg_row, self.field))
+        return [h_starts, h_lens, h_ws], (P,)
 
 
 class HybridTGroupPrim(DataPrim):
@@ -204,7 +200,7 @@ class HybridTGroupPrim(DataPrim):
         self.terms_fn = terms_fn
 
     def build(self, seg_row, ctxs, D, S, cache):
-        from elasticsearch_tpu.search.context import split_runs
+        from elasticsearch_tpu.search.context import stack_chunk_tables
 
         blocks = []
         F = 8
@@ -229,7 +225,6 @@ class HybridTGroupPrim(DataPrim):
 
         per_shard = []
         row_ws: List[Dict[int, float]] = []
-        Pmax, Tmax = 1, 1
         for si, ((inv, blk), ctx) in enumerate(zip(blocks, ctxs)):
             runs = []
             row_w: Dict[int, float] = {}
@@ -246,14 +241,10 @@ class HybridTGroupPrim(DataPrim):
                     else:
                         s0 = int(inv.offsets[tid])
                         runs.append((s0, int(inv.offsets[tid + 1]) - s0, w))
-            starts, lens, ws, max_len = split_runs(runs) if runs else ([], [], [], 1)
-            Pmax = max(Pmax, pow2_bucket(max_len))
-            Tmax = max(Tmax, len(starts))
-            per_shard.append((starts, lens, ws))
+            per_shard.append(runs)
             row_ws.append(row_w)
         from elasticsearch_tpu.ops.scoring import pack_dense_rows
 
-        T = pow2_bucket(Tmax, minimum=1)
         # shared packing (ops/scoring.pack_dense_rows): per-shard R may
         # differ, so pack each then pad to the common pow2 R
         packed = [pack_dense_rows(rw) for rw in row_ws]
@@ -263,14 +254,9 @@ class HybridTGroupPrim(DataPrim):
         for si, (qr, qv) in enumerate(packed):
             h_qrows[si, : qr.shape[0]] = qr
             h_qrw[si, : qv.shape[0]] = qv
-        h_starts = np.zeros((S, T), np.int32)
-        h_lens = np.zeros((S, T), np.int32)
-        h_ws = np.zeros((S, T), np.float32)
-        for si, (st, ln, ws) in enumerate(per_shard):
-            h_starts[si, : len(st)] = st
-            h_lens[si, : len(ln)] = ln
-            h_ws[si, : len(ws)] = ws
-        return arrays + [h_qrows, h_qrw, h_starts, h_lens, h_ws], (Pmax, R)
+        h_starts, h_lens, h_ws, P = stack_chunk_tables(
+            per_shard, stacked_nnz(seg_row, self.field))
+        return arrays + [h_qrows, h_qrw, h_starts, h_lens, h_ws], (P, R)
 
 
 class RangePrim(DataPrim):

@@ -616,25 +616,24 @@ class MeshSearchExecutor:
 
         # shape buckets common across shards
         D = pow2_bucket(max((s.max_docs if s is not None else 1) for s in seg_row))
-        nnz = 1
-        for seg in seg_row:
-            inv = seg.inverted.get(field) if seg is not None else None
-            if inv is not None:
-                nnz = max(nnz, inv.nnz_pad)
-        nnz = pow2_bucket(nnz)
+        from elasticsearch_tpu.parallel.compiler import stacked_nnz
+        from elasticsearch_tpu.search.context import (chunk_count_bucket,
+                                                      split_runs, tail_width)
 
-        # per-shard chunk tables (vocab is shard-local)
-        tables = []  # (starts[Q,?], lens, weights) variable T, P
-        Pmax, Tmax = 1, 1
-        for seg in seg_row:
-            per_q = []
-            for terms in query_terms:
-                starts, lens, ws, P = _chunk_table(seg, field, terms)
-                Pmax = max(Pmax, P)
-                Tmax = max(Tmax, len(starts))
-                per_q.append((starts, lens, ws))
-            tables.append(per_q)
-        T = pow2_bucket(Tmax)
+        nnz = stacked_nnz(seg_row, field)
+
+        # per-shard chunk tables (vocab is shard-local), every run cut at
+        # the one width of the round
+        runs = [[_term_runs(seg, field, terms) for terms in query_terms]
+                for seg in seg_row]
+        P = tail_width(nnz, [r for per_q in runs for rs in per_q for r in rs])
+        # (starts[Q,?], lens, weights) variable T
+        tables = [[split_runs(rs, P) for rs in per_q] for per_q in runs]
+        Tmax = max((len(starts) for per_q in tables
+                    for starts, _l, _w in per_q), default=1)
+        # the floor keeps the short queries of a cold mesh in one class,
+        # where each T is a sharded program to compile for every Q bucket
+        T = chunk_count_bucket(Tmax, P, minimum=8)
         # pow2-bucket the query axis: Q rides the program cache key, so a
         # raw len() would mint one compiled program per distinct query
         # count (recompile storm). Padded query rows carry all-zero chunk
@@ -682,13 +681,13 @@ class MeshSearchExecutor:
                 h_ws[si, qi] = pad_t(ws, dtype=np.float32)
 
         prog = _bm25_program(self.mesh, self._programs,
-                             Q=Q, T=T, P=Pmax, D=D, k=min(k, D))
+                             Q=Q, T=T, P=P, D=D, k=min(k, D))
         from elasticsearch_tpu.monitor.programs import static_sig
 
         # nnz in the sig: the postings buffers are [S, nnz], so two nnz
         # classes are two distinct device programs — census keys must
         # separate them or warmup verification over-reports warm
-        sig = static_sig(S=self.S, Q=Q, T=T, P=Pmax, D=D, k=min(k, D),
+        sig = static_sig(S=self.S, Q=Q, T=T, P=P, D=D, k=min(k, D),
                          nnz=nnz)
         dev = (d_doc, d_tfn, put(h_starts), put(h_lens), put(h_ws),
                put(h_live))
@@ -1174,10 +1173,9 @@ def _segments_of(s) -> list:
     return [s]  # bare TpuSegment
 
 
-def _chunk_table(seg, field, terms):
-    """Shard-local chunk table for (term, boost) list; idf folded in."""
-    from elasticsearch_tpu.search.context import split_runs
-
+def _term_runs(seg, field, terms):
+    """Shard-local (start, len, weight) postings runs of a (term, boost)
+    list; idf folded in."""
     runs = []
     inv = seg.inverted.get(field) if seg is not None else None
     if inv is not None:
@@ -1185,8 +1183,7 @@ def _chunk_table(seg, field, terms):
             s, ln = inv.term_slice(term)
             if ln > 0:
                 runs.append((s, ln, inv.idf(term) * boost))
-    starts, lens, ws, max_len = split_runs(runs)
-    return starts, lens, ws, pow2_bucket(max_len)
+    return runs
 
 
 def _merge_rounds(a, b, k):

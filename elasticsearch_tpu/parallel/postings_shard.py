@@ -93,9 +93,10 @@ class PostingsShardSplit:
         """Route query terms to owning devices; returns per-device chunk
         tables (starts/lens i32[S, Tb], ws f32[S, Tb], P, n_present) with
         starts REBASED into each device's local postings slice."""
+        from elasticsearch_tpu.search.context import stack_chunk_tables
+
         per_dev: List[List[Tuple[int, int, float]]] = [[] for _ in range(self.S)]
         n_present = 0
-        max_run = 1
         for t, w in zip(terms, weights):
             tid = self._vocab.get(t, -1)
             if tid < 0:
@@ -106,24 +107,8 @@ class PostingsShardSplit:
             ln = int(self._offsets[tid + 1] - self._offsets[tid])
             if ln > 0:
                 per_dev[s].append((start, ln, float(w)))
-                max_run = max(max_run, ln)
-        # chunk to a power-of-two P so every (start, len) run fits one
-        # vmap slice (same bucketing contract as SegmentContext)
-        P = pow2_bucket(min(max_run, 1 << 14))
-        chunked: List[List[Tuple[int, int, float]]] = [[] for _ in range(self.S)]
-        for s, runs in enumerate(per_dev):
-            for start, ln, w in runs:
-                off = 0
-                while off < ln:
-                    chunked[s].append((start + off, min(P, ln - off), w))
-                    off += P
-        Tb = pow2_bucket(max((len(c) for c in chunked), default=1), minimum=1)
-        starts = np.zeros((self.S, Tb), np.int32)
-        lens = np.zeros((self.S, Tb), np.int32)
-        ws = np.zeros((self.S, Tb), np.float32)
-        for s, cs in enumerate(chunked):
-            for i, (st, ln, w) in enumerate(cs):
-                starts[s, i], lens[s, i], ws[s, i] = st, ln, w
+        # the same window as SegmentContext's, a device
+        starts, lens, ws, P = stack_chunk_tables(per_dev, self.L)
         return starts, lens, ws, P, n_present
 
     # -- compiled programs ------------------------------------------------

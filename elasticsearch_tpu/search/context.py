@@ -15,35 +15,79 @@ import numpy as np
 from elasticsearch_tpu.analysis.registry import AnalysisRegistry
 from elasticsearch_tpu.index.mappings import Mappings
 from elasticsearch_tpu.index.segment import InvertedField, NumericColumn, TpuSegment
-from elasticsearch_tpu.utils.shapes import pow2_bucket
+from elasticsearch_tpu.utils.shapes import half_step_bucket, pow2_bucket
 
-# cap on a single postings slice width; longer term runs are split into
-# multiple chunks (keeps the [T, P] intermediate bounded)
-P_MAX = 1 << 15
+# widest chunk of the tail's [T, P] postings window: a run longer than
+# this is cut into pieces of this one width, so a long run neither widens
+# the other terms' chunks past it nor gives the query a program class of
+# its own. The device pays per window slot, valid or not (PERF.md §6,
+# PR 30 has the readings for 2048 / 4096 / 8192)
+TAIL_W = 1 << 12
 
 
-def split_runs(runs):
-    """P_MAX-split raw (start, len, weight) postings runs.
+def tail_width(nnz_pad: int, runs) -> int:
+    """The window width P of a score program over these (start, len,
+    weight) runs: the pow2 bucket of the longest, so that a thousand
+    one-posting runs make a [1024, 8] window and not a [1024, 4096] one,
+    capped at ``TAIL_W``, and at the whole (pow2-padded) postings array
+    where a segment is smaller than one chunk — a ``dynamic_slice``
+    cannot be wider than its operand."""
+    longest = max((ln for _s, ln, _w in runs), default=0)
+    return min(TAIL_W, pow2_bucket(longest), nnz_pad)
 
-    Returns (starts, lens, ws, max_len); max_len is the window width P the
-    score program needs — a run split into full-width chunks forces P_MAX,
-    not just its tail length.
+
+def chunk_count_bucket(n: int, W: int, minimum: int = 1) -> int:
+    """The padded chunk count T of a window of ``n`` chunks W wide: off
+    the ``half_step_bucket`` ladder where the chunks are ``TAIL_W`` wide
+    (a padding chunk costs the device W slots), a power of two where the
+    runs were short enough for a narrower window — a padding chunk is
+    cheap there and a chunk count of its own is a program to compile."""
+    return (half_step_bucket if W >= TAIL_W else pow2_bucket)(n, minimum)
+
+
+def split_runs(runs, W: int):
+    """Cut raw (start, len, weight) postings runs into chunks of width W.
+
+    Returns (starts, lens, ws) lists, one entry a chunk, a run's chunks
+    adjacent and in order; every chunk but a run's last is W long. An
+    empty run keeps its one (start, 0) chunk.
     """
     starts, lens, ws = [], [], []
-    max_len = 1
     for s, ln, w in runs:
-        while ln > P_MAX:
-            starts.append(s)
-            lens.append(P_MAX)
-            ws.append(w)
-            s += P_MAX
-            ln -= P_MAX
-            max_len = P_MAX
-        starts.append(s)
-        lens.append(ln)
-        ws.append(w)
-        max_len = max(max_len, ln)
-    return starts, lens, ws, max_len
+        n = max(-(-ln // W), 1)
+        starts.extend(range(s, s + n * W, W))
+        lens.extend([W] * (n - 1))
+        lens.append(ln - (n - 1) * W)
+        ws.extend([w] * n)
+    return starts, lens, ws
+
+
+def chunk_table(runs, W: int):
+    """(starts i32[T], lens i32[T], ws f32[T]) of :func:`split_runs`, the
+    chunk count padded with (0, 0) chunks to its ``chunk_count_bucket``
+    T."""
+    starts, lens, ws = split_runs(runs, W)
+    pad = chunk_count_bucket(len(starts), W) - len(starts)
+    return (np.asarray(starts + [0] * pad, np.int32),
+            np.asarray(lens + [0] * pad, np.int32),
+            np.asarray(ws + [0.0] * pad, np.float32))
+
+
+def stack_chunk_tables(per_shard, nnz_pad: int):
+    """(starts i32[S, T], lens i32[S, T], ws f32[S, T], P) of S lists of
+    raw runs, one a shard of a mesh: every shard's runs cut at the
+    ``tail_width`` P of them all, T the ``chunk_count_bucket`` of the
+    longest table, shorter tables ending in (0, 0) chunks."""
+    P = tail_width(nnz_pad, [run for runs in per_shard for run in runs])
+    cut = [split_runs(runs, P) for runs in per_shard]
+    S = len(cut)
+    T = chunk_count_bucket(max(len(starts) for starts, _l, _w in cut), P)
+    out = (np.zeros((S, T), np.int32), np.zeros((S, T), np.int32),
+           np.zeros((S, T), np.float32))
+    for si, cols in enumerate(cut):
+        for stacked, col in zip(out, cols):
+            stacked[si, : len(col)] = col
+    return out + (P,)
 
 
 @dataclass
@@ -100,10 +144,11 @@ class SegmentContext:
         return self.analysis.get(fm.search_analyzer or fm.analyzer)
 
     def chunked_slices(self, inv: InvertedField, terms, weights):
-        """Split (term -> postings run) into P-bucketed chunks.
+        """Split (term -> postings run) into chunks of one width.
 
         Returns (starts i32[Tb], lens i32[Tb], w f32[Tb], P, n_real_terms)
-        where Tb is a pow2 bucket. Terms absent from the segment contribute
+        where P is the runs' ``tail_width`` and Tb the chunk count's
+        ``chunk_count_bucket``. Terms absent from the segment contribute
         (0, 0) chunks. n_real_terms counts distinct terms present.
         """
         runs = []
@@ -113,19 +158,9 @@ class SegmentContext:
             if ln > 0:
                 n_present += 1
             runs.append((s, ln, w))
-        starts, lens, ws, max_len = split_runs(runs)
-        P = pow2_bucket(max_len)
-        Tb = pow2_bucket(len(starts), minimum=1)
-        starts += [0] * (Tb - len(starts))
-        lens += [0] * (Tb - len(lens))
-        ws += [0.0] * (Tb - len(ws))
-        return (
-            np.asarray(starts, np.int32),
-            np.asarray(lens, np.int32),
-            np.asarray(ws, np.float32),
-            P,
-            n_present,
-        )
+        P = tail_width(inv.nnz_pad, runs)
+        starts, lens, ws = chunk_table(runs, P)
+        return starts, lens, ws, P, n_present
 
     def hybrid_slices(self, inv: InvertedField, terms, weights,
                       need_qw: bool = True):
@@ -172,22 +207,7 @@ class SegmentContext:
                              int(inv.offsets[tid + 1] - inv.offsets[tid]), w))
         if not row_w:
             return None
-        starts, lens, ws, max_len = split_runs(runs) if runs else ([], [], [], 1)
-        P = pow2_bucket(max_len)
-        Tb = pow2_bucket(max(len(starts), 1), minimum=1)
-        starts += [0] * (Tb - len(starts))
-        lens += [0] * (Tb - len(lens))
-        ws += [0.0] * (Tb - len(ws))
+        P = tail_width(inv.nnz_pad, runs)
+        starts, lens, ws = chunk_table(runs, P)
         qrows, qrw = pack_dense_rows(row_w)
-        return (
-            impact,
-            qw,
-            qind,
-            np.asarray(starts, np.int32),
-            np.asarray(lens, np.int32),
-            np.asarray(ws, np.float32),
-            P,
-            n_present,
-            qrows,
-            qrw,
-        )
+        return impact, qw, qind, starts, lens, ws, P, n_present, qrows, qrw

@@ -207,12 +207,19 @@ def term_group_topk(ctx, plan: TermGroupPlan, k: int):
     kernels.record("bm25_hybrid" if plan.impact is not None
                    else "bm25_scatter")
     kernels.record("bm25_one_program")
+    # what the tail's scatter is paid for (every slot of the [T, P]
+    # window) beside what it is for (the real postings): their ratio is
+    # the window's fill
+    kernels.record("tail_window_slots", plan.starts.shape[0] * plan.P)
+    kernels.record("tail_window_postings", int(plan.lens.sum()))
     words = pack_term_group_words(plan.qrows, plan.qrw, plan.starts,
                                   plan.lens, plan.ws)
     with span("device.dispatch", program="bm25_term_group_topk"):
-        # R and T are the pow2 buckets hybrid_slices / chunked_slices pad
-        # the row list and the chunk table to: the staged programs' own
-        # shape classes  # tpulint: bucketed
+        # R is the pow2 bucket hybrid_slices pads the row list to, P the
+        # pow2 bucket of the longest run up to TAIL_W, T the chunk count's
+        # bucket (context.chunk_count_bucket: 1, 2, 3, 4, 6, 8, 12, … at
+        # TAIL_W, a pow2 under it): the staged programs' own shape
+        # classes  # tpulint: bucketed
         return bm25_term_group_topk(
             plan.impact, inv.doc_ids, inv.tfnorm, seg.live,
             seg.roots_dev if seg.has_nested else None, words,
@@ -244,7 +251,7 @@ def _fused_eligible_terms(ctx, query, idf: bool = True):
 
     ``idf=False`` keeps the weights idf-free (duplicate terms still merge
     additively): the mesh query-then-fetch path folds each SEGMENT's idf
-    inside the sharded program (executor._chunk_table), so handing it
+    inside the sharded program (executor._term_runs), so handing it
     pre-folded weights would double-count."""
     if isinstance(query, MatchQuery):
         if (query.operator != "or" or query.msm is not None

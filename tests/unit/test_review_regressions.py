@@ -101,16 +101,45 @@ def _mini_ctx(docs, mapping):
 def test_chunked_slices_p_covers_full_chunks(monkeypatch):
     import elasticsearch_tpu.search.context as C
 
-    monkeypatch.setattr(C, "P_MAX", 4)
+    monkeypatch.setattr(C, "TAIL_W", 4)
     docs = [{"t": "x"} for _ in range(10)]  # term "x" in 10 docs -> runs of 4,4,2
     ctx = _mini_ctx(docs, {"properties": {"t": {"type": "text"}}})
     inv = ctx.inv("t")
     starts, lens, ws, P, n = ctx.chunked_slices(inv, ["x"], [1.0])
-    assert P >= 4  # must cover the full-width chunks, not just the tail of 2
+    # the cap's width, whatever the run's tail of 2; three chunks are a
+    # bucket of their own (3), not padded to 4
+    assert P == 4 and lens.tolist() == [4, 4, 2]
+    assert (starts[1:] - starts[:-1]).tolist() == [4, 4]
     from elasticsearch_tpu.ops.scoring import match_count_segment
 
     counts = np.asarray(match_count_segment(inv.doc_ids, starts, lens, P=P, D=ctx.D))
     assert counts[:10].tolist() == [1] * 10
+
+
+@pytest.mark.parametrize("n_ids,absent", [(1000, 0), (600, 400), (40, 0)])
+def test_a_terms_filter_of_short_runs_keeps_a_narrow_window(n_ids, absent):
+    """A terms filter over a keyword field's ids (df 1 each) cuts a
+    [T, 8] window, as it did before the tail's width was capped at
+    TAIL_W — not a [T, TAIL_W] one — and still finds every document."""
+    import elasticsearch_tpu.search.context as C
+    from elasticsearch_tpu.search.queries import parse_query
+    from elasticsearch_tpu.utils.shapes import pow2_bucket
+
+    docs = [{"id": f"k{i:05d}", "t": "x"} for i in range(n_ids)]
+    ctx = _mini_ctx(docs, {"properties": {"id": {"type": "keyword"},
+                                          "t": {"type": "text"}}})
+    ids = [f"k{i:05d}" for i in range(n_ids)] + [
+        f"none{i}" for i in range(absent)]
+    inv = ctx.inv("id")
+    assert inv.nnz_pad > 8
+    starts, lens, _ws, P, n_present = ctx.chunked_slices(
+        inv, ids, [1.0] * len(ids))
+    assert P == 8 < C.TAIL_W and n_present == n_ids
+    # no more slots than the pow2 table of 8-wide chunks it was
+    assert starts.shape[0] * P <= pow2_bucket(len(ids)) * 8
+    assert int(lens.sum()) == n_ids
+    _scores, mask = parse_query({"terms": {"id": ids}}).execute(ctx)
+    assert int(np.asarray(mask).sum()) == n_ids
 
 
 def test_match_phrase_prefix_mixed_empty_expansion():

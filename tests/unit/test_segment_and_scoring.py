@@ -7,7 +7,7 @@ from elasticsearch_tpu.analysis.registry import AnalysisRegistry
 from elasticsearch_tpu.index.doc_parser import DocumentParser
 from elasticsearch_tpu.index.mappings import Mappings
 from elasticsearch_tpu.index.segment import SegmentBuilder, K1, B, split_i64
-from elasticsearch_tpu.utils.shapes import pow2_bucket
+from elasticsearch_tpu.utils.shapes import half_step_bucket, pow2_bucket
 
 DOCS = [
     "the quick brown fox jumps over the lazy dog",
@@ -566,16 +566,9 @@ def test_candidates_topk_matches_scatter_path():
         if not row_w:
             continue  # hybrid paths require >= 1 dense term
         qrows, qrw = pack_dense_rows(row_w)
-        from elasticsearch_tpu.search.context import split_runs
-        starts_l, lens_l, ws_l, max_len = (split_runs(runs) if runs
-                                           else ([], [], [], 1))
-        P = pow2_bucket(max_len)
-        T = pow2_bucket(max(len(starts_l), 1))
-        starts = np.zeros(T, np.int32)
-        lens = np.zeros(T, np.int32)
-        ws = np.zeros(T, np.float32)
-        for i, (s, ln, w) in enumerate(zip(starts_l, lens_l, ws_l)):
-            starts[i], lens[i], ws[i] = s, ln, w
+        from elasticsearch_tpu.search.context import chunk_table
+        P = 128  # narrower than the long runs: they split into chunks
+        starts, lens, ws = chunk_table(runs, P)
 
         # reference: full scatter score vector -> masked topk + count
         scores = np.asarray(bm25_score_hybrid_gather(
@@ -638,7 +631,7 @@ def test_candidates_topk_batch_matches_scatter_batch():
                [0, 1, 2, 3, 60, 61]]
     qw = np.zeros((len(batches), F), np.float32)
     all_runs = []
-    Pmax, Tmax = 1, 1
+    Pmax, Tmax = 128, 1  # narrower than the long runs: they split
     for qi, qterms in enumerate(batches):
         runs = []
         for i, t in enumerate(qterms):
@@ -648,11 +641,10 @@ def test_candidates_topk_batch_matches_scatter_batch():
                 qw[qi, row] += w
             else:
                 runs.append((int(offsets[t]), int(df[t]), w))
-        st, ln, ws_, mx = split_runs(runs) if runs else ([], [], [], 1)
-        Pmax = max(Pmax, pow2_bucket(mx))
+        st, ln, ws_ = split_runs(runs, Pmax)
         Tmax = max(Tmax, len(st))
         all_runs.append((st, ln, ws_))
-    T = pow2_bucket(max(Tmax, 1))
+    T = half_step_bucket(Tmax)
     starts = np.zeros((len(batches), T), np.int32)
     lens = np.zeros((len(batches), T), np.int32)
     ws = np.zeros((len(batches), T), np.float32)
@@ -685,7 +677,7 @@ def test_lookup_tail_matches_scatter_forms():
         bm25_score_segment, bm25_score_segment_lookup,
         match_count_segment, match_count_segment_lookup, term_mask,
         term_mask_lookup)
-    from elasticsearch_tpu.search.context import split_runs
+    from elasticsearch_tpu.search.context import chunk_table
 
     rng = np.random.default_rng(41)
     n_docs, vocab = 512, 32
@@ -710,14 +702,8 @@ def test_lookup_tail_matches_scatter_forms():
     for qterms in ([0, 1, 5, 30], [2], [0, 1, 2, 3, 4, 5, 6, 7]):
         runs = [(int(offsets[t]), int(df[t]), 1.0 + 0.25 * i)
                 for i, t in enumerate(qterms)]
-        st, ln, ws_, mx = split_runs(runs)
-        P = pow2_bucket(mx)
-        T = pow2_bucket(len(st))
-        starts = np.zeros(T, np.int32)
-        lens = np.zeros(T, np.int32)
-        ws = np.zeros(T, np.float32)
-        for i, (s, l, w) in enumerate(zip(st, ln, ws_)):
-            starts[i], lens[i], ws[i] = s, l, w
+        P = 128  # narrower than the long runs: they split into chunks
+        starts, lens, ws = chunk_table(runs, P)
         want = np.asarray(bm25_score_segment(
             d_doc, d_tfn, starts, lens, ws, P=P, D=D))
         got = np.asarray(bm25_score_segment_lookup(
@@ -741,7 +727,7 @@ def test_hybrid_lookup_matches_hybrid_gather():
         bm25_score_hybrid_gather, bm25_score_hybrid_lookup,
         match_count_hybrid_gather, match_count_hybrid_lookup,
         pack_dense_rows, term_mask_hybrid_gather, term_mask_hybrid_lookup)
-    from elasticsearch_tpu.search.context import split_runs
+    from elasticsearch_tpu.search.context import chunk_table
 
     rng = np.random.default_rng(47)
     n_docs, vocab = 512, 64
@@ -777,14 +763,8 @@ def test_hybrid_lookup_matches_hybrid_gather():
             runs.append((int(offsets[t]), int(df[t]), w))
     assert row_w and runs
     qrows, qrw = pack_dense_rows(row_w)
-    st, ln, ws_, mx = split_runs(runs)
-    P = pow2_bucket(mx)
-    T = pow2_bucket(len(st))
-    starts = np.zeros(T, np.int32)
-    lens = np.zeros(T, np.int32)
-    ws = np.zeros(T, np.float32)
-    for i, (s, l, w) in enumerate(zip(st, ln, ws_)):
-        starts[i], lens[i], ws[i] = s, l, w
+    P = 128  # narrower than the long runs: they split into chunks
+    starts, lens, ws = chunk_table(runs, P)
 
     want = np.asarray(bm25_score_hybrid_gather(
         impact, qrows, qrw, d_doc, d_tfn, starts, lens, ws, P=P, D=D))
@@ -842,7 +822,7 @@ def _term_group_tables(c, qterms, dense: bool):
     chunked_slices build them; ``dense`` False sends every term down the
     scatter tail (qrows, qrw None)."""
     from elasticsearch_tpu.ops.scoring import pack_dense_rows
-    from elasticsearch_tpu.search.context import split_runs
+    from elasticsearch_tpu.search.context import chunk_table
 
     row_w, runs = {}, []
     for i, t in enumerate(qterms):
@@ -852,14 +832,10 @@ def _term_group_tables(c, qterms, dense: bool):
             row_w[row] = row_w.get(row, 0.0) + w
         else:
             runs.append((int(c["offsets"][t]), int(c["df"][t]), w))
-    st, ln, ws_, mx = split_runs(runs) if runs else ([], [], [], 1)
-    T = pow2_bucket(max(len(st), 1))
-    starts = np.zeros(T, np.int32)
-    lens = np.zeros(T, np.int32)
-    ws = np.zeros(T, np.float32)
-    starts[:len(st)], lens[:len(ln)], ws[:len(ws_)] = st, ln, ws_
+    P = 128  # narrower than the long runs: they split into chunks
+    starts, lens, ws = chunk_table(runs, P)
     qrows, qrw = pack_dense_rows(row_w) if row_w else (None, None)
-    return qrows, qrw, starts, lens, ws, pow2_bucket(mx)
+    return qrows, qrw, starts, lens, ws, P
 
 
 TERM_GROUP_CASES = {

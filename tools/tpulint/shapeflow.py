@@ -29,8 +29,9 @@ invariants as four rules:
 
   - ``Concrete`` — literals and closure constants (`k = 10`);
   - ``PaddedPow2`` — produced by the padding helpers (`pow2_bucket`,
-    `round_up` — utils/shapes.py) or joins of padded values (`max` of
-    pow2 buckets is a pow2 bucket: the `Pmax` accumulation idiom);
+    `half_step_bucket`, `round_up` — utils/shapes.py; `chunk_count_bucket`,
+    which picks one of the two — search/context.py) or joins of padded
+    values (`max` of buckets is a bucket: the `Tmax` accumulation idiom);
   - ``DataDependent`` — derived from `len()`, `.shape`/`.size` of host
     data, dict sizes: an unbounded universe;
   - ``Unknown`` — no evidence either way (never alarms).
@@ -147,7 +148,8 @@ DimVal = Union[Dim, Tuple[Dim, ...]]
 # the result is PaddedPow2 regardless of the operand (utils/shapes.py;
 # name-matched so fixtures and future helpers with the same contract
 # participate without central registration).
-PAD_PRODUCER_NAMES = {"pow2_bucket", "round_up"}
+PAD_PRODUCER_NAMES = {"pow2_bucket", "half_step_bucket", "chunk_count_bucket",
+                      "round_up"}
 # min/max/arithmetic join operand classifications (max of pow2 buckets
 # is a pow2 bucket; min(k, D) is bounded by both operands' universes —
 # the join keeps the worst one, which is the conservative direction).
