@@ -1,27 +1,29 @@
 """Configuration file -> a Node serving it, by the file's ``kind``.
 
-Copied from ``bench.py`` (``make_msmarco_node``, ``make_sift_node``) and
-``chip_smoke.py:width_child``; this copy is now the yardstick — later PRs
-may change those two files, not this one. Data is loaded as one frozen
+``load`` resolves ``cfg["kind"]`` to the module ``benchmarks/kinds/<kind>.py``
+and calls its ``load(cfg, seed, devices, rehearse)``; there is no table of
+kinds beside the files. The kinds there were copied from ``bench.py``
+(``make_msmarco_node``, ``make_sift_node``) and
+``chip_smoke.py:width_child``; those copies are now the yardstick — later
+PRs may change those two files, not these. Data is loaded as one frozen
 segment a shard through the product's own structures (``InvertedField``,
 ``VectorColumn``, ``TpuSegment``) and appended to the shard's engine: the
 only way to a deployment-sized index inside a run (``_bulk`` ingests about
 1,100 documents a second). The write path is therefore not on the measured
 path, and every configuration file says so.
 
-A loader returns a ``Loaded``: the node, the index name, the pool of
-queries (``pool_size``, ``request(i)``), the plain reference over the
-generator's raw output (``reference``) and what the roofline needs to know
-of the work (``work(i)``, read from the resident arrays at run time).
+A kind returns a ``Loaded``: the node, the index name, the pool of requests
+(``pool_size``, ``request(i)``, ``path(i)``), what counts as an answer
+(``answer(reply)``), how a sample of answers is held to the plain reference
+over the generator's raw output (``compare(sample)``) and what takes the
+program's place one precision — or one guarantee — down (``control(pool)``),
+and what the roofline needs to know of the work (``work(i)``, read from the
+resident arrays at run time). The base class is a top-k of hits judged by
+``reference/check.py``: what the first two kinds are.
 """
 from __future__ import annotations
 
-import numpy as np
-
-from benchmarks.data import text as text_data
-from benchmarks.data import vectors as vector_data
-from benchmarks.reference.bm25 import Bm25Reference
-from benchmarks.reference.knn import KnnReference
+from benchmarks import byname
 
 
 class Loaded:
@@ -33,6 +35,40 @@ class Loaded:
 
     def request(self, i: int) -> dict:
         raise NotImplementedError
+
+    def path(self, i: int) -> str:
+        """Where pool entry ``i`` is sent as a single request."""
+        return f"/{self.index}/_search"
+
+    def answer(self, reply: dict):
+        """What of one reply is held to the reference, or None where the
+        reply is no valid answer (then the search is ``unanswered``)."""
+        if ("error" not in reply and not reply.get("timed_out")
+                and isinstance(reply.get("hits", {}).get("hits"), list)):
+            return reply["hits"]["hits"]
+        return None
+
+    def compare(self, sample: list) -> dict:
+        """``sample``: (pool index, answer) pairs -> {"numbers": {name:
+        value}, "faults": [the first few, in words], "compared": n}. Every
+        number needs a limit in the cell's file."""
+        from benchmarks.reference import check
+
+        return check.compare(self.reference, sample, self.k)
+
+    def control(self, pool: list) -> list:
+        """The control's answers to these pool entries, as ``compare``
+        takes them: the reference in the program's place, one precision
+        (or one stated guarantee) down. It has to come out not correct."""
+        from benchmarks.reference import check
+
+        return check.control_answers(self.reference, pool, self.k)
+
+    def group(self, name: str, arg) -> list:
+        """Pool entries of a named sort, for a warm-up that has to send
+        just those together (``warmup.batches.of`` of a traffic file). A
+        kind names its own; the base class knows none."""
+        raise ValueError(f"{type(self).__name__} has no group [{name}]")
 
     def work(self, i: int) -> dict:
         """{"flop": .., "bytes": ..} the chip cannot do without to answer
@@ -49,182 +85,13 @@ def _sized(cfg: dict, rehearse: bool) -> dict:
     return out
 
 
-# --------------------------------------------------------------------------
-# bm25_text_shard
-# --------------------------------------------------------------------------
-
-class TextShards(Loaded):
-    def __init__(self, cfg: dict, seed: int, devices, rehearse: bool):
-        import jax
-
-        from elasticsearch_tpu.index.segment import InvertedField, TpuSegment
-        from elasticsearch_tpu.node import Node
-        from elasticsearch_tpu.utils.shapes import pad_to, pow2_bucket
-
-        cfg = _sized(cfg, rehearse)
-        self.cfg = cfg
-        self.index, self.field = cfg["index"], cfg["field"]
-        self.size = self.k = int(cfg["size"])
-        n_shards = int(cfg["shards"])
-        n_docs, vocab = int(cfg["documents_per_shard"]), int(cfg["vocab"])
-        k1, b = float(cfg["bm25"]["k1"]), float(cfg["bm25"]["b"])
-        self.shards = [
-            text_data.make_corpus(
-                n_docs, vocab, seed if s == 0 else seed * 4 + s,
-                postings_per_doc=cfg["postings_per_doc"],
-                exponent=cfg["zipf_exponent"],
-                df_cap_share=cfg["df_cap_share"])
-            for s in range(n_shards)]
-        q = cfg["queries"]
-        # the pool is the configuration's (fixed seed): term ids are ranks
-        # of the df law, which no seed changes, so every run holds the same
-        # set of queries over another corpus
-        self.pool = text_data.make_queries(
-            self.shards[0], q["pool_seed"], n_queries=q["pool"],
-            min_terms=q["min_terms"], max_terms=q["max_terms"])
-        self.pool_size = len(self.pool)
-        self.reference = Bm25Reference(self.shards, k1, b, self.pool)
-
-        terms = [f"t{t}" for t in range(vocab)]
-        term_vocab = {t: i for i, t in enumerate(terms)}
-        D = pow2_bucket(n_docs, minimum=64)
-        node = Node(name="bench", data_path=cfg.get("data_path"))
-        node.create_index(self.index, {
-            "settings": {"number_of_shards": n_shards},
-            "mappings": {"properties": {self.field: {"type": "text"}}}})
-        self.posting_bytes = 0
-        for s, c in enumerate(self.shards):
-            dev = devices[s % len(devices)]
-            put = lambda a: jax.device_put(a, dev)  # noqa: E731
-            nnz_pad = pow2_bucket(c.nnz, minimum=8)
-            tf = c.tf.astype(np.float32)
-            avg = float(c.doc_len.mean())
-            # the engine's own derived column (the reference derives its
-            # own, in float64, from tf and doc_len)
-            tfn = (tf * (k1 + 1.0) / (tf + k1 * (
-                1.0 - b + b * c.doc_len[c.doc_ids].astype(np.float32) / avg))
-                   ).astype(np.float32)
-            term_ids = np.repeat(np.arange(vocab, dtype=np.int32), c.df)
-            inv = InvertedField(
-                name=self.field, vocab=term_vocab, terms=terms,
-                df=c.df.astype(np.int32), cf=c.df.astype(np.int64),
-                offsets=c.offsets,
-                doc_ids=put(pad_to(c.doc_ids, nnz_pad, D)),
-                tf=put(pad_to(tf, nnz_pad, 0.0)),
-                tfnorm=put(pad_to(tfn, nnz_pad, 0.0)),
-                term_ids=put(pad_to(term_ids, nnz_pad, vocab)),
-                nnz=c.nnz, num_docs=n_docs,
-                total_terms=int(c.doc_len.sum()), avg_len=avg,
-                doc_ids_host=c.doc_ids, tfnorm_host=tfn, max_docs=D)
-            self.posting_bytes = (inv.doc_ids.dtype.itemsize
-                                  + inv.tfnorm.dtype.itemsize)
-            lens = np.zeros(D, np.float32)
-            lens[:n_docs] = c.doc_len
-            base = s * n_docs
-            seg = TpuSegment(
-                num_docs=n_docs, max_docs=D,
-                inverted={self.field: inv}, numerics={}, keywords={},
-                vectors={}, sources=[None] * n_docs, stored=[None] * n_docs,
-                ids=[str(base + i) for i in range(n_docs)], id_map={},
-                field_lengths={self.field: put(lens)})
-            node.indices[self.index].shards[s].engine.segments.append(seg)
-        self.node = node
-        self.score_bytes = 4  # one f32 score a live document for the top-k
-        self.info = {"shards": n_shards, "documents_per_shard": n_docs,
-                     "slots_per_shard": D, "vocab": vocab,
-                     "postings": [c.nnz for c in self.shards],
-                     "postings_padded": pow2_bucket(self.shards[0].nnz, 8),
-                     "avg_len": float(self.shards[0].doc_len.mean())}
-
-    def request(self, i: int) -> dict:
-        return {"query": {"match": {self.field: " ".join(
-            f"t{t}" for t in self.pool[i])}},
-            "size": self.size, "_source": False}
-
-    def work(self, i: int) -> dict:
-        terms = self.pool[i]
-        postings = sum(int(c.df[terms].sum()) for c in self.shards)
-        docs = sum(c.n_docs for c in self.shards)
-        return {"flop": 2.0 * postings,
-                "bytes": float(postings * self.posting_bytes
-                               + docs * self.score_bytes),
-                "batch_bytes": 0.0}
-
-
-# --------------------------------------------------------------------------
-# dense_vector_shard
-# --------------------------------------------------------------------------
-
-class VectorShards(Loaded):
-    def __init__(self, cfg: dict, seed: int, devices, rehearse: bool):
-        from elasticsearch_tpu.index.segment import TpuSegment, VectorColumn
-        from elasticsearch_tpu.node import Node
-        from elasticsearch_tpu.utils.shapes import pow2_bucket
-
-        import jax
-
-        cfg = _sized(cfg, rehearse)
-        self.cfg = cfg
-        self.index, self.field = cfg["index"], cfg["field"]
-        self.k = int(cfg["k"])
-        n, dims = int(cfg["vectors"]), int(cfg["dims"])
-        if int(cfg["shards"]) != 1:
-            raise ValueError("dense_vector_shard loads one shard")
-        D = pow2_bucket(n, minimum=64)
-        mix = cfg["mixture"]
-        slab, queries = vector_data.make_vectors(
-            n, D, dims, seed, clusters=mix["clusters"],
-            spread=mix["spread"], n_queries=cfg["queries"]["pool"],
-            decimals=cfg["queries"]["decimals"], device=devices[0])
-        vecs_host = np.asarray(slab)  # the engine's host mirror
-        self.queries = np.asarray(queries)
-        self.pool_size = self.queries.shape[0]
-        exists = np.zeros(D, bool)
-        exists[:n] = True
-        self.reference = KnnReference(vecs_host[:n], self.queries,
-                                      cfg["similarity"])
-        vc = VectorColumn(
-            name=self.field, vecs=slab,
-            exists=jax.device_put(exists, devices[0]), dims=dims,
-            vecs_host=vecs_host, exists_host=exists,
-            similarity=cfg["similarity"])
-        seg = TpuSegment(
-            num_docs=n, max_docs=D, inverted={}, numerics={}, keywords={},
-            vectors={self.field: vc}, sources=[None] * n, stored=[None] * n,
-            ids=[str(i) for i in range(n)], id_map={}, field_lengths={})
-        node = Node(name="bench", data_path=cfg.get("data_path"))
-        node.create_index(self.index, {
-            "settings": {"number_of_shards": 1},
-            "mappings": {"properties": {self.field: {
-                "type": "dense_vector", "dims": dims,
-                "similarity": cfg["similarity"]}}}})
-        node.indices[self.index].shards[0].engine.segments.append(seg)
-        self.node = node
-        self.n, self.dims = n, dims
-        self.slab_bytes = float(D * dims * slab.dtype.itemsize)
-        self.info = {"shards": 1, "vectors": n, "slots": D, "dims": dims,
-                     "slab_bytes": self.slab_bytes}
-
-    def request(self, i: int) -> dict:
-        return {"query": {"knn": {
-            "field": self.field,
-            "query_vector": [float(x) for x in self.queries[i].tolist()],
-            "k": self.k, "ann": False}},
-            "size": self.k, "_source": False}
-
-    def work(self, i: int) -> dict:
-        # 2*N*dims flop a query; the slab is read once a device batch
-        return {"flop": 2.0 * self.n * self.dims, "bytes": 0.0,
-                "batch_bytes": self.slab_bytes}
-
-
-KINDS = {"bm25_text_shard": TextShards, "dense_vector_shard": VectorShards}
-
-
 def load(cfg: dict, seed: int, devices, rehearse: bool = False) -> Loaded:
-    try:
-        kind = KINDS[cfg["kind"]]
-    except KeyError:
-        raise ValueError(f"unknown configuration kind [{cfg.get('kind')}]; "
-                         f"known: {sorted(KINDS)}") from None
-    return kind(cfg, seed, devices, rehearse)
+    kind = byname.module("benchmarks.kinds", cfg.get("kind"),
+                         "configuration kind")
+    loaded = kind.load(cfg, seed, devices, rehearse)
+    if (type(loaded).control is Loaded.control
+            and not callable(getattr(loaded.reference, "control", None))):
+        raise ValueError(
+            f"configuration kind [{cfg['kind']}] has no control: neither "
+            f"its Loaded nor its reference gives one")
+    return loaded

@@ -14,7 +14,8 @@ cell asks for: exit 3 and nothing on standard output.
 
 The benchmark's own flags: ``--rehearse`` (every cell's whole path at a
 thousand documents on the CPU; prints no line and no time), ``--control 1``
-(also read the lower-precision control on the same sample), ``--sweep
+(also read the configuration kind's control on the same sample; a
+rehearsal with it fails where the control comes out correct), ``--sweep
 r1,r2,..`` (one set-up, then the window at each rate; prints no line).
 How a cell's files are found: benchmarks/README.md.
 """
@@ -125,10 +126,11 @@ def percentile(values: list, p: float) -> float:
 # what a phase's records say
 # --------------------------------------------------------------------------
 
-def unpack(schedule: dict, result: dict) -> list:
+def unpack(schedule: dict, result: dict, loaded) -> list:
     """One entry a request: {"pool": [...], "due", "sent", "done",
-    "answers": [hits | None, ...]}; an answer is None where the search got
-    no valid reply."""
+    "answers": [answer | None, ...]}; what of a reply is its answer is the
+    configuration kind's to say (``loaded.answer``), and an answer is None
+    where the request got no valid reply."""
     out = []
     for idx, due, sent, done, status, text in result["records"]:
         req = (schedule["requests"][idx] if schedule["mode"] == "open"
@@ -140,11 +142,8 @@ def unpack(schedule: dict, result: dict) -> list:
                 replies = (body["responses"] if req["path"] == "/_msearch"
                            else [body])
                 if len(replies) == len(answers):
-                    answers = [
-                        r["hits"]["hits"] if isinstance(r, dict)
-                        and "error" not in r and not r.get("timed_out")
-                        and isinstance(r.get("hits", {}).get("hits"), list)
-                        else None for r in replies]
+                    answers = [loaded.answer(r) if isinstance(r, dict)
+                               else None for r in replies]
             except (ValueError, KeyError, TypeError):
                 pass
         out.append({"pool": req["pool"], "due": due, "sent": sent,
@@ -155,12 +154,12 @@ def unpack(schedule: dict, result: dict) -> list:
 
 
 def sample_answers(requests: list, loaded, n: int, seed: int) -> list:
-    """(pool index, hits) pairs to hold to the reference: a seeded sample
+    """(pool index, answer) pairs to hold to the reference: a seeded sample
     of the searches that got a reply, the one with the most work in it."""
     import numpy as np
 
-    have = [(q, hits) for r in requests
-            for q, hits in zip(r["pool"], r["answers"]) if hits is not None]
+    have = [(q, a) for r in requests
+            for q, a in zip(r["pool"], r["answers"]) if a is not None]
     if len(have) <= n:
         return have
     rng = np.random.default_rng([int(seed), 0x5A3F])
@@ -182,8 +181,8 @@ def traced_works(requests: list, loaded, t_a: float, t_b: float) -> list:
         share = max(0.0, min(r["done"], t_b) - max(r["sent"], t_a)) / span
         if share <= 0:
             continue
-        for q, hits in zip(r["pool"], r["answers"]):
-            if hits is not None:
+        for q, a in zip(r["pool"], r["answers"]):
+            if a is not None:
                 w = loaded.work(q)
                 works.append({"flop": w["flop"] * share,
                               "bytes": w["bytes"] * share,
@@ -278,17 +277,37 @@ def memory_peak(devices) -> int:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
+def _single(loaded, q: int) -> dict:
+    return {"method": "POST", "path": loaded.path(q), "pool": [q],
+            "body": json.dumps(loaded.request(q))}
+
+
 def singles(loaded, pool: list, clients: int) -> dict:
-    """Each query of ``pool`` once, as a single search, dealt round
+    """Each entry of ``pool`` once, as a single request, dealt round
     ``clients`` callers who send back to back and then stop."""
-    path = f"/{loaded.index}/_search"
     lists = [[] for _ in range(max(1, min(clients, len(pool))))]
     for n, q in enumerate(pool):
-        lists[n % len(lists)].append({
-            "method": "POST", "path": path, "pool": [int(q)],
-            "body": json.dumps(loaded.request(int(q)))})
+        lists[n % len(lists)].append(_single(loaded, int(q)))
     return {"mode": "closed", "once": True, "seconds": 0.0,
             "reply_timeout_s": 300.0, "requests": lists}
+
+
+def at_once(loaded, pool: list, connections: int) -> dict:
+    """Each entry of ``pool`` once, as a single request, all due at the
+    same instant over ``connections`` connections."""
+    return {"mode": "open", "seconds": 0.0, "connections": connections,
+            "reply_timeout_s": 300.0,
+            "requests": [dict(_single(loaded, int(q)), due=0.0)
+                         for q in pool]}
+
+
+def put_settings(transient: dict) -> dict:
+    """One ``PUT /_cluster/settings`` of the program's dynamic settings (a
+    value of None puts a setting back to its default)."""
+    return {"mode": "closed", "once": True, "seconds": 0.0,
+            "reply_timeout_s": 60.0, "requests": [[{
+                "method": "PUT", "path": "/_cluster/settings", "pool": [],
+                "body": json.dumps({"transient": transient})}]]}
 
 
 def warm_up(files: CellFiles, gen: LoadGen, loaded, seed: int,
@@ -303,9 +322,21 @@ def warm_up(files: CellFiles, gen: LoadGen, loaded, seed: int,
        as single searches from a few callers back to back, so that the
        program for each query's shape class (chunk count x run length) is
        compiled or loaded from the cache before the window.
-    3. Rounds of the cell's own traffic from a seed offset, the first with
-       bursts (the coalescer's batch shapes), until a round compiles
-       nothing more."""
+    3. Where the traffic file asks for them (``warmup.batches``), the
+       coalescer's batch shapes, formed on purpose: under the program's
+       own dynamic settings ``hold`` (every search parks, none is flushed
+       early) and ``size_setting`` = n, n searches due at once make one
+       batch of exactly n, for each n of ``sizes`` (the smallest of each
+       padded shape). Which batches thread timing forms in a burst is
+       luck, and a shape first met inside the window compiles there (a
+       second's stall, PR 31). Where only some pool entries coalesce into
+       a program of their own, ``of`` = {group: argument} names them and
+       the configuration's kind lists them (``Loaded.group``); else the
+       window's own first entries are sent. The settings go back to their
+       defaults before anything else is sent.
+    4. Rounds of the cell's own traffic from a seed offset, the first with
+       bursts (the coalescer's batch shapes, as luck forms them), until a
+       round compiles nothing more."""
     from benchmarks.loadgen import schedule as sched_mod
 
     warm = files.traffic["warmup"]
@@ -314,7 +345,7 @@ def warm_up(files: CellFiles, gen: LoadGen, loaded, seed: int,
     def phase(name, sched):
         nonlocal last
         t0 = time.monotonic()
-        reqs = unpack(sched, gen.run(sched))
+        reqs = unpack(sched, gen.run(sched), loaded)
         now = compiles()
         row = {"phase": name, "seconds": round(time.monotonic() - t0, 3),
                "searches": sum(len(r["pool"]) for r in reqs),
@@ -326,6 +357,13 @@ def warm_up(files: CellFiles, gen: LoadGen, loaded, seed: int,
         out.setdefault("phases", []).append(row)
         return row
 
+    def settle(transient: dict):
+        sched = put_settings(transient)
+        for r in unpack(sched, gen.run(sched), loaded):
+            if r["status"] != 200:
+                raise RuntimeError(f"the program refused {transient}: "
+                                   f"status {r['status']}: {r['error']}")
+
     window = sched_mod.build(files.traffic, seed, seconds, rate, loaded)
     flat = (window["requests"] if window["mode"] == "open"
             else [r for lst in window["requests"] for r in lst])
@@ -333,6 +371,26 @@ def warm_up(files: CellFiles, gen: LoadGen, loaded, seed: int,
     phase("first touch", singles(loaded, distinct[:1], 1))
     phase("pool pass", singles(loaded, distinct[1:],
                                int(warm["pass_clients"])))
+    forced = warm.get("batches")
+    if forced:
+        keys = [*forced["hold"], forced["size_setting"]]
+        entries = distinct
+        if "of" in forced:
+            (group, arg), = forced["of"].items()
+            entries = [int(q) for q in loaded.group(group, arg)]
+        try:
+            for n in (int(n) for n in forced["sizes"]):
+                if len(entries) < n:
+                    raise RuntimeError(
+                        f"a batch of {n} asks for more entries than "
+                        f"{forced.get('of', 'the window')} gives: "
+                        f"{len(entries)}")
+                settle({**forced["hold"], forced["size_setting"]: n})
+                phase(f"batch of {n}", at_once(
+                    loaded, entries[:n],
+                    int(files.traffic.get("connections", n))))
+        finally:
+            settle(dict.fromkeys(keys))
     for i in range(int(warm["max_rounds"])):
         sched = sched_mod.build(
             dict(files.traffic, bursts=warm.get("bursts", []) if not i
@@ -368,7 +426,7 @@ def measure(files: CellFiles, gen: LoadGen, loaded, seed: int,
         tracer.join(120.0)
         if tracer.is_alive() or tracer.error or not tracer.result:
             raise RuntimeError(f"the trace failed: {tracer.error!r}")
-    reqs = unpack(sched, result)
+    reqs = unpack(sched, result, loaded)
     # open loop: the window is the offered [t0, t0 + seconds); closed
     # loop: it closes with the last reply of the requests started in it
     t0 = result["t0"]
@@ -417,6 +475,7 @@ def run_cell(args, table: dict, workload: str, rehearse: bool) -> dict:
     from benchmarks.metrics import counters as counters_mod
     from benchmarks.metrics import read_metric
     from benchmarks.reference import check
+    from benchmarks.trace import host_spans
     from benchmarks.trace import reduce as trace_reduce
 
     files = CellFiles(table, workload, rehearse)
@@ -492,23 +551,19 @@ def run_cell(args, table: dict, workload: str, rehearse: bool) -> dict:
             break
     t0 = time.monotonic()
     sample = sample_answers(reqs, loaded, int(files.own["sample"]), seed)
-    cmp = check.compare(loaded.reference, sample, loaded.k)
+    cmp = loaded.compare(sample)
     numbers = dict(cmp["numbers"], unanswered=unanswered)
     limits = files.own["limits"]
     correct = check.verdict(numbers, limits)
     record["reference_seconds"] = round(time.monotonic() - t0, 3)
     record["compared"] = {"answers": cmp["compared"], "faults": cmp["faults"]}
     if args.control:
-        ctl = check.compare(
-            loaded.reference,
-            check.control_answers(loaded.reference,
-                                  [q for q, _ in sample], loaded.k),
-            loaded.k)
+        ctl = loaded.compare(loaded.control([q for q, _ in sample]))
         record["control"] = {"numbers": ctl["numbers"],
                              "correct": check.verdict(
                                  dict(ctl["numbers"], unanswered=0), limits)}
-        log(f"control (the reference one precision down): "
-            f"{record['control']}")
+        log(f"control (the kind's own: one precision, or one guarantee, "
+            f"down): {record['control']}")
 
     e2e = end_to_end(win)
     e2e["setup_s"] = setup_s
@@ -520,7 +575,8 @@ def run_cell(args, table: dict, workload: str, rehearse: bool) -> dict:
             "failed": unanswered, "metrics": {}, "device": device}
     if args.trace:
         found = trace_reduce.find_trace(trace_dir)
-        red = trace_reduce.reduce_file(found, cpu_rehearsal=rehearse)
+        planes, window = trace_reduce.read_traced(found, rehearse)
+        red = trace_reduce.reduce_events(planes, window)
         tr = win["traced"]
         device["window_s"], device["busy_s"] = red["window_s"], red["busy_s"]
         ctx = {"counters": {"window": win["counters"],
@@ -537,9 +593,10 @@ def run_cell(args, table: dict, workload: str, rehearse: bool) -> dict:
             v = read_metric(m["name"], ctx)
             if v is not None:
                 values[m["name"]] = v
-        line["breakdown"] = {
-            "device_ops": red["device_ops"],
-            "idle_gaps": name_gaps(red["gaps"], reqs, tr)}
+        # the device's seconds by program and op, its idle seconds by
+        # what the host was doing: the tables gap_report.py prints
+        line["breakdown"] = host_spans.report(
+            planes, window, host_spans.read_host_events(found))["breakdown"]
         record["trace"] = {k: red[k] for k in (
             "window_s", "busy_s", "busy_by_device")}
         if args.describe_trace:
@@ -562,6 +619,12 @@ def run_cell(args, table: dict, workload: str, rehearse: bool) -> dict:
     record["gc"] = win["gc"]
     if win["mode"] == "open":
         record["latency_by_second"] = latency_story(win)
+        # every answered request, [due s into the window, ms]: what a
+        # shorter or longer window of this same run would have read
+        record["latencies"] = [
+            [round(r["due"] - win["t0"], 3),
+             round((r["done"] - r["due"]) * 1000.0, 3)]
+            for r in win["requests"] if r["done"] is not None]
     log(f"garbage collections in the window: {win['gc']}")
     story = record["counters"]
     log("counters over the window: " + json.dumps({
@@ -636,24 +699,6 @@ def counter_story(pair) -> dict:
             "program_execute_seconds_top": top}
 
 
-def name_gaps(gaps: list, reqs: list, tr: dict) -> list:
-    """The longest idle gaps of the device, by what the harness knows the
-    host was doing at the gap's start: was a request in flight (then the
-    host path held the device back) or none (it waited for a request).
-    A gap starts so many ns into the annotation, which opened at ``t_a``
-    on the host's clock. Finer attribution is the tracing issue's."""
-    inflight = [(r["sent"], r["done"]) for r in reqs
-                if r["sent"] is not None and r["done"] is not None]
-    out: dict = {}
-    for rel_ns, secs in gaps:
-        at = tr["t_a"] + rel_ns / 1e9
-        name = ("requests in flight (host path)"
-                if any(s <= at <= d for s, d in inflight)
-                else "no request in flight (waiting for one)")
-        out[name] = out.get(name, 0.0) + secs
-    return [[k, v] for k, v in sorted(out.items(), key=lambda kv: -kv[1])]
-
-
 def sweep(files, gen, loaded, seed, seconds, rates, snapshot) -> list:
     """One set-up, then the window at each rate of the ladder: does the
     reply rate keep up, and is a backlog left growing at the end?"""
@@ -700,6 +745,10 @@ def rehearse(table: dict, args) -> int:
             if not line["correct"]:
                 log(f"rehearsal of {name}: not correct: {line['compared']} "
                     f"{done['record']['compared']}")
+                return 1
+            if args.control and done["record"]["control"]["correct"]:
+                log(f"rehearsal of {name}: the control came out correct: "
+                    f"{done['record']['control']}")
                 return 1
     log("rehearsal passed")
     return 0

@@ -2,7 +2,7 @@
 
 One set-up and one traced window of a cell, through ``run.py``'s own
 functions (``loaders.load``, ``warm_up``, ``measure``), with the trace
-kept; then ``trace/host_spans.py`` over it. Prints three tables:
+kept; then ``trace/host_spans.py`` over it. Prints four tables:
 
 1. the phase table — for every span name the program's tracer recorded
    over the window: ms a search answered (wall, self) and the spans a
@@ -13,7 +13,10 @@ kept; then ``trace/host_spans.py`` over it. Prints three tables:
    root span;
 2. idle seconds of the traced interval by phase (``idle_by_phase``);
 3. device seconds by XLA module, and the top device ops with the
-   modules they ran in.
+   modules they ran in;
+4. the device ops as a traced run's last line prints them (``breakdown``:
+   ``host_spans.report`` is the one function this tool and ``run.py``
+   read).
 
 A diagnostic for the builder: it prints no last line, compares nothing
 with the reference and is no part of the check.
@@ -139,9 +142,7 @@ def main(argv=None) -> int:
     obs = bench.observed(win)
     names = host_spans.load_names()
     found = trace_reduce.find_trace(trace_dir)
-    planes, window = trace_reduce.read_planes(found, args.rehearse)
-    if window is None:
-        raise SystemExit(f"no [{trace_reduce.WINDOW}] annotation in {found}")
+    planes, window = trace_reduce.read_traced(found, args.rehearse)
     rep = host_spans.report(planes, window,
                             host_spans.read_host_events(found), names)
     phases = phase_table(win["counters"], max(obs["answered"], 1))
@@ -193,6 +194,10 @@ def main(argv=None) -> int:
         mods = ", ".join(f"{m[:48] or '(none)'} {s:.4f}"
                          for m, s in list(by.items())[:3])
         print(f"   {secs:10.4f}  {name[:64]}  <- {mods}")
+    print("\n4. the rows a traced run's last line carries (breakdown): "
+          "device ops by printed name")
+    for name, secs in rep["breakdown"]["device_ops"]:
+        print(f"   {secs:10.4f}  {name}")
     return 0
 
 

@@ -11,14 +11,17 @@ because a wait that starts on one thread and ends on another has no
 annotation.
 
 Pure functions in the manner of ``reduce.reduce_events``: the tests feed
-them synthetic planes, ``tools/gap_report.py`` a real trace. Nothing here
-is part of a run's last line.
+them synthetic planes, ``tools/gap_report.py`` and ``run.py`` a real trace:
+``report`` is the one function both read, and its ``breakdown`` is what a
+traced run's last line carries.
 """
 from __future__ import annotations
 
 import bisect
+import glob
 import json
 import os
+import re
 from collections import Counter
 
 from benchmarks.trace import reduce as trace_reduce
@@ -30,11 +33,35 @@ MODULES_LINE = "XLA Modules"
 NO_SPAN = "no span open, request in flight"
 NO_REQUEST = "no request in the server"
 POOL_WAIT = "rest.pool_wait"
+OTHER_PHASES = "other phases"
 
 
 def load_names(path: str | None = None) -> dict:
-    with open(path or os.path.join(HERE, "span_names.json")) as fh:
-        return json.load(fh)
+    """``span_names.json`` and then every file of the directory
+    ``span_names/`` beside it, in the order of their names: each may add
+    ``containers``, ``leaves`` and ``derived`` (a later PR's spans come as
+    a file of their own), and may not restate ``root`` or a name that is
+    already listed."""
+    path = path or os.path.join(HERE, "span_names.json")
+    with open(path) as fh:
+        names = json.load(fh)
+    more = os.path.splitext(path)[0]
+    for extra in sorted(glob.glob(os.path.join(more, "*.json"))):
+        with open(extra) as fh:
+            add = json.load(fh)
+        if "root" in add:
+            raise ValueError(f"{extra} restates [root]")
+        for key in ("containers", "leaves", "derived"):
+            have = (set(names["containers"]) | set(names["leaves"])
+                    | set(names["derived"]))
+            again = sorted(set(add.get(key, ())) & have)
+            if again:
+                raise ValueError(f"{extra} restates {again}")
+            if key == "derived":
+                names[key] = {**names[key], **add.get(key, {})}
+            else:
+                names[key] = names[key] + list(add.get(key, ()))
+    return names
 
 
 def span_events(host: list, names: dict) -> list:
@@ -144,24 +171,74 @@ def device_seconds_by_module(planes: dict, window: tuple) -> list:
     return [[k, v] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])]
 
 
+def _ops_in_modules(planes: dict, lo: float, hi: float):
+    """(op name, module name or "", seconds inside the window) of every
+    device op: the XLA module whose run the op's event started in."""
+    mods = sorted(device_intervals(planes, MODULES_LINE),
+                  key=lambda m: m[1])
+    starts = [m[1] for m in mods]
+    for name, s, secs in _clipped(device_intervals(planes), lo, hi):
+        j = bisect.bisect_right(starts, s) - 1
+        yield name, (mods[j][0] if j >= 0 and s < mods[j][2] else ""), secs
+
+
 def modules_of_ops(planes: dict, window: tuple, top: int = 10) -> list:
     """[[op name, seconds, {module name: seconds}]] for the ``top`` device
     ops by time in the window: which XLA module's run each op's events
     started in (an op with no module round it is filed under "")."""
-    lo, hi = float(window[0]), float(window[1])
-    mods = sorted(device_intervals(planes, MODULES_LINE),
-                  key=lambda m: m[1])
-    starts = [m[1] for m in mods]
     acc: dict = {}
-    for name, s, secs in _clipped(device_intervals(planes), lo, hi):
-        j = bisect.bisect_right(starts, s) - 1
-        mod = mods[j][0] if j >= 0 and s < mods[j][2] else ""
+    for name, mod, secs in _ops_in_modules(planes, float(window[0]),
+                                           float(window[1])):
         row = acc.setdefault(name, [0.0, {}])
         row[0] += secs
         row[1][mod] = row[1].get(mod, 0.0) + secs
     rows = sorted(acc.items(), key=lambda kv: -kv[1][0])[:top]
     return [[name, total, dict(sorted(by.items(), key=lambda kv: -kv[1]))]
             for name, (total, by) in rows]
+
+
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+#: a row's name in a line's ``breakdown``: what the ledger keeps of it
+PRINTED = 64
+
+
+def printed_op(module: str, op: str) -> str:
+    """``<jitted function>/<instruction> <result type>``, at most
+    ``PRINTED`` characters: the module without its fingerprint, the HLO
+    instruction without layouts and operands. One op of one function in
+    many shape classes prints one name."""
+    head, sep, rest = op.partition(" = ")
+    if sep:
+        rest, depth = _LAYOUT.sub("", rest), 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if ch == " " and not depth:
+                rest = rest[:i]
+                break
+        op = f"{head} {rest}"
+    module, op = module.partition("(")[0], op.lstrip("%")
+    return (f"{module}/{op}" if module else op)[:PRINTED]
+
+
+def device_ops(planes: dict, window: tuple, top: int = 10) -> list:
+    """[[printed name, seconds]] of the first device's ops inside the
+    window, most first: rows whose printed names are equal are one row."""
+    acc: dict = {}
+    for name, mod, secs in _ops_in_modules(planes, float(window[0]),
+                                           float(window[1])):
+        key = printed_op(mod, name)
+        acc[key] = acc.get(key, 0.0) + secs
+    return [[k, v] for k, v in sorted(acc.items(),
+                                      key=lambda kv: -kv[1])[:top]]
+
+
+def top_rows(rows: list, top: int, rest: str) -> list:
+    """The ``top`` - 1 largest of [[name, seconds]] and what is left under
+    one name, so that the rows still add up."""
+    rows = sorted(rows, key=lambda kv: -kv[1])
+    if len(rows) <= top:
+        return rows
+    return rows[:top - 1] + [[rest, sum(v for _, v in rows[top - 1:])]]
 
 
 def read_host_events(path: str) -> list:
@@ -206,4 +283,10 @@ def report(planes: dict, window: tuple, host: list,
         "idle_named_share": named / idle_s if idle_s > 0 else 1.0,
         "device_by_module": device_seconds_by_module(planes, window),
         "ops_in_modules": modules_of_ops(planes, window, top),
+        # what a run's last line carries (``run.py``), under names a
+        # reader of the ledger can plan from
+        "breakdown": {
+            "device_ops": device_ops(planes, window, top),
+            "idle_gaps": top_rows([[k, v] for k, v in by_phase.items()],
+                                  top, OTHER_PHASES)},
     }

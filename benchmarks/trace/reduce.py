@@ -55,14 +55,16 @@ def idle_gaps(intervals, lo: float, hi: float, top: int = 10) -> list:
     return sorted(gaps, key=lambda g: -g[1])[:top]
 
 
-def reduce_events(planes: dict, window: tuple, top: int = 10) -> dict:
+def reduce_events(planes: dict, window: tuple) -> dict:
     """``planes``: {plane name: {line name: [(event name, start ns,
     duration ns), ...]}}; ``window``: (start ns, end ns). Pure: the tests
-    feed it synthetic events, ``reduce_file`` a real trace."""
+    feed it synthetic events, ``reduce_file`` a real trace. (The tables of
+    a trace — device seconds by op, idle seconds by phase — are
+    ``host_spans.report``'s.)"""
     lo, hi = float(window[0]), float(window[1])
     if not hi > lo:
         raise ValueError(f"the traced window is empty: {window}")
-    busy, ops, first = {}, {}, None
+    busy = {}
     for name, lines in sorted(planes.items()):
         m = DEVICE_PLANE.match(name)
         if not m:
@@ -72,29 +74,14 @@ def reduce_events(planes: dict, window: tuple, top: int = 10) -> dict:
             raise ValueError(
                 f"device plane [{name}] has no [{OPS_LINE}] line; it has "
                 f"{sorted(lines)}")
-        spans = [(s, s + d) for _, s, d in events]
-        busy[int(m.group(1))] = union_seconds(spans, lo, hi)
-        if first is None:
-            first = spans
-        for ev, s, d in events:
-            part = min(s + d, hi) - max(s, lo)
-            if part > 0:
-                ops[ev] = ops.get(ev, 0.0) + part / 1e9
+        busy[int(m.group(1))] = union_seconds(
+            [(s, s + d) for _, s, d in events], lo, hi)
     if not busy:
         raise ValueError(
             f"the trace holds no device plane; planes: {sorted(planes)}")
-    n = len(busy)
-    return {
-        "window_s": (hi - lo) / 1e9,
-        "busy_s": sum(busy.values()) / n,
-        "busy_by_device": busy,
-        # seconds a device, like busy_s
-        "device_ops": [[k, v / n] for k, v in sorted(
-            ops.items(), key=lambda kv: -kv[1])[:top]],
-        # (ns after the window opened, seconds), of the first device
-        "gaps": [(at - lo, secs) for at, secs in
-                 idle_gaps(first, lo, hi, top)],
-    }
+    return {"window_s": (hi - lo) / 1e9,
+            "busy_s": sum(busy.values()) / len(busy),
+            "busy_by_device": busy}
 
 
 def find_trace(trace_dir: str) -> str:
@@ -139,12 +126,16 @@ def read_planes(path: str, cpu_rehearsal: bool = False):
     return planes, window
 
 
-def reduce_file(path: str, top: int = 10, cpu_rehearsal: bool = False
-                ) -> dict:
+def read_traced(path: str, cpu_rehearsal: bool = False):
+    """``read_planes`` of a run's trace: the window has to be there."""
     planes, window = read_planes(path, cpu_rehearsal)
     if window is None:
         raise ValueError(f"no [{WINDOW}] annotation in {path}")
-    return reduce_events(planes, window, top)
+    return planes, window
+
+
+def reduce_file(path: str, cpu_rehearsal: bool = False) -> dict:
+    return reduce_events(*read_traced(path, cpu_rehearsal))
 
 
 def describe(path: str, events: int = 4) -> str:

@@ -1,6 +1,9 @@
 """trace/host_spans.py on synthetic planes: idle seconds by phase sum to
 the idle time, overlapping requests share an instant equally, the pool
 wait is derived from the trace ids, and device ops find their module."""
+import json
+import os
+
 import pytest
 
 from benchmarks.trace import host_spans
@@ -131,3 +134,138 @@ def test_report_names_its_share_and_needs_a_device_plane():
     assert rep["idle_named_share"] == pytest.approx((86 - 6) / 86)
     with pytest.raises(ValueError):
         host_spans.idle_intervals({"/host:CPU": {}}, WINDOW)
+
+
+# ---- the line's breakdown: names one can plan from -----------------------------
+
+SCATTER = ("%fusion.1 = f32[4194304]{0:T(1024)S(1)} fusion(f32[4194304]"
+           "{0:T(1024)S(1)} %while.2, s32[%d]{0:T(1024)S(1)} %gte.42), "
+           "kind=kCustom, calls=%fused_computation.1")
+TOPK = ("%custom-call = (f32[512,10]{1,0:T(8,128)S(1)}, s32[512,10]{1,0:"
+        "T(8,128)S(1)}) custom-call(f32[512,8192]{1,0:T(8,128)S(1)} "
+        "%reshape.13), custom_call_target=\"TopK\"")
+
+
+def test_a_printed_op_is_the_function_the_instruction_and_its_result():
+    mod = "jit_bm25_term_group_topk(2502640247167472124)"
+    assert host_spans.printed_op(mod, SCATTER.replace("%d", "131072")) == (
+        "jit_bm25_term_group_topk/fusion.1 f32[4194304]")
+    assert host_spans.printed_op(mod, TOPK) == (
+        "jit_bm25_term_group_topk/custom-call (f32[512,10], s32[512,10])")
+    assert host_spans.printed_op("", "copy.9") == "copy.9"
+    long = host_spans.printed_op("jit_" + "x" * 80, TOPK)
+    assert len(long) == host_spans.PRINTED
+
+
+def test_device_ops_with_one_printed_name_are_one_row():
+    """One op of one function in several shape classes (other operands,
+    other fingerprints) was several rows of PR 30's line."""
+    modules = [("jit_bm25_term_group_topk(1)", 19, 12),
+               ("jit_bm25_term_group_topk(2)", 39, 6),
+               ("jit_knn_topk(3)", 59, 4)]
+    ops = [(SCATTER.replace("%d", "131072"), 20, 6), (TOPK, 26, 4),
+           (SCATTER.replace("%d", "196608"), 40, 3), (TOPK, 43, 1),
+           (TOPK, 60, 2), ("copy.9", 70, 1)]
+    rows = host_spans.device_ops(_planes(ops, modules), WINDOW)
+    assert rows == [
+        ["jit_bm25_term_group_topk/fusion.1 f32[4194304]",
+         pytest.approx(9e-3)],
+        ["jit_bm25_term_group_topk/custom-call (f32[512,10], s32[512,10])",
+         pytest.approx(5e-3)],
+        ["jit_knn_topk/custom-call (f32[512,10], s32[512,10])",
+         pytest.approx(2e-3)],
+        ["copy.9", pytest.approx(1e-3)]]
+    assert len({name for name, _ in rows}) == len(rows)
+    assert host_spans.device_ops(_planes(ops, modules), WINDOW, top=2) == (
+        rows[:2])
+    # clipped to the window like every other table
+    assert host_spans.device_ops(_planes(ops, modules), (0.0, 23 * MS)) == [
+        [rows[0][0], pytest.approx(3e-3)]]
+
+
+def test_top_rows_keep_the_sum():
+    rows = [[f"p{i}", float(i)] for i in range(1, 14)]
+    got = host_spans.top_rows(rows, 10, "other phases")
+    assert len(got) == 10 and got[0] == ["p13", 13.0]
+    assert got[-1] == ["other phases", 1.0 + 2.0 + 3.0 + 4.0]
+    assert sum(v for _, v in got) == sum(v for _, v in rows)
+    assert host_spans.top_rows(rows[:3], 10, "x") == [
+        ["p3", 3.0], ["p2", 2.0], ["p1", 1.0]]
+
+
+def test_the_reports_breakdown_is_the_lines():
+    """``idle_gaps`` by phase in place of PR 23's two names (a request in
+    flight or none): the same trace, now saying which phase held the
+    device back; its rows add up to the idle time."""
+    modules = [("jit_bm25_term_group_topk(1)", 19, 30)]
+    rep = host_spans.report(_planes(OPS, modules), WINDOW, HOST, NAMES)
+    bd = rep["breakdown"]
+    assert set(bd) == {"device_ops", "idle_gaps"}
+    gaps = dict(bd["idle_gaps"])
+    assert gaps[host_spans.NO_REQUEST] == pytest.approx(0.050)
+    assert gaps["device.wait"] == pytest.approx(0.0105)
+    assert gaps[host_spans.NO_SPAN] == pytest.approx(0.006)
+    assert bd["idle_gaps"] == rep["idle_by_phase"]  # under ten phases here
+    assert sum(gaps.values()) == pytest.approx(rep["idle_s"])
+    assert bd["device_ops"] == [
+        ["jit_bm25_term_group_topk/fusion.1", pytest.approx(10e-3)],
+        ["jit_bm25_term_group_topk/custom-call", pytest.approx(4e-3)]]
+    # the form check_last_line takes: at most ten [name, seconds] rows
+    for rows in bd.values():
+        assert len(rows) <= 10 and all(
+            isinstance(n, str) and isinstance(s, float) for n, s in rows)
+
+
+# ---- span names come as files ---------------------------------------------------
+
+def _names_dir(tmp_path, **files):
+    base = tmp_path / "span_names.json"
+    base.write_text(json.dumps({
+        "root": "rest.request", "containers": ["rest.request", "search"],
+        "leaves": ["search.plan"], "derived": {"rest.pool_wait": "x"}}))
+    (tmp_path / "span_names").mkdir()
+    for name, body in files.items():
+        (tmp_path / "span_names" / f"{name}.json").write_text(
+            json.dumps(body))
+    return str(base)
+
+
+def test_span_names_are_the_table_and_then_every_file_beside_it(tmp_path):
+    path = _names_dir(
+        tmp_path,
+        b_bulk={"containers": ["bulk"], "leaves": ["bulk.translog"]},
+        a_aggs={"what": "x", "leaves": ["search.aggregate"],
+                "derived": {"bulk.queue": "how it is derived"}})
+    names = host_spans.load_names(path)
+    assert names["root"] == "rest.request"
+    assert names["containers"] == ["rest.request", "search", "bulk"]
+    assert names["leaves"] == ["search.plan", "search.aggregate",
+                               "bulk.translog"]  # files in name order
+    assert set(names["derived"]) == {"rest.pool_wait", "bulk.queue"}
+    # the added leaf is a phase like any other
+    by = host_spans.idle_by_phase(
+        [(0.0, 10 * MS)], [("bulk", 0.0, 10 * MS, "A"),
+                           ("bulk.translog", 2 * MS, 6 * MS, "A")], names)
+    assert by == {host_spans.NO_SPAN: pytest.approx(6e-3),
+                  "bulk.translog": pytest.approx(4e-3)}
+
+
+@pytest.mark.parametrize("body", [
+    {"root": "bulk"}, {"root": "rest.request"},
+    {"leaves": ["search.plan"]}, {"containers": ["search.plan"]},
+    {"leaves": ["search"]}, {"derived": {"rest.pool_wait": "again"}},
+    {"leaves": ["rest.pool_wait"]}])
+def test_a_names_file_may_not_restate_what_is_listed(tmp_path, body):
+    with pytest.raises(ValueError, match="restates"):
+        host_spans.load_names(_names_dir(tmp_path, more=body))
+
+
+def test_the_committed_table_stands_alone_where_the_directory_is_missing():
+    here = os.path.dirname(host_spans.__file__)
+    extra = [f for f in os.listdir(here) if f == "span_names"]
+    names = host_spans.load_names()
+    with open(os.path.join(here, "span_names.json")) as fh:
+        table = json.load(fh)
+    if not extra:  # this PR adds no file there
+        assert names == table
+    assert names["root"] == table["root"]

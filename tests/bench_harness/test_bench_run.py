@@ -37,12 +37,16 @@ def test_unknown_workload_exits_nonzero_with_empty_stdout():
 
 
 def test_rehearsal_passes_and_prints_no_line():
-    p = subprocess.run([sys.executable, RUN, "--rehearse"],
+    """Every one-chip cell, untraced and traced; with ``--control 1`` a
+    rehearsal also fails where a cell's control comes out correct."""
+    p = subprocess.run([sys.executable, RUN, "--rehearse", "--control", "1"],
                        cwd=contract.ROOT, env=ENV, capture_output=True,
                        timeout=900)
     assert p.returncode == 0, p.stderr[-3000:].decode("utf-8", "replace")
     assert p.stdout == b""
     assert b"rehearsal passed" in p.stderr
+    assert p.stderr.count(b"'correct': False") == 2 * len(
+        contract.load_table()["workloads"])
 
 
 def test_load_generator_imports_nothing_of_jax_or_the_program():

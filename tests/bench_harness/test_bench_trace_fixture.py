@@ -5,6 +5,7 @@ import os
 
 import pytest
 
+from benchmarks.trace import host_spans
 from benchmarks.trace import reduce as trace_reduce
 from benchmarks.trace import trim
 
@@ -16,8 +17,10 @@ def test_recorded_trace_gives_busy_inside_the_window():
     red = trace_reduce.reduce_file(FIXTURE)
     assert 0 < red["busy_s"] <= red["window_s"]
     assert set(red["busy_by_device"]) == {0}
-    assert red["device_ops"] and len(red["device_ops"]) <= 10
-    assert all(secs > 0 for _, secs in red["device_ops"])
+    ops = host_spans.device_ops(*trace_reduce.read_traced(FIXTURE))
+    assert ops and len(ops) <= 10
+    assert all(secs > 0 for _, secs in ops)
+    assert len({name for name, _ in ops}) == len(ops)
 
 
 def test_recorded_trace_has_the_planes_and_lines_the_reducer_reads():
