@@ -90,8 +90,10 @@ def _scale_replicas(svc, target: int) -> None:
             while len(group.replicas) > target:
                 group.replicas.pop().close()
             while len(group.replicas) < target:
-                replica = IndexShard(svc.name, group.shard_id, svc.mappings,
-                                     svc.analysis, None)
+                replica = IndexShard(
+                    svc.name, group.shard_id, svc.mappings, svc.analysis,
+                    None, device=svc.shard_device(
+                        group.shard_id, len(group.replicas) + 1))
                 recover_peer(group.primary.engine, replica.engine)
                 group.replicas.append(replica)
     svc.num_replicas = target

@@ -100,8 +100,12 @@ class Engine:
         refresh_interval_docs: int = 0,
         merge_segment_count: int = 8,
         index_name: str = "",
+        device=None,
     ):
         self.index_name = index_name  # for typed errors: "engine for [x]"
+        # the shard's chip (None: the default device): every segment this
+        # engine freezes or merges is placed there
+        self.device = device
         self.mappings = mappings
         self.analysis = analysis
         self.parser = DocumentParser(mappings, analysis)
@@ -682,7 +686,7 @@ class Engine:
             # the docs and a later refresh serves them (unlike a translog
             # failure, nothing acknowledged is at risk)
             FAULTS.check("segment.freeze", index=self.index_name)
-            fresh = SegmentBuilder(self.mappings)
+            fresh = SegmentBuilder(self.mappings, self.device)
             for d in live_docs:
                 fresh.add(d)
             seg = fresh.freeze()
@@ -702,7 +706,7 @@ class Engine:
                     loc.where = seg.seg_id
                     loc.local_id = local
                     loc.source = None
-            self.buffer = SegmentBuilder(self.mappings)
+            self.buffer = SegmentBuilder(self.mappings, self.device)
             self._buffer_ids.clear()
             self.stats.refresh_total += 1
             self.maybe_merge()
@@ -738,7 +742,7 @@ class Engine:
                 return
             targets = subset if subset is not None else list(self.segments)
             target_ids = {s.seg_id for s in targets}
-            builder = SegmentBuilder(self.mappings)
+            builder = SegmentBuilder(self.mappings, self.device)
             from elasticsearch_tpu.tracing import check_cancelled
 
             for seg in targets:

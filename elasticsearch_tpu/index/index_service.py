@@ -56,8 +56,10 @@ class IndexService:
         from elasticsearch_tpu.index.recovery import RecoveryRegistry
 
         self.recoveries = RecoveryRegistry()
+        self._device_table = None  # shard_device(): built on first ask
         self.shards: List[IndexShard] = [
-            IndexShard(name, i, self.mappings, self.analysis, data_path)
+            IndexShard(name, i, self.mappings, self.analysis, data_path,
+                       device=self.shard_device(i))
             for i in range(self.num_shards)
         ]
         # replica copies + replication groups (reference: primary→replica
@@ -68,8 +70,9 @@ class IndexService:
 
         self.groups: List[ReplicationGroup] = []
         for i, primary in enumerate(self.shards):
-            replicas = [IndexShard(name, i, self.mappings, self.analysis, None)
-                        for _ in range(self.local_replicas)]
+            replicas = [IndexShard(name, i, self.mappings, self.analysis, None,
+                                   device=self.shard_device(i, r + 1))
+                        for r in range(self.local_replicas)]
             self.groups.append(ReplicationGroup(i, primary, replicas))
         self.closed = False
         self._percolator = None
@@ -96,6 +99,30 @@ class IndexService:
             # gateway recovery (reference: gateway/GatewayService +
             # IndexShardGateway): replay any existing translog on open
             self.recover()
+
+    def shard_device(self, shard_id: int, replica: int = 0):
+        """The chip a copy of a shard lives on — ``placement.allocate``'s
+        table over this host's devices, asked here and nowhere else — or
+        None (the default device, today's arrays) for a one-shard index
+        and on a one-device host. Everything the copy's segments place,
+        at freeze and lazily, goes there (``TpuSegment.device``)."""
+        if self.num_shards < 2:
+            return None
+        table = self._device_table
+        if table is None:
+            import jax
+
+            from elasticsearch_tpu.parallel.placement import (
+                allocate, placement_table)
+
+            devices = jax.devices()
+            table = {} if len(devices) < 2 else {
+                key[1:]: devices[ordinal]
+                for key, ordinal in placement_table(allocate(
+                    self.name, self.num_shards, self.local_replicas,
+                    len(devices))).items()}
+            self._device_table = table
+        return table.get((shard_id, replica))
 
     def fail_shard(self, shard_id: int):
         """Primary failure → promote a replica (reference: shard failed →

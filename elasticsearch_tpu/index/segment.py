@@ -36,6 +36,7 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass, field as dfield
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -55,13 +56,25 @@ def _jnp():
     return jnp
 
 
-def _device_put(x):
+def _device_put(x, device=None):
     # every always-resident segment placement goes through the residency
     # choke point (accounting; admission control is the engine's
-    # per-segment breaker charge at freeze — see _charge_segment)
+    # per-segment breaker charge at freeze — see _charge_segment).
+    # ``device``: the owning shard's chip (TpuSegment.device); None is the
+    # default device, uncommitted — a one-shard index's arrays
     from elasticsearch_tpu import resources
 
-    return resources.RESIDENCY.device_put(x, tier="segments")
+    return resources.RESIDENCY.device_put(x, device, tier="segments")
+
+
+def _committed_device(arrays):
+    """The one device these arrays are committed to, or None (host
+    arrays, uncommitted placements, or more than one device)."""
+    found = set()
+    for a in arrays:
+        if getattr(a, "committed", False):
+            found |= set(a.devices())
+    return found.pop() if len(found) == 1 else None
 
 
 def split_i64(v: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -194,24 +207,32 @@ class InvertedField:
     # parallel/postings_shard.py): None = unchecked, False = declined
     _pshard: Any = None
     max_docs: int = 0
+    # the owning segment's chip (TpuSegment sets it; None = the default
+    # device): where the lazy accessors and the dense block place
+    device = None
 
     def wants_postings_shard(self) -> bool:
-        """True when this field's postings exceed the single-device budget
-        (mesh_service uses this to route such indices to the host loop,
-        where the sharded program runs)."""
+        """True when a SECOND, stacked copy of this field's postings is
+        more than the mesh executor and the batched tiers take on
+        (mesh_service uses this to route such indices to the host loop) —
+        whether or not one chip holds the field whole."""
         from elasticsearch_tpu.parallel.postings_shard import \
-            POSTINGS_SHARD_NNZ
+            declines_stacked_copy
 
-        return self.nnz >= POSTINGS_SHARD_NNZ
+        return declines_stacked_copy(self.nnz)
 
     def postings_split(self):
-        """Build-once term-range split across devices, or None (field under
-        the threshold, single device, or no host mirror to split from)."""
+        """Build-once term-range split across devices, or None (a field
+        one chip holds whole, single device, or no host mirror to split
+        from)."""
         if self._pshard is False:
             return None
         if self._pshard is not None:
             return self._pshard
-        if not self.wants_postings_shard():
+        from elasticsearch_tpu.parallel.postings_shard import \
+            chip_holds_whole
+
+        if chip_holds_whole(self.nnz):
             return None
         with self._dense_lock:
             if self._pshard is None:
@@ -303,7 +324,8 @@ class InvertedField:
             # the scatter path instead of failing the request
             handle = resources.RESIDENCY.put_array(
                 impact, label=f"dense_impact:{self.name}",
-                tier="fielddata", dtype=dtype, best_effort=True)
+                tier="fielddata", dtype=dtype, best_effort=True,
+                device=self.device)
             if handle is None:
                 return None  # budget tight: retry later
             # host mirror: mesh prims restack [S, F, D] from it — pulling
@@ -364,7 +386,7 @@ def _lazy_device_field(name: str):
     def _get(self):
         v = self.__dict__[raw]
         if isinstance(v, np.ndarray):
-            v = _device_put(v)
+            v = _device_put(v, self.device)
             self.__dict__[raw] = v
         return v
 
@@ -416,7 +438,8 @@ def _resident_field(name: str):
 
                     v = resources.RESIDENCY.put_array(
                         v, label=f"column:{self.name}.{name}",
-                        tier="fielddata")
+                        tier="fielddata",
+                        device=self.device)
                     self.__dict__[raw] = v
             if isinstance(v, ResidentArray):
                 return v.get()
@@ -443,6 +466,7 @@ class NumericColumn:
     # offset, with offset = segment min. Consumers add offset back (aggs) or
     # shift query bounds down (range masks); exact compares use (hi, lo).
     offset: float = 0.0
+    device = None  # the owning segment's chip (TpuSegment sets it)
 
     @property
     def has_pair(self) -> bool:
@@ -466,6 +490,7 @@ class KeywordColumn:
     host_values: List[Optional[List[str]]] = dfield(default_factory=list)
     ords_host: Optional[np.ndarray] = None
     exists_host: Optional[np.ndarray] = None
+    device = None  # the owning segment's chip (TpuSegment sets it)
 
 
 @dataclass
@@ -490,6 +515,7 @@ class VectorColumn:
     # slab per freeze/snapshot call is measurable host CPU)
     _ck: Any = None
     _ck_max: int = -1
+    device = None  # the owning segment's chip (TpuSegment sets it)
 
     def cache_key(self, max_docs: int) -> str:
         if self._ck is None or self._ck_max != max_docs:
@@ -619,9 +645,22 @@ class TpuSegment:
         ids: List[str],
         id_map: Dict[str, int],
         field_lengths: Dict[str, Any],
+        device: Any = None,
     ):
         TpuSegment._next_id += 1
         self.seg_id = TpuSegment._next_id
+        # the chip this segment lives on: its shard's (SegmentBuilder
+        # passes it), else the one its postings were put on by whoever
+        # built it, else None — the default device, as a one-shard index
+        # has it. Everything the segment and its fields place, now or
+        # lazily (live mask, dense impact block, columns), goes there.
+        if device is None:
+            device = _committed_device(
+                inv._doc_ids_raw for inv in inverted.values())
+        self.device = device
+        for fld in (*inverted.values(), *numerics.values(),
+                    *keywords.values(), *vectors.values()):
+            fld.device = device
         self.num_docs = num_docs
         self.max_docs = max_docs  # pow2 padded
         self.inverted = inverted
@@ -636,7 +675,7 @@ class TpuSegment:
         # deletion state: host-authoritative, device copy refreshed on change
         self._live_host = np.zeros(max_docs, dtype=bool)
         self._live_host[:num_docs] = True
-        self._live_dev = _device_put(self._live_host)
+        self._live_dev = _device_put(self._live_host, device)
         self._live_dirty = False
         self.deleted_count = 0
         # block-join (set by SegmentBuilder.freeze when the segment holds
@@ -683,7 +722,7 @@ class TpuSegment:
     @property
     def live(self):
         if self._live_dirty:
-            self._live_dev = _device_put(self._live_host)
+            self._live_dev = _device_put(self._live_host, self.device)
             self._live_dirty = False
         return self._live_dev
 
@@ -763,8 +802,11 @@ class SegmentBuilder:
     is device arrays rather than an on-disk codec.
     """
 
-    def __init__(self, mappings: Mappings):
+    def __init__(self, mappings: Mappings, device: Any = None):
         self.mappings = mappings
+        # the owning shard's chip (None: the default device): where
+        # freeze() places the segment
+        self.device = device
         self.docs: List[ParsedDocument] = []
         # block-join metadata aligned with docs: immediate parent local id
         # (-1 for root docs) — children are emitted BEFORE their parent, the
@@ -828,7 +870,7 @@ class SegmentBuilder:
             lens = np.zeros(max_docs, dtype=np.float32)
             for i, d in enumerate(self.docs):
                 lens[i] = d.field_length(fname)
-            field_lengths[fname] = _device_put(lens)
+            field_lengths[fname] = _device_put(lens, self.device)
 
         # -- keyword fields: inverted (for term filters + terms agg) + ords
         keywords: Dict[str, KeywordColumn] = {}
@@ -885,6 +927,7 @@ class SegmentBuilder:
             ids=ids,
             id_map={doc_id: i for i, doc_id in enumerate(ids)},
             field_lengths=field_lengths,
+            device=self.device,
         )
         seg.metas = [d.meta for d in self.docs]
         # block-join arrays (all-root fast path: leave device arrays None)
@@ -926,14 +969,26 @@ class SegmentBuilder:
                     anc[nested_code[i]][i] = i  # a doc is its own level-ancestor
             seg.root_id_host = root_id
             seg.ancestors_host = anc
-            seg.parent_id_dev = _device_put(parent_id)
-            seg.nested_code_dev = _device_put(nested_code)
-            seg.roots_dev = _device_put(roots)
-            seg.root_id_dev = _device_put(root_id)
-            seg.ancestors_dev = {c: _device_put(a) for c, a in anc.items()}
+            put = partial(_device_put, device=self.device)
+            seg.parent_id_dev = put(parent_id)
+            seg.nested_code_dev = put(nested_code)
+            seg.roots_dev = put(roots)
+            seg.root_id_dev = put(root_id)
+            seg.ancestors_dev = {c: put(a) for c, a in anc.items()}
         return seg
 
     # -- builders --------------------------------------------------------------
+
+    def _postings_put(self, nnz: int):
+        """Where freeze leaves a field's postings: on the shard's chip,
+        or — a field one chip cannot hold whole, the rule
+        ``InvertedField.postings_split`` asks too — on the host."""
+        from elasticsearch_tpu.parallel.postings_shard import \
+            chip_holds_whole
+
+        if chip_holds_whole(nnz):
+            return partial(_device_put, device=self.device)
+        return lambda a: a
 
     def _build_inverted_text(self, fname: str, n: int, max_docs: int) -> InvertedField:
         # term -> list[(doc, tf, positions)]
@@ -994,9 +1049,7 @@ class SegmentBuilder:
         # device at freeze — scoring goes through the cross-device split;
         # the lazy accessors place these host arrays only if a fallback
         # path (phrase, terms agg) actually asks for the full copy
-        from elasticsearch_tpu.parallel.postings_shard import \
-            POSTINGS_SHARD_NNZ
-        put = (lambda a: a) if nnz >= POSTINGS_SHARD_NNZ else _device_put
+        put = self._postings_put(nnz)
         return InvertedField(
             name=fname,
             vocab=vocab,
@@ -1071,9 +1124,7 @@ class SegmentBuilder:
         nnz_pad = pow2_bucket(max(nnz, 1), minimum=8)
         ones = np.ones(nnz, dtype=np.float32)
         # same oversized-field treatment as _build_inverted_text
-        from elasticsearch_tpu.parallel.postings_shard import \
-            POSTINGS_SHARD_NNZ
-        put = (lambda a: a) if nnz >= POSTINGS_SHARD_NNZ else _device_put
+        put = self._postings_put(nnz)
         inv = InvertedField(
             name=fname,
             vocab=vocab2,

@@ -22,15 +22,19 @@ class IndexShard:
         mappings: Mappings,
         analysis: AnalysisRegistry,
         data_path: Optional[str] = None,
+        device=None,
     ):
         self.index_name = index_name
         self.shard_id = shard_id
+        # this copy's chip (parallel/placement.py), None on a one-shard
+        # index or a one-device host: the default device
+        self.device = device
         self.state = "CREATED"
         translog_path = None
         if data_path:
             translog_path = os.path.join(data_path, index_name, str(shard_id), "translog")
         self.engine = Engine(mappings, analysis, translog_path=translog_path,
-                             index_name=index_name)
+                             index_name=index_name, device=device)
         self.searcher = ShardSearcher(self.engine.segments, mappings, analysis,
                                       shard_ord=shard_id, index_name=index_name)
         self.state = "STARTED"

@@ -810,6 +810,31 @@ def bm25_term_group_topk(dense_impact, doc_ids, tfnorm, live, roots, words,
                        topk_block=topk_block)[0]
 
 
+def merge_shard_topk(all_packed, *, k: int):
+    """The global top-k of S shards' packed results, by (score desc,
+    shard, local doc): ``all_packed`` i32[S, 2k+1] is every shard's
+    :func:`pack_topk_result` (an ``all_gather`` of them inside the sharded
+    program). Returns ONE i32[3k + S]: ``scores | shard | local doc |
+    the shards' totals``. Only selects: a score leaves as the bits it
+    came in with; non-hits (-inf) sort last."""
+    S = all_packed.shape[0]
+    vals = lax.bitcast_convert_type(all_packed[:, :k], jnp.float32)
+    shard = lax.broadcasted_iota(jnp.int32, (S, k), 0)
+    neg, shard, local = lax.sort(
+        (-vals.reshape(-1), shard.reshape(-1),
+         all_packed[:, k: 2 * k].reshape(-1)), num_keys=3)
+    return jnp.concatenate([
+        lax.bitcast_convert_type(-neg[:k], jnp.int32), shard[:k], local[:k],
+        all_packed[:, 2 * k]])
+
+
+def unpack_shard_topk(packed_np, k: int, S: int):
+    """np i32[3k+S] → (vals f32[k], shard i32[k], local i32[k],
+    totals i32[S])."""
+    return (packed_np[:k].view(np.float32), packed_np[k: 2 * k],
+            packed_np[2 * k: 3 * k], packed_np[3 * k: 3 * k + S])
+
+
 # ---------------------------------------------------------------------------
 # per-field segment reductions (aggregation building blocks)
 # ---------------------------------------------------------------------------

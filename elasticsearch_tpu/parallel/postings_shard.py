@@ -31,7 +31,12 @@ cannot be stacked into the [S, ...] per-shard arrays the mesh executor
 ships, so mesh_service falls back to the host loop for indices holding
 such segments (counted via mesh_fallback_total) and the host loop runs
 this program instead — postings-parallelism replaces segment-parallelism
-for exactly the segments where the latter is impossible.
+for exactly the segments where the latter is impossible. The mesh
+executor declines a second stacked copy from a smaller size on
+(``declines_stacked_copy``, 2^26 postings): a field between the two
+sizes is resident whole on its shard's chip and scored there by the host
+loop (``bm25_term_group_topk``; all shards at once by
+parallel/term_group_sharded.py), never split.
 
 HBM contract: freeze does NOT allocate the full single-device postings
 for an oversized field — InvertedField's lazy accessors keep the padded
@@ -55,10 +60,42 @@ import numpy as np
 
 from elasticsearch_tpu.utils.shapes import pow2_bucket
 
-# postings entries (doc_id+tfnorm pairs) above which a field's CSR is
-# split across devices. 64M entries ≈ 512 MB of padded postings arrays —
-# beyond this a single v5e chip's HBM share for one field is gone.
-POSTINGS_SHARD_NNZ = int(os.environ.get("ESTPU_POSTINGS_SHARD_NNZ", 1 << 26))
+# Two questions, two sizes.
+#
+# Can ONE chip hold the field whole? Asked by freeze (place the postings
+# or leave them on the host) and by ``InvertedField.postings_split``
+# (score on the chip or split in place) alike: ``chip_holds_whole``.
+# Reckoned against the chip's memory as the breaker layer knows it
+# (``resources.breakers.hbm_capacity``): a field's padded postings
+# (POSTING_BYTES each: doc id, tf, tf-norm, term id) may take a quarter
+# of it — 4 of 16 GiB, 2^28 postings. A number in ``POSTINGS_SHARD_NNZ``
+# (``ESTPU_POSTINGS_SHARD_NNZ``, or a test) takes the reckoning's place:
+# a field of that many postings is split.
+POSTINGS_SHARD_NNZ = (int(os.environ["ESTPU_POSTINGS_SHARD_NNZ"])
+                      if os.environ.get("ESTPU_POSTINGS_SHARD_NNZ")
+                      else None)
+POSTING_BYTES = 16
+# Can the mesh executor and the batched tiers stack a SECOND [S, ...] copy
+# of it? 64M entries ≈ 1 GiB of padded postings: beyond that they decline
+# (mesh_service, queries.batch tiers, hybrid) and the host loop serves —
+# from the chip that holds the field whole, or through the split.
+STACKED_COPY_NNZ = 1 << 26
+
+
+def chip_holds_whole(nnz: int) -> bool:
+    """Whether one chip holds a field of ``nnz`` postings whole."""
+    if POSTINGS_SHARD_NNZ is not None:
+        return nnz < POSTINGS_SHARD_NNZ
+    from elasticsearch_tpu.resources.breakers import hbm_capacity
+
+    return (POSTING_BYTES * pow2_bucket(max(nnz, 1), minimum=8)
+            <= hbm_capacity() // 4)
+
+
+def declines_stacked_copy(nnz: int) -> bool:
+    """Whether a second, stacked copy of a field of ``nnz`` postings is
+    declined: too big to hold twice, or split in place already."""
+    return nnz >= STACKED_COPY_NNZ or not chip_holds_whole(nnz)
 
 
 def _jax():

@@ -72,7 +72,8 @@ class ResidentArray:
     """
 
     def __init__(self, registry: "ResidencyRegistry", host: np.ndarray,
-                 label: str, tier: str, dtype: Any = None):
+                 label: str, tier: str, dtype: Any = None,
+                 device: Any = None):
         try:  # device dtype decides the footprint (bf16 halves it)
             itemsize = (np.dtype(dtype).itemsize if dtype is not None
                         else host.dtype.itemsize)
@@ -85,6 +86,9 @@ class ResidentArray:
         self.rehydrations = 0
         self._host = host
         self._dtype = dtype
+        # the owning shard's chip; None = the default device. A
+        # rehydration goes back where the first placement went.
+        self._device = device
         self._dev: Any = None
         self._lock = threading.Lock()
         self._registry = registry
@@ -100,11 +104,12 @@ class ResidentArray:
         return self._dev is not None
 
     def _place(self):
+        host = self._host
         if self._dtype is not None:
             import jax.numpy as jnp
 
-            return jnp.asarray(self._host, dtype=self._dtype)
-        return _jax_device_put(self._host)
+            host = jnp.asarray(host, dtype=self._dtype)
+        return _jax_device_put(host, self._device)
 
     def get(self):
         with self._lock:
@@ -219,14 +224,16 @@ class ResidencyRegistry:
 
     def put_array(self, host: np.ndarray, *, label: str,
                   tier: str = "fielddata", dtype: Any = None,
-                  best_effort: bool = False) -> Optional[ResidentArray]:
+                  best_effort: bool = False,
+                  device: Any = None) -> Optional[ResidentArray]:
         """Register ``host`` and place its device copy, charging the
         tier's breaker (evicting LRU peers under pressure). Raises
         CircuitBreakingException when nothing evictable covers the
         reservation — or returns None with ``best_effort=True`` (for
         pure accelerations like dense impact blocks, where the caller
         has a slower but correct path)."""
-        handle = ResidentArray(self, host, label, tier, dtype=dtype)
+        handle = ResidentArray(self, host, label, tier, dtype=dtype,
+                               device=device)
         try:
             self._reserve(handle.nbytes, tier, label, exclude=handle)
         except CircuitBreakingException:

@@ -173,24 +173,31 @@ class TermGroupPlan(NamedTuple):
     P: int
 
 
-def plan_term_group(ctx, query) -> Optional[TermGroupPlan]:
+def build_term_group_plan(ctx, query) -> Optional[TermGroupPlan]:
     """The plan when `query` is a pure disjunctive term group
     (:func:`_fused_eligible_terms`) on a field this device holds whole,
-    else None (the caller runs the query tree)."""
+    else None (the caller runs the query tree). Opens no span: a caller
+    that plans several shards under one ``search.plan`` calls this,
+    everyone else :func:`plan_term_group`."""
+    e = _fused_eligible_terms(ctx, query)
+    if e is None:
+        return None
+    field, (tlist, wlist) = e
+    inv = ctx.inv(field)
+    if inv is None or inv.postings_split() is not None:
+        return None
+    hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
+    if hyb is None:  # no dense block / no dense query term
+        starts, lens, ws, P, _n = ctx.chunked_slices(inv, tlist, wlist)
+        return TermGroupPlan(inv, None, None, None, starts, lens, ws, P)
+    impact, _qw, _qind, starts, lens, ws, P, _n, qrows, qrw = hyb
+    return TermGroupPlan(inv, impact, qrows, qrw, starts, lens, ws, P)
+
+
+def plan_term_group(ctx, query) -> Optional[TermGroupPlan]:
+    """:func:`build_term_group_plan` under its own ``search.plan`` span."""
     with span("search.plan"):
-        e = _fused_eligible_terms(ctx, query)
-        if e is None:
-            return None
-        field, (tlist, wlist) = e
-        inv = ctx.inv(field)
-        if inv is None or inv.postings_split() is not None:
-            return None
-        hyb = ctx.hybrid_slices(inv, tlist, wlist, need_qw=False)
-        if hyb is None:  # no dense block / no dense query term
-            starts, lens, ws, P, _n = ctx.chunked_slices(inv, tlist, wlist)
-            return TermGroupPlan(inv, None, None, None, starts, lens, ws, P)
-        impact, _qw, _qind, starts, lens, ws, P, _n, qrows, qrw = hyb
-        return TermGroupPlan(inv, impact, qrows, qrw, starts, lens, ws, P)
+        return build_term_group_plan(ctx, query)
 
 
 def term_group_topk(ctx, plan: TermGroupPlan, k: int):

@@ -654,7 +654,20 @@ def search_shards(
     from elasticsearch_tpu.utils.errors import CircuitBreakingException
 
     shard_failures: List[dict] = []
+    # shards that each hold their one segment on a chip of their own
+    # answer a plain term-group search with ONE program over all of them
+    # (parallel/term_group_sharded.py); None: shard after shard, below
+    from elasticsearch_tpu.parallel import term_group_sharded
+
+    tq = time.perf_counter()
+    sharded = term_group_sharded.query_phase(searchers, body, global_stats)
     for pos, s in enumerate(searchers):
+        if sharded is not None:
+            # (its docs carry their searcher's list position already)
+            results.append(sharded[pos])
+            s.stats.on_query((time.perf_counter() - tq) * 1000,
+                             groups=body.get("stats"))
+            continue
         tq = time.perf_counter()
         try:
             r = s.query_phase(body, global_stats, collect_full=scroll)
