@@ -1116,9 +1116,10 @@ def width_child(args) -> int:
     # -- the three kernels, at the dispatcher's tiles, against their twins --
     kern: dict = {}
 
-    from elasticsearch_tpu.ops.knn import knn_topk
+    from elasticsearch_tpu.ops.knn import knn_topk_stored
 
     slab = sift_seg.vectors["emb"].vecs
+    slab_terms = sift_seg.vectors["emb"].row_terms()
     Dk = int(slab.shape[0])
     klive = jnp.asarray(np.arange(Dk) < n)
     for Q, k, precise in ((8, 64, True), (256, 40, False)):
@@ -1131,8 +1132,8 @@ def width_child(args) -> int:
                                     precise=precise)
         pv, pi = np.asarray(pv), np.asarray(pi)
         secs = time.monotonic() - t0
-        xv, xi = knn_topk(qs, slab, klive, k=k, metric="cosine",
-                          use_bf16=False)
+        xv, xi = knn_topk_stored(qs, slab, slab_terms, klive, k=k,
+                                 metric="cosine", use_bf16=False)
         xv, xi = np.asarray(xv), np.asarray(xi)
         rtol = RTOL_SINGLE if precise else RTOL_BATCHED
         overlap = np.mean([len(set(a) & set(b)) / k

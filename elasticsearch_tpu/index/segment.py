@@ -515,7 +515,40 @@ class VectorColumn:
     # slab per freeze/snapshot call is measurable host CPU)
     _ck: Any = None
     _ck_max: int = -1
+    # lazy f32[max_docs] per-row term of this similarity's kNN score
+    # (row_terms below), resident beside the slab on the slab's chip
+    _row_terms: Any = None
     device = None  # the owning segment's chip (TpuSegment sets it)
+
+    def row_terms(self):
+        """The stored per-row term every kNN program over this slab reads
+        (ops/knn.knn_row_terms: ||v||^2 for l2_norm, 1/||v|| for cosine;
+        None for dot_product, which has none). The slab is immutable, so
+        the term is built ONCE, by one pass over the resident slab on its
+        own chip at the first kNN search, and lives as long as the column:
+        a merged or refreshed segment is a new column and builds its own,
+        a delete touches only the segment's live mask, an evicted slab
+        rehydrates to the same values and keeps it. Always resident (4 B a
+        slot, TpuSegment.memory_bytes charges it to the `segments`
+        breaker); never written to the store — a reloaded segment builds
+        it again. Counter: knn_row_terms_build, once a column."""
+        from elasticsearch_tpu.ops.knn import has_row_terms, knn_row_terms
+
+        if not has_row_terms(self.similarity):
+            return None
+        if self._row_terms is None:
+            # first-touch build is locked, as a column's first placement
+            # is (_resident_field): two searches must not both scan
+            lock = self.__dict__.setdefault("_row_terms_lock",
+                                            threading.Lock())
+            with lock:
+                if self._row_terms is None:
+                    from elasticsearch_tpu.monitor import kernels
+
+                    self._row_terms = knn_row_terms(
+                        self.vecs, metric=self.similarity)
+                    kernels.record("knn_row_terms_build")
+        return self._row_terms
 
     def cache_key(self, max_docs: int) -> str:
         if self._ck is None or self._ck_max != max_docs:
@@ -736,13 +769,19 @@ class TpuSegment:
 
     def memory_bytes(self) -> int:
         """Approximate ALWAYS-RESIDENT HBM footprint — the `segments`
-        breaker charge at freeze (live mask + postings). Doc-value
+        breaker charge at freeze (live mask + postings + a vector
+        column's stored row term, VectorColumn.row_terms). Doc-value
         columns and vector slabs are NOT counted here: they load lazily
         into the evictable fielddata tier and charge the fielddata
         breaker on first touch (resources/residency.py)."""
+        from elasticsearch_tpu.ops.knn import has_row_terms
+
         total = self.max_docs  # live mask
         for inv in self.inverted.values():
             total += inv.nnz_pad * (4 + 4 + 4 + 4)
+        for vc in self.vectors.values():
+            if has_row_terms(vc.similarity):
+                total += 4 * self.max_docs
         return total
 
     def _column_iter(self):

@@ -47,6 +47,10 @@ Names:
                       (index/ivf_cache.py) instead of rebuilt
   pq_build            PQ codebooks trained + slab encoded at freeze
   pq_cache_hit        PQ tier reloaded from the persisted blob cache
+  knn_row_terms_build  a vector column built the stored per-row term of its
+                      kNN score (VectorColumn.row_terms: one pass over its
+                      immutable slab, once a column); a window in which
+                      this rises rebuilt one
   mesh_search         request served by the mesh product path
   mesh_fallback_total request fell back to the host per-shard loop
   mesh_build_failed   an index's shard mesh could not be built (logged

@@ -307,7 +307,7 @@ def make_ivf_search(C: int, Lmax: int, D: int, nprobe: int, metric: str,
     import jax.numpy as jnp
     from jax import lax
 
-    from elasticsearch_tpu.ops.knn import knn_scores
+    from elasticsearch_tpu.ops.knn import knn_row_terms, knn_scores
 
     @jax.jit
     def run(query, centroids, lists, vecs):
@@ -325,8 +325,10 @@ def make_ivf_search(C: int, Lmax: int, D: int, nprobe: int, metric: str,
         # 3. exact metric on candidates only — f32: the whole point of IVF
         # is to spend full precision on a small candidate set (the brute
         # path's bf16 trade-off buys nothing on a matmul this size)
-        cscores = knn_scores(query[None, :], cvecs, metric=metric,
-                             use_bf16=False)[0]
+        # (the row term of the gathered candidates, never of the slab)
+        cscores = knn_scores(query[None, :], cvecs,
+                             knn_row_terms(cvecs, metric=metric),
+                             metric=metric, use_bf16=False)[0]
         # 4. expand to the whole-segment score vector
         if scatter_free:
             # each vector belongs to exactly ONE list, so candidate ids
@@ -380,7 +382,7 @@ def make_ivf_pq_search(C: int, Lmax: int, D: int, nprobe: int, metric: str,
     from jax import lax
 
     from elasticsearch_tpu.ops.bitvec import test_bits
-    from elasticsearch_tpu.ops.knn import knn_scores
+    from elasticsearch_tpu.ops.knn import knn_row_terms, knn_scores
 
     @jax.jit
     def run(query, centroids, lists, vecs, *rest):
@@ -417,14 +419,16 @@ def make_ivf_pq_search(C: int, Lmax: int, D: int, nprobe: int, metric: str,
             fvalid = fv > -jnp.inf
             fsafe = jnp.where(fvalid, fids, 0)
             fvecs = vecs[fsafe]  # [fine_k, dims] — the ONLY f32 gather
-            fscores = knn_scores(query[None, :], fvecs, metric=metric,
-                                 use_bf16=False)[0]
+            fscores = knn_scores(query[None, :], fvecs,
+                                 knn_row_terms(fvecs, metric=metric),
+                                 metric=metric, use_bf16=False)[0]
             fscores = jnp.where(fvalid, fscores, -jnp.inf)
         else:
             # pre-filter-only caller: exact scores for every candidate
             cvecs = vecs[safe]
-            cs = knn_scores(query[None, :], cvecs, metric=metric,
-                            use_bf16=False)[0]
+            cs = knn_scores(query[None, :], cvecs,
+                            knn_row_terms(cvecs, metric=metric),
+                            metric=metric, use_bf16=False)[0]
             fids, fvalid = cand, valid
             fscores = jnp.where(valid, cs, -jnp.inf)
         tgt = jnp.where(fvalid, fids, D)  # invalid -> out of range, dropped

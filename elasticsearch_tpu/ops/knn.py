@@ -3,10 +3,13 @@
 The reference ES 2.0 predates dense_vector; this implements the north-star
 kNN path (BASELINE.json: SIFT1M exact-kNN at recall parity, ≥8× p50 vs CPU).
 Design: the corpus slab is a [D, dims] f32 array in HBM; queries arrive as
-[Q, dims]. Similarity = one bf16 matmul (cosine/dot) or a fused
-norm-expansion (l2), producing [Q, D] scores tiled by XLA onto the MXU,
-followed by masked top-k. For very large D the executor scans HBM chunks
-with lax.map to bound the [Q, D] intermediate.
+[Q, dims]. Similarity = one matmul (bf16 sweep or f32 HIGHEST) and the
+stored per-row term of the metric (``knn_row_terms``: ||v||^2 for the l2
+norm-expansion, 1/||v|| for cosine — a constant of the immutable slab,
+built once a column and read by every program, so a search is ONE pass
+over the slab at any Q), producing [Q, D] scores tiled by XLA onto the
+MXU, followed by masked top-k. For very large D the executor scans HBM
+chunks with lax.map to bound the [Q, D] intermediate.
 """
 from __future__ import annotations
 
@@ -21,9 +24,43 @@ from elasticsearch_tpu.ops.scoring import topk_auto
 NEG_INF = jnp.float32(-jnp.inf)
 
 
+def has_row_terms(metric: str) -> bool:
+    """Whether a kNN score under ``metric`` has a term that depends on the
+    stored vectors alone (``knn_row_terms``); ``dot_product`` has none."""
+    return metric not in ("dot_product", "dot")
+
+
+@partial(jax.jit, static_argnames=("metric",))
+def knn_row_terms(vecs, *, metric: str):
+    """The per-row term of a kNN score that depends only on the stored
+    vectors, f32[..., D] for vecs [..., D, dims]:
+
+      l2_norm:     ||v||^2
+      cosine:      1 / max(||v||, 1e-12)
+      dot_product: None (no such term)
+
+    A segment's slab is immutable, so its ``VectorColumn`` runs this ONCE
+    on the slab's own chip and keeps the result resident beside it
+    (``VectorColumn.row_terms``); every kNN program below takes the term
+    as an input and none reduces over the slab to rebuild it. A caller
+    that scores a gathered subset runs it on the rows it holds."""
+    if not has_row_terms(metric):
+        return None
+    v2 = jnp.sum(vecs.astype(jnp.float32) ** 2, axis=-1)
+    if metric == "cosine":
+        return 1.0 / jnp.maximum(jnp.sqrt(v2), 1e-12)
+    if metric in ("l2_norm", "l2"):
+        return v2
+    raise ValueError(f"unknown knn metric [{metric}]")
+
+
 @partial(jax.jit, static_argnames=("metric", "use_bf16"))
-def knn_scores(queries, vecs, *, metric: str = "cosine", use_bf16: bool = True):
+def knn_scores(queries, vecs, row_terms, *, metric: str = "cosine",
+               use_bf16: bool = True):
     """Similarity scores [Q, D] between queries [Q, dims] and corpus [D, dims].
+
+    ``row_terms`` f32[D] is ``knn_row_terms(vecs, metric=metric)``, read
+    here and never recomputed: one pass over the corpus, the product.
 
     Scoring matches ES dense_vector `similarity` semantics:
       cosine:      (1 + cos) / 2           (ES _score for cosine)
@@ -40,9 +77,8 @@ def knn_scores(queries, vecs, *, metric: str = "cosine", use_bf16: bool = True):
         prec = lax.Precision.HIGHEST
     if metric == "cosine":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12).astype(q.dtype)
-        vn = v / jnp.maximum(jnp.linalg.norm(v, axis=-1, keepdims=True), 1e-12).astype(v.dtype)
-        sim = jnp.matmul(qn, vn.T, preferred_element_type=jnp.float32, precision=prec)
-        return (1.0 + sim) * 0.5
+        dots = jnp.matmul(qn, v.T, preferred_element_type=jnp.float32, precision=prec)
+        return (1.0 + dots * row_terms[None, :]) * 0.5
     if metric in ("dot_product", "dot"):
         sim = jnp.matmul(q, v.T, preferred_element_type=jnp.float32, precision=prec)
         return (1.0 + sim) * 0.5
@@ -50,20 +86,34 @@ def knn_scores(queries, vecs, *, metric: str = "cosine", use_bf16: bool = True):
         # ||q - v||^2 = ||q||^2 - 2 q.v + ||v||^2 — matmul-dominant expansion
         dots = jnp.matmul(q, v.T, preferred_element_type=jnp.float32, precision=prec)
         q2 = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-        v2 = jnp.sum(vecs.astype(jnp.float32) ** 2, axis=-1)[None, :]
-        d2 = jnp.maximum(q2 - 2.0 * dots + v2, 0.0)
+        d2 = jnp.maximum(q2 - 2.0 * dots + row_terms[None, :], 0.0)
         return 1.0 / (1.0 + d2)
     raise ValueError(f"unknown knn metric [{metric}]")
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "use_bf16", "topk_block"))
-def knn_topk(queries, vecs, mask, *, k: int, metric: str = "cosine",
-             use_bf16: bool = True, topk_block: int = 0):
+def knn_topk_stored(queries, vecs, row_terms, mask, *, k: int,
+                    metric: str = "cosine", use_bf16: bool = True,
+                    topk_block: int = 0):
     """Fused scores + masked top-k: ([Q, k] scores, [Q, k] doc ids)."""
-    scores = knn_scores(queries, vecs, metric=metric, use_bf16=use_bf16)
+    scores = knn_scores(queries, vecs, row_terms, metric=metric,
+                        use_bf16=use_bf16)
     masked = jnp.where(mask[None, :], scores, NEG_INF)
     vals, idx = topk_auto(masked, k, topk_block)
     return vals, idx.astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("k", "metric", "use_bf16", "topk_block"))
+def knn_topk(queries, vecs, mask, *, k: int, metric: str = "cosine",
+             use_bf16: bool = True, topk_block: int = 0):
+    """``knn_topk_stored`` over bare rows that no ``VectorColumn`` backs:
+    the term is built here, for the rows handed in. Nothing that holds a
+    resident slab calls this (it would read the slab twice a call); it
+    keeps the three-array form the benchmark's compile check lowers
+    (tests/bench_harness/test_bench_tpu_compile.py)."""
+    return knn_topk_stored(queries, vecs, knn_row_terms(vecs, metric=metric),
+                           mask, k=k, metric=metric, use_bf16=use_bf16,
+                           topk_block=topk_block)
 
 
 @partial(jax.jit, static_argnames=("metric",))
@@ -126,8 +176,9 @@ def merge_candidate_topk(vals, ids, *, k: int):
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "chunk", "use_bf16"))
-def knn_topk_chunked(queries, vecs, mask, *, k: int, metric: str = "cosine",
-                     chunk: int = 1 << 16, use_bf16: bool = True):
+def knn_topk_chunked(queries, vecs, row_terms, mask, *, k: int,
+                     metric: str = "cosine", chunk: int = 1 << 16,
+                     use_bf16: bool = True):
     """HBM-bounded scan over corpus chunks, merging running top-k.
 
     Keeps the intermediate at [Q, chunk] instead of [Q, D]; used when
@@ -143,7 +194,9 @@ def knn_topk_chunked(queries, vecs, mask, *, k: int, metric: str = "cosine",
         best_v, best_i = carry
         v = lax.dynamic_slice_in_dim(vecs, i * chunk, chunk, axis=0)
         m = lax.dynamic_slice_in_dim(mask, i * chunk, chunk, axis=0)
-        s = knn_scores(queries, v, metric=metric, use_bf16=use_bf16)
+        t = (None if row_terms is None else
+             lax.dynamic_slice_in_dim(row_terms, i * chunk, chunk, axis=0))
+        s = knn_scores(queries, v, t, metric=metric, use_bf16=use_bf16)
         s = jnp.where(m[None, :], s, NEG_INF)
         cand_v, cand_i = lax.top_k(s, min(k, chunk))
         cand_i = cand_i + i * chunk

@@ -334,9 +334,13 @@ def _knn_tile_for(Q: int, dims: int, k: int, D: int) -> int:
     return 0
 
 
-def knn_topk_auto(queries, vecs, mask, *, k: int, metric: str = "cosine",
-                  precise: bool = False):
+def knn_topk_auto(queries, vecs, row_terms, mask, *, k: int,
+                  metric: str = "cosine", precise: bool = False):
     """Dispatch: Pallas fused kernel on TPU when shapes fit, XLA otherwise.
+
+    ``row_terms`` is the slab's stored per-row term (ops.knn.knn_row_terms;
+    ``VectorColumn.row_terms()``), which the XLA program reads; the Pallas
+    kernel builds its norms inside the tile it streams anyway.
 
     precise=True scores in f32 end to end (Pallas multi-pass / XLA
     use_bf16=False) — exact-kNN recall parity for latency-path queries;
@@ -354,7 +358,7 @@ def knn_topk_auto(queries, vecs, mask, *, k: int, metric: str = "cosine",
     to 8 with zero queries and slices the result — round 1 sent every
     single-query request down the XLA path that materializes the [Q, D]
     matrix this kernel exists to avoid."""
-    from elasticsearch_tpu.ops.knn import knn_topk
+    from elasticsearch_tpu.ops.knn import knn_topk_stored
 
     Q, dims = queries.shape
     D = vecs.shape[0]
@@ -373,8 +377,9 @@ def knn_topk_auto(queries, vecs, mask, *, k: int, metric: str = "cosine",
                                tile=tile, precise=precise)
     from elasticsearch_tpu.ops.scoring import topk_block_config
 
-    return knn_topk(queries, vecs, mask, k=k, metric=metric,
-                    use_bf16=not precise, topk_block=topk_block_config())
+    return knn_topk_stored(queries, vecs, row_terms, mask, k=k, metric=metric,
+                           use_bf16=not precise,
+                           topk_block=topk_block_config())
 
 
 # ---------------------------------------------------------------------------

@@ -505,13 +505,17 @@ class ColPrim(DataPrim):
 class VecsPrim(DataPrim):
     """dense_vector slab for knn-as-query: vecs [S, D, dims] + exists
     [S, D] (cached per segment round) + the query vector broadcast
-    [S, dims] (per-request data)."""
+    [S, dims] (per-request data) + the stacked slab's stored row term
+    [S, D] (ops/knn.knn_row_terms: built once on the device from the
+    cached slab and cached beside it; a ``dot_product`` field has none
+    and the group is three arrays)."""
 
-    n_arrays = 3
+    n_arrays = 4
 
-    def __init__(self, field: str, qvec):
+    def __init__(self, field: str, qvec, metric: str):
         self.field = field
         self.qvec = np.asarray(qvec, np.float32)
+        self.metric = metric
 
     def build(self, seg_row, ctxs, D, S, cache):
         dims = self.qvec.shape[0]
@@ -530,9 +534,16 @@ class VecsPrim(DataPrim):
                     h_ex[si, : ex.shape[0]] = ex
             return [h_vecs, h_ex]
 
-        key = ("vecs", self.field, tuple(id(s) for s in seg_row), D, dims)
-        arrays = list(cache(key, fill))
+        from elasticsearch_tpu.ops.knn import has_row_terms, knn_row_terms
+
+        segs = tuple(id(s) for s in seg_row)
+        arrays = list(cache(("vecs", self.field, segs, D, dims), fill))
         arrays.append(np.broadcast_to(self.qvec, (S, dims)).copy())
+        if has_row_terms(self.metric):
+            slab = arrays[0]
+            arrays += cache(
+                ("vec_terms", self.field, segs, D, dims, self.metric),
+                lambda: [knn_row_terms(slab, metric=self.metric)])
         return arrays, (dims,)
 
 
@@ -1005,13 +1016,15 @@ class EKnn(Emit):
         from elasticsearch_tpu.ops.pallas_kernels import knn_topk_auto
 
         jnp = _jnp()
-        vecs, exists, q = env[self.prim]
+        vecs, exists, q, *terms = env[self.prim]
         lv = exists & env[self.live][0]
         if self.filter is not None:
             _, fm = self.filter.ex(env, meta)
             lv = lv & fm
-        vals, idx = knn_topk_auto(q[None, :], vecs, lv, k=self.kc,
-                                  metric=self.metric, precise=True)
+        vals, idx = knn_topk_auto(q[None, :], vecs,
+                                  terms[0] if terms else None, lv,
+                                  k=self.kc, metric=self.metric,
+                                  precise=True)
         valid = vals[0] > -jnp.inf
         scores = jnp.zeros(self.D, jnp.float32).at[idx[0]].max(
             jnp.where(valid, vals[0] * self.boost, 0.0), mode="drop")
@@ -1545,9 +1558,9 @@ class MeshQueryCompiler:
         filt = self._c(q.filter) if q.filter is not None else None
         # tokens[0], not the raw body value: a single-token query_vectors
         # body arrives nested ([1, dims]) and VecsPrim wants the 1-D vector
-        prim = self._add(VecsPrim(q.field, q.tokens[0]))
-        kc = int(min(max(q.num_candidates, q.k), self.D))
         metric = getattr(fm, "similarity", None) or "cosine"
+        prim = self._add(VecsPrim(q.field, q.tokens[0], metric))
+        kc = int(min(max(q.num_candidates, q.k), self.D))
         return EKnn(prim, filt, self._live, kc, metric, q.boost, self.D)
 
     def _function_score(self, q) -> Emit:

@@ -161,10 +161,11 @@ def _bm25_program(mesh, cache, *, Q: int, T: int, P: int, D: int, k: int):
 def _knn_program(mesh, cache, *, Q: int, dims: int, D: int, k: int, metric: str):
     """Distributed brute-force kNN: queries replicated, vector slabs sharded.
 
-    vecs f32[S, D, dims] sharded over 'shard'; queries f32[Q, dims]
-    replicated; live bool[S, D]. bf16 matmul on the MXU per shard, local
-    top-k, all_gather merge — the ES-2.0-era equivalent would be a
-    per-shard Lucene scan + coordinator merge.
+    vecs f32[S, D, dims] sharded over 'shard'; terms f32[S, D] the
+    slabs' stored row term (ops/knn.knn_row_terms; None for dot_product);
+    queries f32[Q, dims] replicated; live bool[S, D]. bf16 matmul on the
+    MXU per shard, local top-k, all_gather merge — the ES-2.0-era
+    equivalent would be a per-shard Lucene scan + coordinator merge.
     """
     from elasticsearch_tpu.ops.scoring import topk_block_config
 
@@ -177,12 +178,12 @@ def _knn_program(mesh, cache, *, Q: int, dims: int, D: int, k: int, metric: str)
     from jax import lax
     from jax.sharding import PartitionSpec as PS
 
-    from elasticsearch_tpu.ops.knn import exact_rescore_topk
+    from elasticsearch_tpu.ops.knn import exact_rescore_topk, has_row_terms
     from elasticsearch_tpu.ops.pallas_kernels import knn_topk_auto
 
     psum, all_gather, wrap, sl = _collectives(mesh)
 
-    def body(queries, vecs, live):
+    def body(queries, vecs, terms, live):
         # per-shard fused scores+mask+topk: the Pallas streaming kernel on
         # TPU (no [Q, D] HBM intermediate), the XLA path elsewhere. bf16
         # sweep OVERSAMPLED 4x (bf16's ~3-digit mantissa can rank a true
@@ -190,8 +191,9 @@ def _knn_program(mesh, cache, *, Q: int, dims: int, D: int, k: int, metric: str)
         # an f32 re-rank of the candidates cut back to k — FAISS-style
         # two-stage refinement, so merged results keep exact recall.
         kp = min(max(4 * k, k), D)
-        vals, idx = knn_topk_auto(queries, sl(vecs), sl(live), k=kp,
-                                  metric=metric)
+        vals, idx = knn_topk_auto(queries, sl(vecs),
+                                  None if terms is None else sl(terms),
+                                  sl(live), k=kp, metric=metric)
         vals, idx = exact_rescore_topk(queries, sl(vecs), vals, idx,
                                        metric=metric)
         vals, idx = vals[:, :k], idx[:, :k]
@@ -207,7 +209,10 @@ def _knn_program(mesh, cache, *, Q: int, dims: int, D: int, k: int, metric: str)
 
     from elasticsearch_tpu.parallel import aot
 
-    fn = wrap(body, (PS(), PS("shard"), PS("shard")), (PS(), PS(), PS()))
+    # a dot_product slab has no row term: None in, no spec for it
+    terms_spec = PS("shard") if has_row_terms(metric) else None
+    fn = wrap(body, (PS(), PS("shard"), terms_spec, PS("shard")),
+              (PS(), PS(), PS()))
     fn = aot.wrap(fn, "mesh_knn", key)
     cache[key] = fn
     return fn
@@ -233,16 +238,18 @@ def _maxsim_program(mesh, cache, *, Q: int, T: int, dims: int, D: int,
     from jax.sharding import PartitionSpec as PS
 
     from elasticsearch_tpu.ops.knn import (exact_rescore_topk,
+                                           has_row_terms,
                                            merge_candidate_topk)
     from elasticsearch_tpu.ops.pallas_kernels import knn_topk_auto
 
     psum, all_gather, wrap, sl = _collectives(mesh)
 
-    def body(tokens, vecs, live):
+    def body(tokens, vecs, terms, live):
         flat = tokens.reshape(Q * T, dims)
         kp = min(max(4 * k, k), D)
-        vals, idx = knn_topk_auto(flat, sl(vecs), sl(live), k=kp,
-                                  metric=metric)
+        vals, idx = knn_topk_auto(flat, sl(vecs),
+                                  None if terms is None else sl(terms),
+                                  sl(live), k=kp, metric=metric)
         vals, idx = exact_rescore_topk(flat, sl(vecs), vals, idx,
                                        metric=metric)
         # per-request dedup-by-max over the token axis, then local top-k
@@ -260,7 +267,10 @@ def _maxsim_program(mesh, cache, *, Q: int, T: int, dims: int, D: int,
 
     from elasticsearch_tpu.parallel import aot
 
-    fn = wrap(body, (PS(), PS("shard"), PS("shard")), (PS(), PS(), PS()))
+    # a dot_product slab has no row term: None in, no spec for it
+    terms_spec = PS("shard") if has_row_terms(metric) else None
+    fn = wrap(body, (PS(), PS("shard"), terms_spec, PS("shard")),
+              (PS(), PS(), PS()))
     fn = aot.wrap(fn, "mesh_maxsim", key)
     cache[key] = fn
     return fn
@@ -512,7 +522,11 @@ class MeshSearchExecutor:
         """Device-put a host array laid out [S, ...] for the mesh. On a
         single-slot mesh the shard dim is dropped HERE, on host: slicing
         it inside the program wraps downstream dots in loop fusions (see
-        _collectives). np indexing is a view — no host copy."""
+        _collectives). np indexing is a view — no host copy. An array a
+        cached group derived on the device from its own placed arrays (a
+        slab's row term) is in that layout already and passes through."""
+        if hasattr(a, "sharding"):
+            return a
         jax = _jax()
         # offbudget: mesh placement choke point — transient per-query
         # inputs; the persistent rounds are charged via RESIDENCY.track
@@ -708,7 +722,7 @@ class MeshSearchExecutor:
             queries = np.concatenate(
                 [queries, np.repeat(queries[:1], Q - Qr, axis=0)])
         out = self._search_vector_rounds(
-            field, queries, k, dims,
+            field, queries, k, dims, metric,
             # dims is the field mapping's embedding width — a config-bounded
             # shape class, not request data  # tpulint: bucketed
             lambda D: _knn_program(self.mesh, self._programs, Q=Q,
@@ -732,7 +746,7 @@ class MeshSearchExecutor:
             tokens = np.concatenate(
                 [tokens, np.repeat(tokens[:1], Q - Qr, axis=0)])
         out = self._search_vector_rounds(
-            field, tokens, k, dims,
+            field, tokens, k, dims, metric,
             # T is the encoder's token grid (repeat-padded to its bucket
             # upstream — search/batch.py) and dims the mapping's embedding
             # width: config-bounded shape classes  # tpulint: bucketed
@@ -744,13 +758,15 @@ class MeshSearchExecutor:
                      for a in out)
 
     def _search_vector_rounds(self, field: str, qarr: np.ndarray, k: int,
-                              dims: int, make_prog,
+                              dims: int, metric: str, make_prog,
                               prog_name: str = "mesh_knn"):
         """Per-round scaffold shared by the kNN and MaxSim programs:
         slab group build/cache (one upload serves both — the data key is
         program-agnostic), live∧exists mask fill, program dispatch, and
         the cross-round top-k merge. ``make_prog(D)`` supplies the
         compiled program for the round's shape class."""
+        from elasticsearch_tpu.ops.knn import has_row_terms, knn_row_terms
+
         jax = _jax()
 
         merged = None
@@ -773,8 +789,15 @@ class MeshSearchExecutor:
                         h_vecs[si, : v.shape[0]] = v
                 return self._put_sharded(h_vecs)
 
-            data_key = ("knn", field, tuple(id(s) for s in seg_row), D, dims)
-            d_vecs = self._cached_data(data_key, build_vecs, seg_row)
+            segs = tuple(id(s) for s in seg_row)
+            d_vecs = self._cached_data(("knn", field, segs, D, dims),
+                                       build_vecs, seg_row)
+            # the stacked slab's stored row term [S, D], built once on the
+            # device from the cached slab (None for dot_product)
+            d_terms = (self._cached_data(
+                ("knn_terms", field, segs, D, dims, metric),
+                lambda: knn_row_terms(d_vecs, metric=metric), seg_row)
+                if has_row_terms(metric) else None)
 
             h_live = np.zeros((self.S, D), bool)
             for si, seg in enumerate(seg_row):
@@ -800,7 +823,7 @@ class MeshSearchExecutor:
                     vals, slot, local = prog(
                         # offbudget: transient per-call query/token upload
                         jax.device_put(np.asarray(qarr, np.float32)),  # tpulint: offbudget
-                        d_vecs, self._put_sharded(h_live))
+                        d_vecs, d_terms, self._put_sharded(h_live))
                 with span("device.wait"):
                     slot = np.asarray(slot)
                     vals, local = np.asarray(vals), np.asarray(local)

@@ -201,7 +201,7 @@ def knn_topk_fused_batch(ctx, queries, k: int):
     with span("device.dispatch", program="batch_knn_fused"):
         lv = vc.exists & ctx.segment.live
         flat = jnp.asarray(toks.reshape(Q * T, vc.dims))
-        vals, idx = knn_topk_auto(flat, vc.vecs, lv, k=kc,
+        vals, idx = knn_topk_auto(flat, vc.vecs, vc.row_terms(), lv, k=kc,
                                   metric=vc.similarity, precise=True)
         best_v, best_i, n_unique = merge_candidate_topk(
             vals.reshape(Q, T * kc), idx.reshape(Q, T * kc), k=min(k, kc))

@@ -92,7 +92,7 @@ def _fuse_math(lex_s, lex_m, vec_s, vec_m, weights, rank_constant, *,
     return fused, lex_m | vec_m
 
 
-def _vector_side(qvec, vecs, vmask, kc, vboost, *, metric: str):
+def _vector_side(qvec, vecs, vterms, vmask, kc, vboost, *, metric: str):
     """Brute-force vector engine inside the fused program: f32 scores for
     every doc + the top-``kc`` candidate mask (ES knn-query semantics:
     candidates beyond num_candidates are non-matches). The rank that
@@ -102,7 +102,8 @@ def _vector_side(qvec, vecs, vmask, kc, vboost, *, metric: str):
     jnp = _jnp()
     from elasticsearch_tpu.ops.knn import knn_scores
 
-    vs = knn_scores(qvec[None, :], vecs, metric=metric, use_bf16=False)[0]
+    vs = knn_scores(qvec[None, :], vecs, vterms, metric=metric,
+                    use_bf16=False)[0]
     key = jnp.where(vmask, vs, NEG_INF)
     order = jnp.argsort(-key, stable=True)
     rank = jnp.argsort(order, stable=True)
@@ -110,17 +111,17 @@ def _vector_side(qvec, vecs, vmask, kc, vboost, *, metric: str):
     return vs * vboost, vm
 
 
-def _fuse_select(lex, live, qvec, vecs, vexists, weights, rank_constant,
-                 kc, vboost, *, k: int, method: str, metric: str,
-                 topk_block: int):
+def _fuse_select(lex, live, qvec, vecs, vterms, vexists, weights,
+                 rank_constant, kc, vboost, *, k: int, method: str,
+                 metric: str, topk_block: int):
     """Shared tail of both stage-1 program variants: vector engine →
     fusion → single masked top-k + exact total, packed for ONE host pull."""
     jnp = _jnp()
     from elasticsearch_tpu.ops.scoring import pack_topk_result, topk_auto
 
     lex_m = (lex > 0) & live
-    vec_s, vec_m = _vector_side(qvec, vecs, vexists & live, kc, vboost,
-                                metric=metric)
+    vec_s, vec_m = _vector_side(qvec, vecs, vterms, vexists & live, kc,
+                                vboost, metric=metric)
     fused, mask = _fuse_math(lex, lex_m, vec_s, vec_m, weights,
                              rank_constant, method=method)
     masked = jnp.where(mask, fused, NEG_INF)
@@ -134,26 +135,27 @@ def _fuse_select(lex, live, qvec, vecs, vexists, weights, rank_constant,
 # ---------------------------------------------------------------------------
 
 def _hybrid_topk_gather(impact, qrows, qrw, doc_ids, tfnorm, starts, lens,
-                        ws, live, qvec, vecs, vexists, weights,
+                        ws, live, qvec, vecs, vterms, vexists, weights,
                         rank_constant, kc, vboost, *, P: int, D: int,
                         k: int, method: str, metric: str, topk_block: int):
     """Stage-1, dense-impact lexical form: BM25 gathers only the query's
-    dense rows (+ scatter tail), the vector engine sweeps the slab, and
-    fusion + top-k + total land in the SAME program — one device dispatch
-    and one packed i32[2k+1] pull per segment."""
+    dense rows (+ scatter tail), the vector engine sweeps the slab (once:
+    ``vterms`` is the column's stored row term), and fusion + top-k +
+    total land in the SAME program — one device dispatch and one packed
+    i32[2k+1] pull per segment."""
     from elasticsearch_tpu.ops.scoring import bm25_score_hybrid_gather
 
     TRACE_COUNTS["hybrid_fused_topk"] += 1
     lex = bm25_score_hybrid_gather(impact, qrows, qrw, doc_ids, tfnorm,
                                    starts, lens, ws, P=P, D=D)
-    return _fuse_select(lex, live, qvec, vecs, vexists, weights,
+    return _fuse_select(lex, live, qvec, vecs, vterms, vexists, weights,
                         rank_constant, kc, vboost, k=k, method=method,
                         metric=metric, topk_block=topk_block)
 
 
 def _hybrid_topk_scatter(doc_ids, tfnorm, starts, lens, ws, live, qvec,
-                         vecs, vexists, weights, rank_constant, kc, vboost,
-                         *, P: int, D: int, k: int, method: str,
+                         vecs, vterms, vexists, weights, rank_constant, kc,
+                         vboost, *, P: int, D: int, k: int, method: str,
                          metric: str, topk_block: int):
     """Stage-1, scatter-only lexical form (segments without a dense
     impact block — small corpora, all-rare term groups)."""
@@ -161,7 +163,7 @@ def _hybrid_topk_scatter(doc_ids, tfnorm, starts, lens, ws, live, qvec,
 
     TRACE_COUNTS["hybrid_fused_topk_scatter"] += 1
     lex = bm25_score_segment(doc_ids, tfnorm, starts, lens, ws, P=P, D=D)
-    return _fuse_select(lex, live, qvec, vecs, vexists, weights,
+    return _fuse_select(lex, live, qvec, vecs, vterms, vexists, weights,
                         rank_constant, kc, vboost, k=k, method=method,
                         metric=metric, topk_block=topk_block)
 
@@ -402,15 +404,15 @@ def hybrid_fused_topk(ctx, query: HybridQuery, k: int):
         prog = _program("hybrid_fused_topk", _hybrid_topk_gather)
         packed = prog(impact, jnp.asarray(qrows), jnp.asarray(qrw),
                       inv.doc_ids, inv.tfnorm, starts, lens, ws, live,
-                      qvec, vc.vecs, vc.exists, weights, rank_c,
-                      jnp.int32(kc), jnp.float32(knn.boost),
+                      qvec, vc.vecs, vc.row_terms(), vc.exists, weights,
+                      rank_c, jnp.int32(kc), jnp.float32(knn.boost),
                       P=P, D=ctx.D, **common)
     else:
         starts, lens, ws, P, _n = ctx.chunked_slices(inv, tlist, wlist)
         prog = _program("hybrid_fused_topk_scatter", _hybrid_topk_scatter)
         packed = prog(inv.doc_ids, inv.tfnorm, starts, lens, ws, live,
-                      qvec, vc.vecs, vc.exists, weights, rank_c,
-                      jnp.int32(kc), jnp.float32(knn.boost),
+                      qvec, vc.vecs, vc.row_terms(), vc.exists, weights,
+                      rank_c, jnp.int32(kc), jnp.float32(knn.boost),
                       P=P, D=ctx.D, **common)
     kernels.record("hybrid_fused_topk")
     # ONE packed pull (i32[2k+1] bitcast) — the fused-path transfer budget
@@ -423,7 +425,7 @@ def hybrid_fused_topk(ctx, query: HybridQuery, k: int):
 # ---------------------------------------------------------------------------
 
 def _hybrid_topk_batch(impact, qrows, qrw, doc_ids, tfnorm, starts, lens,
-                       ws, live, toks, vecs, vexists, weights,
+                       ws, live, toks, vecs, vterms, vexists, weights,
                        rank_constants, kcs, vboosts, *, P: int, D: int,
                        k: int, method: str, metric: str, topk_block: int):
     """Batched stage-1: per-query dense-row gather lexical scores
@@ -444,7 +446,8 @@ def _hybrid_topk_batch(impact, qrows, qrw, doc_ids, tfnorm, starts, lens,
     lex = lex + bm25_score_batch(doc_ids, tfnorm, starts, lens, ws,
                                  P=P, D=D)
     lex_m = (lex > 0) & live[None, :]
-    vs = knn_scores(toks, vecs, metric=metric, use_bf16=False)  # [Q, D]
+    vs = knn_scores(toks, vecs, vterms, metric=metric,
+                    use_bf16=False)  # [Q, D]
     vmask = (vexists & live)[None, :]
     key = jnp.where(vmask, vs, NEG_INF)
     order = jnp.argsort(-key, axis=1, stable=True)
@@ -537,7 +540,7 @@ def hybrid_fused_topk_batch(ctx, queries: List[HybridQuery], k: int):
         impact, jnp.asarray(qrows), jnp.asarray(qrw), inv.doc_ids,
         inv.tfnorm, jnp.asarray(starts), jnp.asarray(lens),
         jnp.asarray(ws), ctx.segment.live, jnp.asarray(toks), vc.vecs,
-        vc.exists, jnp.asarray(weights), jnp.asarray(rcs),
+        vc.row_terms(), vc.exists, jnp.asarray(weights), jnp.asarray(rcs),
         jnp.asarray(kcs), jnp.asarray(boosts), P=P, D=ctx.D, k=kk,
         method=q0.method, metric=vc.similarity,
         topk_block=topk_block_config())

@@ -1185,7 +1185,7 @@ class KnnQuery(Query):
         # per-token fused top-kc (precise: the latency path's exact-recall
         # contract), then a device scatter-MAX merge — the union of the
         # per-token top-kc provably covers the per-doc-max top-kc
-        vals, idx = knn_topk_auto(toks, vc.vecs, lv, k=kc,
+        vals, idx = knn_topk_auto(toks, vc.vecs, vc.row_terms(), lv, k=kc,
                                   metric=vc.similarity, precise=True)
         kernels.record("knn_maxsim")
         valid = (vals > -jnp.inf).reshape(-1)
@@ -1306,8 +1306,8 @@ class KnnQuery(Query):
         # (BASELINE north-star); f32 costs ~3x a bf16 matmul on a single
         # query — noise next to dispatch. Batched throughput paths keep
         # bf16 + exact_rescore_topk instead (parallel/executor.py).
-        vals, idx = knn_topk_auto(q, vc.vecs, lv, k=kc, metric=vc.similarity,
-                                  precise=True)
+        vals, idx = knn_topk_auto(q, vc.vecs, vc.row_terms(), lv, k=kc,
+                                  metric=vc.similarity, precise=True)
         kernels.record("knn_fused_topk")
         valid = vals[0] > -jnp.inf
         scores = jnp.zeros(ctx.D, jnp.float32).at[idx[0]].max(

@@ -6,7 +6,7 @@ is dispatched by knn_topk_auto when running on a real chip.
 import numpy as np
 import pytest
 
-from elasticsearch_tpu.ops.knn import knn_topk
+from elasticsearch_tpu.ops.knn import knn_row_terms, knn_topk_stored
 from elasticsearch_tpu.ops.pallas_kernels import knn_topk_auto, knn_topk_pallas
 
 
@@ -58,7 +58,8 @@ def test_pallas_matches_xla_path():
     v = jnp.asarray(rng.normal(size=(D, dims)).astype(np.float32))
     m = jnp.asarray(np.ones(D, dtype=bool))
     pv, _ = knn_topk_pallas(q, v, m, k=k, metric="cosine", interpret=True)
-    xv, _ = knn_topk(q, v, m, k=k, metric="cosine")
+    xv, _ = knn_topk_stored(q, v, knn_row_terms(v, metric="cosine"), m, k=k,
+                            metric="cosine")
     np.testing.assert_allclose(np.asarray(pv), np.asarray(xv), atol=5e-3)
 
 
@@ -69,7 +70,8 @@ def test_auto_dispatch_falls_back_on_cpu():
     q = jnp.asarray(rng.normal(size=(2, 16)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(100, 16)).astype(np.float32))  # not tile-aligned
     m = jnp.asarray(np.ones(100, dtype=bool))
-    vals, idx = knn_topk_auto(q, v, m, k=3)
+    vals, idx = knn_topk_auto(q, v, knn_row_terms(v, metric="cosine"), m,
+                              k=3)
     assert vals.shape == (2, 3) and idx.shape == (2, 3)
 
 

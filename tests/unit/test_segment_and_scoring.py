@@ -187,7 +187,8 @@ def test_keyword_and_numeric_columns():
 
 
 def test_knn_ops_match_numpy():
-    from elasticsearch_tpu.ops.knn import knn_topk, knn_topk_chunked
+    from elasticsearch_tpu.ops.knn import (knn_row_terms, knn_topk_chunked,
+                                           knn_topk_stored)
 
     rng = np.random.default_rng(0)
     D, dims, Q, k = 256, 32, 4, 5
@@ -195,27 +196,33 @@ def test_knn_ops_match_numpy():
     queries = rng.standard_normal((Q, dims)).astype(np.float32)
     mask = np.ones(D, dtype=bool)
 
-    vals, idx = knn_topk(queries, vecs, mask, k=k, metric="cosine", use_bf16=False)
+    terms = knn_row_terms(vecs, metric="cosine")
+    vals, idx = knn_topk_stored(queries, vecs, terms, mask, k=k,
+                                metric="cosine", use_bf16=False)
     qn = queries / np.linalg.norm(queries, axis=1, keepdims=True)
     vn = vecs / np.linalg.norm(vecs, axis=1, keepdims=True)
     sim = (1 + qn @ vn.T) / 2
     want_idx = np.argsort(-sim, axis=1)[:, :k]
     assert (np.asarray(idx) == want_idx).mean() > 0.95  # ties may reorder
 
-    cvals, cidx = knn_topk_chunked(queries, vecs, mask, k=k, metric="cosine", chunk=64, use_bf16=False)
+    cvals, cidx = knn_topk_chunked(queries, vecs, terms, mask, k=k,
+                                   metric="cosine", chunk=64, use_bf16=False)
     np.testing.assert_allclose(np.sort(np.asarray(cvals)), np.sort(np.asarray(vals)), rtol=1e-5)
 
 
 def test_knn_l2_and_dot():
-    from elasticsearch_tpu.ops.knn import knn_scores
+    from elasticsearch_tpu.ops.knn import knn_row_terms, knn_scores
 
     rng = np.random.default_rng(1)
     vecs = rng.standard_normal((16, 8)).astype(np.float32)
     q = rng.standard_normal((2, 8)).astype(np.float32)
-    s = np.asarray(knn_scores(q, vecs, metric="l2_norm", use_bf16=False))
+    s = np.asarray(knn_scores(q, vecs, knn_row_terms(vecs, metric="l2_norm"),
+                              metric="l2_norm", use_bf16=False))
     d2 = ((q[:, None, :] - vecs[None, :, :]) ** 2).sum(-1)
     np.testing.assert_allclose(s, 1 / (1 + d2), rtol=2e-3, atol=1e-4)
-    sd = np.asarray(knn_scores(q, vecs, metric="dot_product", use_bf16=False))
+    assert knn_row_terms(vecs, metric="dot_product") is None
+    sd = np.asarray(knn_scores(q, vecs, None, metric="dot_product",
+                               use_bf16=False))
     np.testing.assert_allclose(sd, (1 + q @ vecs.T) / 2, rtol=1e-4)
 
 

@@ -525,14 +525,21 @@ def test_shapeflow_sound_vs_eval_shape(monkeypatch, eight_devices):
 
         prog = exmod._knn_program(mesh, {}, Q=Q, dims=dims, D=D, k=k,
                                   metric="dot")
+        # (a dot_product slab has no stored row term: None in its place)
         out = jax.eval_shape(prog, S((Q, dims), f32), S((D, dims), f32),
-                             S((D,), b8))
+                             None, S((D,), b8))
+        assert [o.shape for o in out] == [(Q, k)] * 3
+
+        prog = exmod._knn_program(mesh, {}, Q=Q, dims=dims, D=D, k=k,
+                                  metric="l2_norm")
+        out = jax.eval_shape(prog, S((Q, dims), f32), S((D, dims), f32),
+                             S((D,), f32), S((D,), b8))
         assert [o.shape for o in out] == [(Q, k)] * 3
 
         prog = exmod._maxsim_program(mesh, {}, Q=Q, T=T, dims=dims, D=D,
                                      k=k, metric="dot")
         out = jax.eval_shape(prog, S((Q, T, dims), f32), S((D, dims), f32),
-                             S((D,), b8))
+                             None, S((D,), b8))
         assert [o.shape for o in out] == [(Q, k)] * 3
 
     prog = exmod._psum_program(mesh, {}, (4, 5))
