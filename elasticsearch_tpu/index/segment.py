@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import os
 import threading
+from collections.abc import Sequence
 from dataclasses import dataclass, field as dfield
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
@@ -401,6 +402,18 @@ for _pname in ("doc_ids", "tf", "tfnorm", "term_ids"):
 del _pname
 
 
+class Derived:
+    """A column's host array that is computed from another on its first
+    touch instead of at freeze (``numeric_column``: the f32 channel and
+    the (hi, lo) pair of a column whose search never reads them hold no
+    host memory)."""
+
+    __slots__ = ("fn",)
+
+    def __init__(self, fn):
+        self.fn = fn
+
+
 def _resident_field(name: str):
     """Attach a lazy EVICTABLE device accessor for one doc-value column
     array (the fielddata tier of resources/residency.py).
@@ -426,13 +439,15 @@ def _resident_field(name: str):
 
         if isinstance(v, ResidentArray):
             return v.get()
-        if isinstance(v, np.ndarray):
+        if isinstance(v, (np.ndarray, Derived)):
             # first-touch registration is locked (dict.setdefault is
             # atomic under the GIL): two concurrent searches must not
             # each charge the breaker and upload the same slab
             lock = self.__dict__.setdefault(raw_lock, threading.Lock())
             with lock:
                 v = self.__dict__.get(raw)
+                if isinstance(v, Derived):
+                    v = v.fn()
                 if isinstance(v, np.ndarray):
                     from elasticsearch_tpu import resources
 
@@ -466,7 +481,25 @@ class NumericColumn:
     # offset, with offset = segment min. Consumers add offset back (aggs) or
     # shift query bounds down (range masks); exact compares use (hi, lo).
     offset: float = 0.0
+    # exact int32 code (device, lazy like the rest; CODE_MISSING where the
+    # doc has no value) of the kinds whose values are integers in some
+    # unit: exact value = (code_base + code * code_step) / code_factor.
+    # None where the column has none (see column_code). The aggregation
+    # program (ops/aggs.py) keys, filters and reduces on it alone
+    code: Any = None
+    code_base: int = 0
+    code_step: int = 1
+    code_factor: int = 1
+    code_min: int = 0  # over the docs with a value (0, 0 where none)
+    code_max: int = 0
+    value_count: int = 0  # docs with a value
     device = None  # the owning segment's chip (TpuSegment sets it)
+
+    @property
+    def has_code(self) -> bool:
+        """True when the exact int32 code exists (presence check only,
+        like has_pair: never forces the lazy device load)."""
+        return self.__dict__.get("_code_res") is not None
 
     @property
     def has_pair(self) -> bool:
@@ -634,7 +667,7 @@ class VectorColumn:
 # _resident_field): freeze stores host arrays, the first search places
 # them, pressure evicts them, the next touch rehydrates
 _COLUMN_RESIDENT_FIELDS = (
-    (NumericColumn, ("values", "exists", "hi", "lo")),
+    (NumericColumn, ("values", "exists", "hi", "lo", "code")),
     (KeywordColumn, ("ords", "exists")),
     (VectorColumn, ("vecs", "exists")),
 )
@@ -658,6 +691,133 @@ def _column_resident(col, fields) -> Tuple[int, int, int]:
             ev += h.evictions
             rh += h.rehydrations
     return b, ev, rh
+
+
+# kinds whose exact host mirror is int64 (and whose device form adds the
+# exact (hi, lo) pair)
+EXACT_INT_KINDS = ("long", "date", "ip", "murmur3", "token_count", "integer")
+# the code of a doc with no value: below every code a value takes, so a
+# range test on the code drops it without reading ``exists``
+CODE_MISSING = -(2 ** 31)
+# |code| bound: the program's key arithmetic (code - origin) stays int32
+CODE_LIMIT = 2 ** 30
+
+
+def column_code(kind: str, exact: np.ndarray, exists: np.ndarray,
+                scaling_factor: float = 1.0):
+    """The exact int32 code of a column (CODE_MISSING where a doc has no
+    value), or None where its values are not integers in a unit whose span
+    fits ``CODE_LIMIT``: (code, base, step, factor, least code, greatest
+    code) with exact = (base + code * step) / factor.
+
+    Integer kinds: base = the least value, step 1000 for a date whose
+    every value is a whole second (and 1 else). ``scaled_float``: the
+    value times an integral scaling factor, as ES stores it (the caller
+    has already rounded ``exact`` to the scale). Floating kinds: None."""
+    if not exists.any():
+        return None
+    if kind in EXACT_INT_KINDS:
+        base = int(exact.min(where=exists, initial=np.iinfo(np.int64).max))
+        top = int(exact.max(where=exists, initial=np.iinfo(np.int64).min))
+        step = 1
+        if kind == "date" and base % 1000 == 0 and not np.any(
+                exact % 1000, where=exists):
+            step = 1000
+        if (top - base) // step >= CODE_LIMIT:
+            return None
+        code = np.full(exact.shape, CODE_MISSING, np.int32)
+        np.floor_divide(exact - base, step, out=code, where=exists,
+                        casting="unsafe")
+        return code, base, step, 1, 0, (top - base) // step
+    if kind == "scaled_float" and float(scaling_factor).is_integer() \
+            and scaling_factor >= 1:
+        factor = int(scaling_factor)
+        scaled = np.rint(exact * factor)
+        lo = scaled.min(where=exists, initial=np.inf)
+        hi = scaled.max(where=exists, initial=-np.inf)
+        if max(-lo, hi) >= CODE_LIMIT:
+            return None
+        code = np.full(exact.shape, CODE_MISSING, np.int32)
+        np.copyto(code, scaled, where=exists, casting="unsafe")
+        return code, 0, 1, factor, int(lo), int(hi)
+    return None
+
+
+def numeric_column(name: str, kind: str, exact: np.ndarray,
+                   exists: np.ndarray, scaling_factor: float = 1.0,
+                   device: Any = None) -> NumericColumn:
+    """The one codec of a numeric or date column: exact values (int64 for
+    ``EXACT_INT_KINDS``, float64 else) and an exists mask, both
+    [max_docs], to a NumericColumn of host arrays whose device copies load
+    lazily onto ``device`` (the owning segment's chip; TpuSegment sets it
+    again). ``SegmentBuilder`` calls it on what it collected document by
+    document; a loader that has the arrays calls it directly."""
+    exists = np.asarray(exists, dtype=bool)
+    needs_exact = kind in EXACT_INT_KINDS
+    exact = np.asarray(exact, dtype=np.int64 if needs_exact else np.float64)
+    if kind == "scaled_float" and float(scaling_factor).is_integer() \
+            and scaling_factor >= 1:
+        # ES keeps round(value * factor): doc values read back that / factor
+        exact = np.rint(exact * scaling_factor) / scaling_factor
+    offset = 0.0
+    if needs_exact and exists.any():
+        offset = float(exact.min(where=exists, initial=np.iinfo(np.int64).max))
+
+    def values():
+        return np.where(exists, (exact - offset).astype(np.float32),
+                        np.float32(0))
+
+    # host arrays, or Derived: the device copies load lazily into the
+    # evictable fielddata tier on first touch (_resident_field)
+    col = NumericColumn(name=name, values=Derived(values), exists=exists,
+                        exact=exact, exists_host=exists, kind=kind,
+                        offset=offset)
+    col.device = device
+    col.value_count = int(np.count_nonzero(exists))
+    if needs_exact:
+        col.hi = Derived(lambda: split_i64(exact)[0])
+        col.lo = Derived(lambda: split_i64(exact)[1])
+    coded = column_code(kind, exact, exists, scaling_factor)
+    if coded is not None:
+        (col.code, col.code_base, col.code_step, col.code_factor,
+         col.code_min, col.code_max) = coded
+    return col
+
+
+class RangeIds(Sequence):
+    """The ``_id`` of every document of a segment whose ids are
+    ``start``, ``start + 1``, ... as strings: one int, no Python object a
+    document (a loader's segment of many millions)."""
+
+    def __init__(self, start: int, n: int):
+        self.start, self.n = int(start), int(n)
+
+    def __len__(self) -> int:
+        return self.n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[j] for j in range(*i.indices(self.n))]
+        if not -self.n <= i < self.n:
+            raise IndexError(i)
+        return str(self.start + (i % self.n))
+
+
+class Uniform(Sequence):
+    """One value for every document (no ``_source``, no stored fields)."""
+
+    def __init__(self, value, n: int):
+        self.value, self.n = value, int(n)
+
+    def __len__(self) -> int:
+        return self.n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self.value] * len(range(*i.indices(self.n)))
+        if not -self.n <= i < self.n:
+            raise IndexError(i)
+        return self.value
 
 
 class TpuSegment:
@@ -760,6 +920,28 @@ class TpuSegment:
         return self._live_dev
 
     @property
+    def live_i8(self):
+        """The live mask as int8 on the segment's chip, for kernels that
+        cannot read a bool array (ops/aggs.py): an evictable fielddata
+        array like a doc-value column (_resident_field), placed under one
+        lock on first use and again after a delete (keyed on
+        ``deleted_count``; the replaced handle releases its charge when
+        it is collected)."""
+        lock = self.__dict__.setdefault("_live_i8_lock", threading.Lock())
+        with lock:
+            seen = self.deleted_count
+            if self.__dict__.get("_live_i8_at") != seen:
+                from elasticsearch_tpu import resources
+
+                self._live_i8 = resources.RESIDENCY.put_array(
+                    self._live_host.astype(np.int8),
+                    label=f"segment:{self.seg_id}.live_i8",
+                    tier="fielddata", device=self.device)
+                self._live_i8_at = seen
+            handle = self._live_i8
+        return handle.get()
+
+    @property
     def live_host(self) -> np.ndarray:
         return self._live_host
 
@@ -787,7 +969,7 @@ class TpuSegment:
     def _column_iter(self):
         """(column, resident-field names) for every doc-value column."""
         for col in self.numerics.values():
-            yield col, ("values", "exists", "hi", "lo")
+            yield col, ("values", "exists", "hi", "lo", "code")
         for col in self.keywords.values():
             yield col, ("ords", "exists")
         for col in self.vectors.values():
@@ -1195,29 +1377,14 @@ class SegmentBuilder:
 
     def _build_numeric(self, fname: str, kind: str, n: int, max_docs: int) -> NumericColumn:
         exists = np.zeros(max_docs, dtype=bool)
-        needs_exact = kind in ("long", "date", "ip", "murmur3", "token_count", "integer")
-        exact = np.zeros(max_docs, dtype=np.int64) if needs_exact else np.zeros(max_docs, dtype=np.float64)
+        exact = np.zeros(max_docs, dtype=np.int64 if kind in EXACT_INT_KINDS
+                         else np.float64)
         for i, d in enumerate(self.docs):
             vals = d.doc_values.get(fname)
             if not vals:
                 continue
             exists[i] = True
             exact[i] = vals[0]  # multi-valued numerics: first value in the column (full set in _source)
-        offset = 0.0
-        if needs_exact and exists.any():
-            offset = float(exact[exists].min())
-        values = np.where(exists, (exact - offset).astype(np.float32), np.float32(0))
-        col = NumericColumn(
-            name=fname,
-            values=values.astype(np.float32),  # host: lazy evictable
-            exists=exists,                     # device copies (fielddata)
-            exact=exact,
-            exists_host=exists,
-            kind=kind,
-            offset=offset,
-        )
-        if needs_exact:
-            hi, lo = split_i64(exact)
-            col.hi = hi
-            col.lo = lo
-        return col
+        fm = self.mappings.get(fname)
+        return numeric_column(fname, kind, exact, exists,
+                              scaling_factor=fm.scaling_factor if fm else 1.0)

@@ -25,6 +25,14 @@ Names:
                       tail costs the device
   tail_window_postings  real postings in those windows (Σ lens): what the
                       tail is for; postings / slots is the window's fill
+  agg_one_program     a host-loop search segment whose size-0
+                      aggregation tree ran as ONE agg_tree program
+                      (filter, keys, per-bucket metrics; ops/aggs.py)
+  agg_declined        a host-loop aggregated search segment the program
+                      did not serve (host collectors)
+  agg_declined_mesh   a search the mesh program served whose aggregation
+                      tree is out of the program's shape (its mask
+                      through the host collectors)
   bm25_postings_sharded  oversized field scored via the cross-device
                       postings split + psum merge (parallel/postings_shard)
   knn_fused_topk      fused scores+mask+topk (Pallas on TPU, XLA elsewhere);

@@ -1,0 +1,319 @@
+"""One device program for a ``size: 0`` aggregation tree over a segment.
+
+Reference: org/elasticsearch/search/aggregations/bucket/histogram/
+HistogramAggregator.java and metrics/stats/StatsAggregator.java collect
+one document at a time into per-bucket counters. Here the whole segment
+is one pass of one program over the exact int32 codes of the columns the
+request reads (``index/segment.column_code``: CODE_MISSING where a
+document has no value) and the segment's int8 live mask:
+
+- the filter: a conjunction of inclusive code ranges, ∧ live;
+- the bucket key, in int32 arithmetic from the key column's code:
+  ``floor((code - c0) / q)`` (the caller turns an interval and the first
+  bucket's edge into code units ``q`` and ``c0``), so a date never passes
+  through a float (one f32 ulp of epoch millis is 131 s);
+- per bucket: documents, and for each metric column the values present,
+  their sum, least and greatest code.
+
+No per-document scatter: every bucket is a compare over a tile held in
+VMEM (the TPU path is a Pallas kernel that reads each column once, block
+by block, and keeps ``[B, 8, 128]`` accumulators resident across the
+grid; the sums are f32 with Kahan compensation across blocks, so an
+average over 10^8 codes keeps ~1e-7). Elsewhere (the CPU, tiny segments)
+the same semantics are one XLA program over a ``[B, D]`` compare. Both
+return one packed int32 vector — the segment's match count, then ``[B]``
+per number — for the caller's one pull.
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+CODE_MISSING = -(2 ** 31)  # index/segment.CODE_MISSING
+_I32_MAX = 2 ** 31 - 1
+# the bucket counts the program is compiled for: a request pads its
+# bucket count up to the next class, so a dashboard's pool compiles in
+# warm-up and nothing in the window
+BUCKET_CLASSES = (8, 16, 24, 32, 40, 48, 56, 64, 96, 128)
+# rows of 128 documents a grid step reads, and a chunk of them the inner
+# loop folds into the accumulators at once
+_BLOCK_ROWS = 2048
+_CHUNK_ROWS = 32
+_VMEM_LIMIT_BYTES = 48 << 20
+
+
+def bucket_class(n: int):
+    """The compiled bucket count for ``n`` buckets, or None past the
+    largest class (the caller declines)."""
+    for b in BUCKET_CLASSES:
+        if n <= b:
+            return b
+    return None
+
+
+class Metric(NamedTuple):
+    col: int  # index into the program's code columns
+    count: bool  # values present (needed unless every doc has one)
+    sum: bool
+    min: bool
+    max: bool
+
+
+class TreeSpec(NamedTuple):
+    """The static half of a program (its shape class). ``params`` carries
+    the rest: each filter's (lo, hi) codes in order, then (c0, q)."""
+
+    n_cols: int
+    filters: Tuple[int, ...]  # code column of each range
+    key_col: int  # -1: one bucket (a bucket-less metric tree)
+    B: int
+    metrics: Tuple[Metric, ...]
+
+
+def unpack(spec: TreeSpec, words: np.ndarray):
+    """(total, doc_count[B], [ {"count","sum","min","max"}: [B] ... ])
+    from the pulled vector; sums come back as float64 of the f32 words."""
+    words = np.asarray(words)
+    B = spec.B
+    total = int(words[0])
+    at = 1
+
+    def take():
+        nonlocal at
+        at += B
+        return words[at - B:at]
+
+    counts = take().astype(np.int64)
+    metrics = []
+    for m in spec.metrics:
+        got = {}
+        if m.count:
+            got["count"] = take().astype(np.int64)
+        if m.sum:
+            got["sum"] = take().view(np.float32).astype(np.float64)
+        if m.min:
+            got["min"] = take().astype(np.int64)
+        if m.max:
+            got["max"] = take().astype(np.int64)
+        metrics.append(got)
+    return total, counts, metrics
+
+
+def _floor_key(x, q, inv_q):
+    """floor(x / q) for int32 x, q >= 1: an f32 estimate, then one exact
+    integer correction each way (the estimate is off by at most one for
+    the keys that count, which lie in [0, 128))."""
+    k = jnp.floor(x.astype(jnp.float32) * inv_q).astype(jnp.int32)
+    r = x - k * q
+    return jnp.where(r < 0, k - 1, jnp.where(r >= q, k + 1, k))
+
+
+# --------------------------------------------------------------------------
+# the XLA program (CPU, tiny segments): the semantics, plainly
+# --------------------------------------------------------------------------
+
+def _xla_tree(params, live, cols, *, spec: TreeSpec):
+    sel = live != 0
+    for i, c in enumerate(spec.filters):
+        x = cols[c]
+        sel = sel & (x >= params[2 * i]) & (x <= params[2 * i + 1])
+    total = jnp.sum(sel.astype(jnp.int32))
+    if spec.key_col < 0:
+        key = jnp.where(sel, 0, -1)
+    else:
+        p = 2 * len(spec.filters)
+        kc = cols[spec.key_col]
+        c0, q = params[p], params[p + 1]
+        key = _floor_key(kc - c0, q, 1.0 / q.astype(jnp.float32))
+        key = jnp.where(sel & (kc != CODE_MISSING), key, -1)
+    onehot = key[None, :] == jnp.arange(spec.B, dtype=jnp.int32)[:, None]
+    out = [total[None], jnp.sum(onehot.astype(jnp.int32), axis=1)]
+    for m in spec.metrics:
+        v = cols[m.col]
+        has = onehot & (v != CODE_MISSING)[None, :]
+        if m.count:
+            out.append(jnp.sum(has.astype(jnp.int32), axis=1))
+        if m.sum:
+            s = jnp.sum(jnp.where(has, v.astype(jnp.float32)[None, :], 0.0),
+                        axis=1)
+            out.append(jax.lax.bitcast_convert_type(s, jnp.int32))
+        if m.min:
+            out.append(jnp.min(jnp.where(has, v[None, :], _I32_MAX), axis=1))
+        if m.max:
+            out.append(jnp.max(jnp.where(has, v[None, :], CODE_MISSING),
+                               axis=1))
+    return jnp.concatenate(out)
+
+
+# --------------------------------------------------------------------------
+# the Pallas kernel (TPU)
+# --------------------------------------------------------------------------
+
+def _acc_layout(spec: TreeSpec):
+    """Accumulator outputs of the kernel, in order: (name, metric index,
+    dtype, init)."""
+    rows = [("total", -1, jnp.int32, 0), ("count", -1, jnp.int32, 0)]
+    for j, m in enumerate(spec.metrics):
+        if m.count:
+            rows.append(("mcount", j, jnp.int32, 0))
+        if m.sum:
+            rows.append(("sum", j, jnp.float32, 0.0))
+            rows.append(("comp", j, jnp.float32, 0.0))
+        if m.min:
+            rows.append(("min", j, jnp.int32, _I32_MAX))
+        if m.max:
+            rows.append(("max", j, jnp.int32, CODE_MISSING))
+    return rows
+
+
+def _kernel(*, spec: TreeSpec, TR: int, CH: int):
+    from jax.experimental import pallas as pl
+
+    layout = _acc_layout(spec)
+    nf = len(spec.filters)
+
+    def fold(x, op):
+        # [CH, 128] -> [8, 128]: vreg-wise, no cross-lane work
+        return op(x.reshape(CH // 8, 8, 128), axis=0)
+
+    def kernel(params_ref, *refs):
+        cols = refs[:spec.n_cols]
+        live = refs[spec.n_cols]
+        accs = refs[spec.n_cols + 1:]
+        by = {}
+        for (name, j, _dt, _init), ref in zip(layout, accs):
+            by[(name, j)] = ref
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            for (name, j, dt, init), ref in zip(layout, accs):
+                ref[...] = jnp.full(ref.shape, init, dt)
+
+        lo = [params_ref[2 * i] for i in range(nf)]
+        hi = [params_ref[2 * i + 1] for i in range(nf)]
+        if spec.key_col >= 0:
+            c0, q = params_ref[2 * nf], params_ref[2 * nf + 1]
+            inv_q = 1.0 / q.astype(jnp.float32)
+
+        def chunk(r, carry):
+            rows = pl.ds(pl.multiple_of(r * CH, CH), CH)
+            sel = live[rows, :].astype(jnp.int32) != 0
+            for i, c in enumerate(spec.filters):
+                x = cols[c][rows, :]
+                sel = sel & (x >= lo[i]) & (x <= hi[i])
+            t = by[("total", -1)]
+            t[...] += fold(sel.astype(jnp.int32), jnp.sum)
+            if spec.key_col < 0:
+                key = jnp.where(sel, 0, -1)
+            else:
+                kc = cols[spec.key_col][rows, :]
+                key = _floor_key(kc - c0, q, inv_q)
+                key = jnp.where(sel & (kc != CODE_MISSING), key, -1)
+            vals = []
+            for m in spec.metrics:
+                v = cols[m.col][rows, :]
+                has = v != CODE_MISSING
+                vals.append((has.astype(jnp.int32),
+                             jnp.where(has, v, 0).astype(jnp.float32),
+                             jnp.where(has, v, _I32_MAX),
+                             v))  # CODE_MISSING is already the least
+            cnt = by[("count", -1)]
+            for b in range(spec.B):
+                hit = key == b
+                cnt[b] += fold(hit.astype(jnp.int32), jnp.sum)
+                for j, m in enumerate(spec.metrics):
+                    hasi, vf, vmin, vmax = vals[j]
+                    if m.count:
+                        ref = by[("mcount", j)]
+                        ref[b] += fold(jnp.where(hit, hasi, 0), jnp.sum)
+                    if m.sum:
+                        s_ref, c_ref = by[("sum", j)], by[("comp", j)]
+                        y = fold(jnp.where(hit, vf, 0.0), jnp.sum) - c_ref[b]
+                        acc = s_ref[b]
+                        t2 = acc + y
+                        c_ref[b] = (t2 - acc) - y
+                        s_ref[b] = t2
+                    if m.min:
+                        ref = by[("min", j)]
+                        ref[b] = jnp.minimum(
+                            ref[b], fold(jnp.where(hit, vmin, _I32_MAX),
+                                         jnp.min))
+                    if m.max:
+                        ref = by[("max", j)]
+                        ref[b] = jnp.maximum(
+                            ref[b], fold(jnp.where(hit, vmax, CODE_MISSING),
+                                         jnp.max))
+            return carry
+
+        jax.lax.fori_loop(0, TR // CH, chunk, 0)
+
+    return kernel, layout
+
+
+def _pallas_tree(params, live, cols, *, spec: TreeSpec, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    D = live.shape[0]
+    R = D // 128
+    TR = min(_BLOCK_ROWS, R)
+    CH = min(_CHUNK_ROWS, TR)
+    kernel, layout = _kernel(spec=spec, TR=TR, CH=CH)
+    block = pl.BlockSpec((TR, 128), lambda i, p: (i, 0))
+    shapes, out_specs = [], []
+    for name, _j, dt, _init in layout:
+        if name == "total":
+            shapes.append(jax.ShapeDtypeStruct((8, 128), dt))
+            out_specs.append(pl.BlockSpec((8, 128), lambda i, p: (0, 0)))
+        else:
+            shapes.append(jax.ShapeDtypeStruct((spec.B, 8, 128), dt))
+            out_specs.append(pl.BlockSpec((spec.B, 8, 128),
+                                          lambda i, p: (0, 0, 0)))
+    accs = pl.pallas_call(
+        kernel,
+        out_shape=shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R // TR,),
+            in_specs=[block] * (spec.n_cols + 1), out_specs=out_specs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="agg_tree",
+    )(params, *(c.reshape(R, 128) for c in cols), live.reshape(R, 128))
+    by = {(name, j): a for (name, j, _dt, _i), a in zip(layout, accs)}
+    out = [jnp.sum(by[("total", -1)])[None],
+           jnp.sum(by[("count", -1)], axis=(1, 2))]
+    for j, m in enumerate(spec.metrics):
+        if m.count:
+            out.append(jnp.sum(by[("mcount", j)], axis=(1, 2)))
+        if m.sum:
+            s = jnp.sum(by[("sum", j)] - by[("comp", j)], axis=(1, 2))
+            out.append(jax.lax.bitcast_convert_type(s, jnp.int32))
+        if m.min:
+            out.append(jnp.min(by[("min", j)], axis=(1, 2)))
+        if m.max:
+            out.append(jnp.max(by[("max", j)], axis=(1, 2)))
+    return jnp.concatenate(out)
+
+
+def use_kernel(D: int) -> bool:
+    """The Pallas kernel on a TPU for a segment of at least one block of
+    32 rows (the int8 live mask's tile); the XLA program elsewhere."""
+    return jax.default_backend() == "tpu" and D >= 32 * 128
+
+
+@partial(jax.jit, static_argnames=("spec", "kernel", "interpret"))
+def agg_tree(params, live, *cols, spec: TreeSpec, kernel: bool = False,
+             interpret: bool = False):
+    """The whole tree over one segment: ``params`` int32[2F + 2] (filter
+    code bounds, then c0 and q), ``live`` int8[D], ``cols`` int32[D]
+    codes. Returns the packed int32 vector ``unpack`` reads."""
+    if kernel:
+        return _pallas_tree(params, live, cols, spec=spec,
+                            interpret=interpret)
+    return _xla_tree(params, live, cols, spec=spec)

@@ -24,8 +24,13 @@ fuzzy, bool, constant_score, filtered, dis_max, boosting, knn (brute
 force), function_score (weight / field_value_factor / decay / random,
 score_mode+boost_mode algebra). Sorting: numeric or keyword primary key
 (global-ordinal preselect), multi-key via host full-tuple ordering.
-Aggregations: terms-without-subs reduce fully on device; every other agg
-tree consumes the program's match mask through the host collectors.
+Aggregations: terms-without-subs reduce fully on device. A size-0 tree
+that search/aggregations/program.py serves on every segment (histogram
+or fixed-interval date_histogram with metric subs, or metrics alone,
+under a conjunction of ranges over coded columns) is declined here on
+purpose: the host loop serves it as one ``agg_tree`` program a segment
+with no [D] mask pulled to the host. Every other agg tree consumes this
+program's match mask through the host collectors.
 Still host-loop-only: spans, joins, geo, scripts, IVF knn, more_like_this,
 query_string, fuzzy-match expansion.
 """

@@ -169,6 +169,13 @@ def _try_mesh_search(svc, searchers, body: dict, global_stats=None) -> Optional[
         want_mask = bool(aggs) and not device_aggs
         sort_spec = _parse_sort(body.get("sort"))
         query = parse_query(body.get("query"))
+        if want_mask and size == 0:
+            from elasticsearch_tpu.search.aggregations import program
+
+            if program.in_scope(query, aggs):
+                # the host loop serves this tree as one agg_tree program a
+                # segment, with no [D] mask pulled to the host
+                return _BY_DESIGN
     t0 = time.perf_counter()
     executor = svc.mesh_executor()
     if executor is None:
@@ -299,7 +306,13 @@ def _try_mesh_search(svc, searchers, body: dict, global_stats=None) -> Optional[
         # sum (byte-identical responses on either path)
         partial_lists = _psum_merge_partials(
             executor, aggs, partial_lists, partial_shards)
-        response["aggregations"] = reduce_aggs(aggs, partial_lists)
+        with span("search.aggs"):
+            response["aggregations"] = reduce_aggs(aggs, partial_lists)
+        if not device_aggs:
+            from elasticsearch_tpu.monitor import kernels
+
+            # a tree out of the agg_tree program's shape, served here
+            kernels.record("agg_declined_mesh")
     return response
 
 

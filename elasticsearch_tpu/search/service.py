@@ -122,8 +122,8 @@ class ShardSearcher:
                **tags):
             """The tracer span of a boundary and, under ?profile=true,
             the same duration filed under the profile's phase: one set
-            of clocks. A phase with no span (aggs, rerank) keeps the
-            timer's own."""
+            of clocks. A phase with no span (the host collectors of aggs,
+            rerank) keeps the timer's own."""
             if span_name is None:
                 return prof.phase(phase) if prof is not None \
                     else nullcontext()
@@ -204,6 +204,12 @@ class ShardSearcher:
         fused_ok = (plain and not aggs and min_score is None
                     and search_after is None and not rescore_specs
                     and not collect_full)
+        # a size-0 aggregation tree the program covers: filter, keys and
+        # per-bucket metrics as ONE program a segment (ops/aggs.py)
+        agg_tree_ok = (bool(aggs) and size == 0 and plain
+                       and min_score is None and search_after is None
+                       and not rescore_specs and terminate_after is None
+                       and not body.get("post_filter"))
         from elasticsearch_tpu.search.hybrid import HybridQuery
         # attach the profile timer for the duration of segment execution
         # so fielddata rehydrations (resources/residency.py) file under
@@ -228,6 +234,17 @@ class ShardSearcher:
                 if prof is not None:
                     prof.segments += 1
                 kk = min(k, seg.max_docs)
+                if aggs:
+                    served = (_agg_program(ctx, query, aggs, _p, _dev)
+                              if agg_tree_ok and not seg.has_nested
+                              else None)
+                    if served is not None:
+                        total += served[0]
+                        agg_partials.append(served[1])
+                        continue
+                    from elasticsearch_tpu.monitor import kernels
+
+                    kernels.record("agg_declined")
                 if fused_ok and not seg.has_nested \
                         and isinstance(query, HybridQuery):
                     # hybrid stage 1: BOTH engines + fusion + top-k as ONE
@@ -622,6 +639,29 @@ class ShardSearcher:
 # coordinating search across shards (single node)
 # ---------------------------------------------------------------------------
 
+def _agg_program(ctx, query, aggs, _p, _dev):
+    """(matching documents, agg partials) of one segment from ONE
+    ``agg_tree`` program and one pull, or None where the request or the
+    segment is out of the program's scope (search/aggregations/
+    program.py)."""
+    from elasticsearch_tpu.monitor import kernels
+    from elasticsearch_tpu.search.aggregations import program
+    from elasticsearch_tpu.tracing.tracer import tag_active
+
+    with _p("search.plan", "executor_build"):
+        plan = program.plan(ctx, query, aggs)
+    if plan is None:
+        return None
+    kernels.record("agg_one_program")
+    with _p("device.dispatch", program="agg_tree"):
+        words_dev = _dev(lambda: program.dispatch(ctx, plan), "aggs")
+    with _p("device.wait", "host_sync"):
+        words = np.asarray(words_dev)
+        tag_active(bytes=words.nbytes)
+    with _p("search.aggs", "aggs"):
+        return program.partials(ctx, plan, words)
+
+
 def search_shards(
     searchers: List[ShardSearcher],
     body: dict,
@@ -845,7 +885,10 @@ def search_shards(
     if aggs_present:
         aggs = aggs_present[0]["_aggs"]
         partial_lists = [p for r in aggs_present for p in r["_list"]]
-        response["aggregations"] = reduce_aggs(aggs, partial_lists)
+        from elasticsearch_tpu.tracing.tracer import span
+
+        with span("search.aggs"):
+            response["aggregations"] = reduce_aggs(aggs, partial_lists)
     if profile:
         response["profile"] = {"shards": shard_profiles}
     if scroll:
