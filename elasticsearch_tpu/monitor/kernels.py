@@ -28,6 +28,11 @@ Names:
   agg_one_program     a host-loop search segment whose size-0
                       aggregation tree ran as ONE agg_tree program
                       (filter, keys, per-bucket metrics; ops/aggs.py)
+  agg_bucket_slots    slots those programs scanned times the bucket passes
+                      they made over them (the kernel: the blocks up to
+                      the segment's last used slot x its bucket class;
+                      the XLA program: D x its class): what the kernel's
+                      vector work scales with
   agg_declined        a host-loop aggregated search segment the program
                       did not serve (host collectors)
   agg_declined_mesh   a search the mesh program served whose aggregation

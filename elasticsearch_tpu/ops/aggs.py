@@ -23,6 +23,19 @@ average over 10^8 codes keeps ~1e-7). Elsewhere (the CPU, tiny segments)
 the same semantics are one XLA program over a ``[B, D]`` compare. Both
 return one packed int32 vector — the segment's match count, then ``[B]``
 per number — for the caller's one pull.
+
+The kernel's work is (slots scanned) x (bucket passes). Its grid stops
+at a run-time bound the caller passes with the parameters, so the
+compiled shape (``D``, the bucket class ``B``) never changes: the last
+block that holds one of the segment's used slots (``[0, maxDoc)``,
+deleted documents included; every slot past it is padding whose live
+byte is 0). Later steps fetch no new block (their index map repeats the
+last one) and do nothing, so no sum is reordered. The bucket loop runs
+all ``B`` passes of the class: a guard on each bucket, or a loop to the
+request's bucket count, costs more than the passes it skips (the
+compiler interleaves the buckets of one straight-line body, and a branch
+between them breaks that), and straight-line bodies for fewer passes
+multiply the kernel's compile time.
 """
 from __future__ import annotations
 
@@ -46,6 +59,18 @@ _CHUNK_ROWS = 32
 _VMEM_LIMIT_BYTES = 48 << 20
 
 
+def block_slots(D: int) -> int:
+    """Slots one grid step of the kernel reads over a ``D``-slot
+    segment."""
+    return min(_BLOCK_ROWS * 128, D)
+
+
+def last_block(D: int, used: int) -> int:
+    """The kernel's last grid block that holds one of a segment's
+    ``used`` slots ``[0, used)`` (block 0 when there are none)."""
+    return max(used - 1, 0) // block_slots(D)
+
+
 def bucket_class(n: int):
     """The compiled bucket count for ``n`` buckets, or None past the
     largest class (the caller declines)."""
@@ -65,7 +90,9 @@ class Metric(NamedTuple):
 
 class TreeSpec(NamedTuple):
     """The static half of a program (its shape class). ``params`` carries
-    the rest: each filter's (lo, hi) codes in order, then (c0, q)."""
+    the rest: each filter's (lo, hi) codes in order, then (c0, q), then
+    the kernel's last grid block that holds a used slot
+    (``last_block``)."""
 
     n_cols: int
     filters: Tuple[int, ...]  # code column of each range
@@ -198,6 +225,7 @@ def _kernel(*, spec: TreeSpec, TR: int, CH: int):
         if spec.key_col >= 0:
             c0, q = params_ref[2 * nf], params_ref[2 * nf + 1]
             inv_q = 1.0 / q.astype(jnp.float32)
+        last = params_ref[2 * nf + 2]
 
         def chunk(r, carry):
             rows = pl.ds(pl.multiple_of(r * CH, CH), CH)
@@ -249,7 +277,9 @@ def _kernel(*, spec: TreeSpec, TR: int, CH: int):
                                          jnp.max))
             return carry
 
-        jax.lax.fori_loop(0, TR // CH, chunk, 0)
+        @pl.when(pl.program_id(0) <= last)
+        def _scan():
+            jax.lax.fori_loop(0, TR // CH, chunk, 0)
 
     return kernel, layout
 
@@ -260,10 +290,12 @@ def _pallas_tree(params, live, cols, *, spec: TreeSpec, interpret=False):
 
     D = live.shape[0]
     R = D // 128
-    TR = min(_BLOCK_ROWS, R)
+    TR = block_slots(D) // 128
     CH = min(_CHUNK_ROWS, TR)
     kernel, layout = _kernel(spec=spec, TR=TR, CH=CH)
-    block = pl.BlockSpec((TR, 128), lambda i, p: (i, 0))
+    at = 2 * len(spec.filters) + 2  # params[at]: the last block to scan
+    # past it the index repeats, so Pallas fetches nothing new
+    block = pl.BlockSpec((TR, 128), lambda i, p: (jnp.minimum(i, p[at]), 0))
     shapes, out_specs = [], []
     for name, _j, dt, _init in layout:
         if name == "total":
@@ -310,9 +342,10 @@ def use_kernel(D: int) -> bool:
 @partial(jax.jit, static_argnames=("spec", "kernel", "interpret"))
 def agg_tree(params, live, *cols, spec: TreeSpec, kernel: bool = False,
              interpret: bool = False):
-    """The whole tree over one segment: ``params`` int32[2F + 2] (filter
-    code bounds, then c0 and q), ``live`` int8[D], ``cols`` int32[D]
-    codes. Returns the packed int32 vector ``unpack`` reads."""
+    """The whole tree over one segment: ``params`` int32[2F + 3] (filter
+    code bounds, then c0 and q, then the kernel's last block), ``live``
+    int8[D], ``cols`` int32[D] codes. Returns the packed int32 vector
+    ``unpack`` reads."""
     if kernel:
         return _pallas_tree(params, live, cols, spec=spec,
                             interpret=interpret)

@@ -32,7 +32,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from elasticsearch_tpu.index.segment import CODE_LIMIT
-from elasticsearch_tpu.ops.aggs import Metric, TreeSpec, bucket_class, unpack
+from elasticsearch_tpu.ops.aggs import (Metric, TreeSpec, bucket_class,
+                                        last_block, unpack)
 
 BUCKET_TYPES = {"histogram": frozenset({"field", "interval", "min_doc_count",
                                         "format"}),
@@ -52,7 +53,7 @@ _I32_MIN, _I32_MAX = -(2 ** 31), 2 ** 31 - 1
 
 class Plan(NamedTuple):
     spec: TreeSpec
-    params: np.ndarray  # int32[2F + 2]
+    params: np.ndarray  # int32[2F + 3]
     cols: tuple  # NumericColumn of each code column, in spec order
     bucket: Any  # the bucket aggregator, or None (one bucket)
     keys: Optional[List[float]]  # bucket key of each program bucket
@@ -253,7 +254,10 @@ def plan(ctx, query, aggs) -> Optional[Plan]:
         metrics.append(Metric(c, cnt and not full, s, mn, mx))
     spec = TreeSpec(n_cols=len(cols), filters=tuple(filters),
                     key_col=key_col, B=B, metrics=tuple(metrics))
-    params = np.asarray(params + [c0, q], np.int32)
+    # the kernel's grid stops at the block of the last used slot: maxDoc
+    # (num_docs), deleted documents included, never the live count
+    last = last_block(ctx.D, ctx.segment.num_docs)
+    params = np.asarray(params + [c0, q, last], np.int32)
     return Plan(spec, params, tuple(cols), bucket, keys,
                 [(a, order.index(c)) for a, c in owner])
 
@@ -269,16 +273,21 @@ def dispatch(ctx, p: Plan):
     """Enqueue the program; the device int32 vector (ops/aggs.unpack)."""
     import jax
 
-    from elasticsearch_tpu.ops.aggs import agg_tree, use_kernel
+    from elasticsearch_tpu.monitor import kernels
+    from elasticsearch_tpu.ops.aggs import agg_tree, block_slots, use_kernel
 
     seg = ctx.segment
+    kernel = use_kernel(ctx.D)
+    # slots the program scans times the bucket passes it makes over them
+    slots = (int(p.params[-1]) + 1) * block_slots(ctx.D) if kernel else ctx.D
+    kernels.record("agg_bucket_slots", slots * p.spec.B)
     # a few int32 scalars a search, uploaded with the call  # tpulint: offbudget
     params = jax.device_put(p.params, seg.device) if seg.device is not None \
         else p.params
     # spec.B is a BUCKET_CLASSES class, the columns D-long: the program's
     # own shape classes  # tpulint: bucketed
     return agg_tree(params, seg.live_i8, *(c.code for c in p.cols),
-                    spec=p.spec, kernel=use_kernel(ctx.D))
+                    spec=p.spec, kernel=kernel)
 
 
 def _value(col, code) -> float:

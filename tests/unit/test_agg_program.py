@@ -64,6 +64,18 @@ def _search(node, body):
     return out, kernels.snapshot()
 
 
+def _plan(node, body):
+    from elasticsearch_tpu.search.aggregations import parse_aggs, program
+    from elasticsearch_tpu.search.context import SegmentContext
+    from elasticsearch_tpu.search.queries import parse_query
+
+    svc = node.indices["t"]
+    ctx = SegmentContext(svc.shards[0].engine.segments[0], svc.mappings,
+                         svc.analysis)
+    return program.plan(ctx, parse_query(body["query"]),
+                        parse_aggs(body["aggs"]))
+
+
 def _day_body(d0, d1):
     return {"size": 0,
             "query": {"range": {"ts": {"gte": EPOCH + d0 * DAY,
@@ -140,19 +152,10 @@ def test_day_keys_are_exact_where_the_f32_channel_misbins(midnight):
 @pytest.mark.parametrize("lo,hi,B", [(0, 8, 8), (0, 9, 16), (3, 27, 24)])
 def test_mile_histogram_with_stats_at_a_bucket_class_edge(midnight, lo, hi,
                                                           B):
-    from elasticsearch_tpu.search.aggregations import parse_aggs, program
-    from elasticsearch_tpu.search.context import SegmentContext
-    from elasticsearch_tpu.search.queries import parse_query
-
     ms, dist, amt = midnight
     node = _node(ms, dist, amt)
     body = _mile_body(lo, hi)
-    svc = node.indices["t"]
-    seg = svc.shards[0].engine.segments[0]
-    ctx = SegmentContext(seg, svc.mappings, svc.analysis)
-    plan = program.plan(ctx, parse_query(body["query"]),
-                        parse_aggs(body["aggs"]))
-    assert plan.spec.B == B
+    assert _plan(node, body).spec.B == B
     got, k = _search(node, body)
     assert k.get("agg_one_program") == 1
     want = _ref_miles(dist, amt, lo, hi)
@@ -250,33 +253,174 @@ def test_metrics_alone_and_two_shards_on_the_mesh_default(midnight):
     assert a["lo"]["value"] == ms[sel].min()
 
 
-def test_the_kernel_and_the_xla_program_agree():
-    import jax.numpy as jnp
+# the kernel (interpret mode, blocks of 32 rows = 4,096 slots) against the
+# XLA program. ``used`` is the segment's used-slot count (maxDoc): past it
+# the live byte is 0, and the blocks past its last one hold live-looking
+# documents that match, which the kernel must not read. ``nb`` is the
+# request's real bucket count: the filter on the key keeps keys in [0, nb),
+# and the buckets past it keep their initial values
+KERNEL_CASES = {
+    # name: (D, used, tree, B, nb, deletes in the last used block)
+    "the_first_case": (8192, 8192, "stats", 16, 13, False),
+    "used_ends_mid_block_nb_1": (16384, 2 * 4096 + 1234, "stats", 16, 1,
+                                 False),
+    "used_ends_on_a_block_edge_nb_class_less_7": (16384, 3 * 4096, "stats",
+                                                  16, 9, False),
+    "used_ends_in_the_first_block_nb_class": (16384, 1000, "stats", 16, 16,
+                                              False),
+    "deletes_in_the_last_used_block": (16384, 2 * 4096 + 3000, "stats", 24,
+                                       17, True),
+    "count_only": (16384, 2 * 4096 + 77, "count", 16, 9, False),
+    "bucketless": (16384, 3 * 4096 - 5, "none", 8, 1, False),
+}
 
-    from elasticsearch_tpu.ops.aggs import Metric, TreeSpec, agg_tree
+
+def _kernel_case(D, used, tree, B, nb, deletes):
+    """(spec, params, live, cols) of a case, as a plan would give them,
+    and the live mask a segment holds (0 past ``used``)."""
+    from elasticsearch_tpu.ops.aggs import Metric, TreeSpec, last_block
 
     rng = np.random.default_rng(7)
-    D = 8192
     key = rng.integers(0, 5000, D).astype(np.int32)
     key[rng.random(D) < 0.05] = CODE_MISSING
     val = rng.integers(-300, 100000, D).astype(np.int32)
     val[rng.random(D) < 0.1] = CODE_MISSING
     live = (rng.random(D) < 0.95).astype(np.int8)
-    spec = TreeSpec(n_cols=2, filters=(0, 1), key_col=0, B=16,
-                    metrics=(Metric(1, True, True, True, True),))
-    params = jnp.asarray([100, 1399, -200, 90000, 100, 100], jnp.int32)
-    args = (params, jnp.asarray(live), jnp.asarray(key), jnp.asarray(val))
-    xla = np.asarray(agg_tree(*args, spec=spec))
-    pallas = np.asarray(agg_tree(*args, spec=spec, kernel=True,
-                                 interpret=True))
-    sums = slice(1 + 2 * 16, 1 + 3 * 16)
-    ints = np.ones(xla.shape, bool)
-    ints[sums] = False
-    assert (xla[ints] == pallas[ints]).all()
+    if deletes:
+        live[used - 700:used:3] = 0
+    live[used:] = 0
+    stats = (Metric(1, True, True, True, True),)
+    if tree == "none":
+        spec = TreeSpec(n_cols=2, filters=(0, 1), key_col=-1, B=B,
+                        metrics=stats)
+        params = [100, 1399, -200, 90000, 1, 1]
+    else:
+        # keys floor((code - 100) / 100) over codes [100, 100 nb + 99]
+        spec = TreeSpec(n_cols=2, filters=(0, 1), key_col=0, B=B,
+                        metrics=stats if tree == "stats" else ())
+        params = [100, 100 * nb + 99, -200, 90000, 100, 100]
+    params += [last_block(D, used)]
+    return spec, np.asarray(params, np.int32), live, (key, val)
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_kernel_and_the_xla_program_agree(case, monkeypatch):
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import aggs
+
+    monkeypatch.setattr(aggs, "_BLOCK_ROWS", 32)
+    D, used, tree, B, nb, deletes = KERNEL_CASES[case]
+    spec, params, live, (key, val) = _kernel_case(*KERNEL_CASES[case])
+    last = int(params[-1])
+    assert D // 4096 >= 2 and last == (max(used, 1) - 1) // 4096
+    xla = np.asarray(aggs.agg_tree(jnp.asarray(params), jnp.asarray(live),
+                                   jnp.asarray(key), jnp.asarray(val),
+                                   spec=spec))
+    # past the last used block: documents that are live and match
+    seen = live.copy()
+    seen[(last + 1) * 4096:] = 1
+    k2, v2 = key.copy(), val.copy()
+    k2[(last + 1) * 4096:] = 150
+    v2[(last + 1) * 4096:] = 7
+    pallas = np.asarray(aggs._pallas_tree(
+        jnp.asarray(params), jnp.asarray(seen),
+        (jnp.asarray(k2), jnp.asarray(v2)), spec=spec, interpret=True))
+    sums = np.zeros(xla.shape, bool)
+    at = 1 + B
+    for m in spec.metrics:
+        at += B * m.count
+        if m.sum:
+            sums[at:at + B] = True
+            at += B
+        at += B * (m.min + m.max)
+    assert at == xla.shape[0]
+    assert (xla[~sums] == pallas[~sums]).all()
     np.testing.assert_allclose(xla[sums].view(np.float32),
                                pallas[sums].view(np.float32), rtol=1e-6)
-    assert xla[0] == int(((live != 0) & (key >= 100) & (key <= 1399)
-                          & (val >= -200) & (val <= 90000)).sum())
+    hi = 100 * nb + 99 if tree != "none" else 1399
+    assert xla[0] == int(((live != 0) & (key >= 100) & (key <= hi)
+                          & (val >= -200) & (val <= 90000)).sum()) > 0
+    if tree != "none":
+        assert (xla[1:1 + nb] > 0).all() and not xla[1 + nb:1 + B].any()
+
+
+@pytest.fixture
+def trips3k():
+    """A node of 3,000 trips over 40 days (a segment of 4,096 slots: four
+    kernel blocks of 1,024 under ``_BLOCK_ROWS`` 8) whose last 1,100
+    documents are deleted: 1,900 live documents in 3,000 used slots."""
+    rng = np.random.default_rng(36)
+    n = 3000
+    ms = EPOCH + rng.integers(0, 40 * DAY // 1000, n) * 1000
+    dist = rng.integers(0, 3000, n)
+    amt = rng.integers(250, 20000, n)
+    node = _node(ms.astype(np.int64), dist, amt)
+    seg = node.indices["t"].shards[0].engine.segments[0]
+    for i in range(1900, n):
+        seg.delete_local(i)
+    assert (seg.max_docs, seg.num_docs, seg.live_docs) == (4096, n, 1900)
+    node.live_trips = (ms[:1900], dist[:1900], amt[:1900])
+    return node
+
+
+@pytest.mark.parametrize("body,nb", [
+    (_mile_body(0, 5), 5), (_mile_body(0, 20), 20), (_mile_body(0, 30), 30),
+    (_day_body(2, 3), 2), (_day_body(2, 9), 8), (_day_body(2, 33), 32)])
+def test_the_plan_bounds_the_kernels_grid(trips3k, monkeypatch, body, nb):
+    """The last block from the used slots (maxDoc), not the live count;
+    the class from the request's real bucket count ``kmax - kmin + 1``:
+    ``gte: 0, lt hi`` whole miles is hi buckets, n days from midnight to
+    midnight (``lte``) n + 1."""
+    from elasticsearch_tpu.ops import aggs
+
+    monkeypatch.setattr(aggs, "_BLOCK_ROWS", 8)
+    plan = _plan(trips3k, body)
+    last = int(plan.params[-1])
+    assert last == 2 != aggs.last_block(4096, 1900)
+    assert plan.spec.B == aggs.bucket_class(nb)
+    step = DAY if "days" in body["aggs"] else 1
+    assert plan.keys[nb - 1] == plan.keys[0] + (nb - 1) * step
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("body", [_mile_body(0, 20), _day_body(2, 18)])
+def test_agg_bucket_slots_rise_by_what_the_program_scans(trips3k,
+                                                         monkeypatch, kernel,
+                                                         body):
+    """``estpu_kernel_dispatch_total{kernel="agg_bucket_slots"}`` rises
+    by (slots scanned) x (bucket passes) a segment-search: D x B for the
+    XLA program, (last block + 1) x block x B for the kernel (run here in
+    interpret mode); the answer is the live documents' either way."""
+    from elasticsearch_tpu.ops import aggs
+
+    monkeypatch.setattr(aggs, "_BLOCK_ROWS", 8)
+    if kernel:
+        monkeypatch.setattr(aggs, "use_kernel", lambda D: True)
+        monkeypatch.setattr(
+            aggs, "agg_tree", lambda params, live, *cols, spec, kernel:
+            aggs._pallas_tree(params, live, cols, spec=spec, interpret=True))
+    plan = _plan(trips3k, body)
+    assert plan.spec.B == 24
+    got, k = _search(trips3k, body)
+    assert k.get("agg_one_program") == 1
+    want = (3 * 1024 if kernel else 4096) * 24
+    assert k.get("agg_bucket_slots") == want
+    ms, dist, amt = trips3k.live_trips
+    (name, agg), = got["aggregations"].items()
+    buckets = agg["buckets"]
+    if name == "days":
+        want = _ref_days(ms, 2, 18)
+        assert [(b["key"], b["doc_count"]) for b in buckets] == want
+        return
+    want = _ref_miles(dist, amt, 0, 20)
+    assert [b["key"] for b in buckets] == sorted(want)
+    for b in buckets:
+        n, total, lo, hi = want[b["key"]]
+        st = b["amt"]
+        assert b["doc_count"] == st["count"] == n
+        assert st["sum"] == pytest.approx(total, rel=1e-6)
+        assert (st["min"], st["max"]) == (pytest.approx(lo), pytest.approx(hi))
 
 
 KINDS = {"long": ("long", [5, -3, None, 2 ** 40]),
