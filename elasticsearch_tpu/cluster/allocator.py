@@ -509,7 +509,7 @@ class ClusterAllocator:
                            "source": source, "body": body,
                            "relocate": True}
         threading.Thread(target=self._run_relocation, args=(task,),
-                         name=f"tpu-relocate-{index}-{sid}",
+                         name=f"tpu-relocate[{index}][{sid}]",
                          daemon=True).start()
         return task
 

@@ -24,10 +24,25 @@ import time
 import zlib
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
+from elasticsearch_tpu.monitor.stats import THREAD_CPU
 from elasticsearch_tpu.tracing import adopt_wire_context, wire_context
 from elasticsearch_tpu.utils.errors import ElasticsearchTpuException
 from elasticsearch_tpu.utils.faults import FAULTS
 from elasticsearch_tpu.utils.wire import attach_ctx, extract_ctx
+
+# a search's phases for another node (cluster/search_action.py: query,
+# fetch, free_context) run inline on the connection's thread
+_SEARCH_ACTIONS = "indices:data/read/search["
+
+
+def handler_thread_name(action: str) -> str:
+    """The name a transport connection's thread takes once its frame's
+    action is known: the CPU account (monitor/stats.py) files a thread by
+    its name, so a search phase's CPU is a search's and every other
+    action's is the cluster's own background work."""
+    if action.startswith(_SEARCH_ACTIONS):
+        return "transport.search"
+    return "tpu-transport[connection]"
 
 
 class TransportError(ElasticsearchTpuException):
@@ -468,11 +483,14 @@ class TcpTransportServer:
 
         class _Handler(socketserver.BaseRequestHandler):
             def handle(self):  # noqa: N802 (socketserver API)
+                me = threading.current_thread()
+                me.name = "tpu-transport[connection]"
                 try:
                     req, rx_bytes = _recv_frame_sized(self.request)
                     _count_bytes(service.metrics, "rx", rx_bytes)
                     if req is None:
                         return
+                    me.name = handler_thread_name(req.get("action", ""))
                     try:
                         result = service.handle_frame(
                             req.get("action", ""), req.get("payload", {}),
@@ -493,6 +511,10 @@ class TcpTransportServer:
                             self.request, {"ok": False, "error": str(e)}))
                 except Exception:
                     pass  # broken pipe / malformed frame: drop the connection
+                finally:
+                    # one frame a connection: its thread ends here, so it
+                    # files its CPU now, not as `exited` at the next scrape
+                    THREAD_CPU.observe_current()
 
         self._srv = socketserver.ThreadingTCPServer((host, port), _Handler,
                                                     bind_and_activate=True)

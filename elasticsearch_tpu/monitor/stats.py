@@ -14,9 +14,12 @@ dashboards keep working.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+from elasticsearch_tpu.monitor.metrics import OVERFLOW_LABEL, SHARED
 
 
 class SearchStats:
@@ -285,3 +288,206 @@ def device_stats() -> dict:
         },
         "devices": rows,
     }
+
+
+# -- per-thread CPU account (estpu_thread_cpu_seconds_total) ----------------
+
+#: the closed label set of the family's ``group``: a metric file can only
+#: select exact label values, so the set never grows
+THREAD_GROUPS = ("request", "runtime", "background", "other")
+#: named ``(group, thread)`` series kept of the names the product does not
+#: give (a runtime thread's ``comm``, a foreign Python thread's name); a
+#: later one folds into ``{group, thread="_other_"}`` (the registry's
+#: overflow convention). The ``request`` and ``background`` names are a set
+#: the product's code fixes, and are never folded: on the four-chip host
+#: 645 threads carry 22 runtime names, and they would otherwise take the
+#: room before the first search starts the pools
+THREAD_SERIES_CAP = 32
+_NAMED_BY_CODE = ("request", "background")
+#: CPU that no live or remembered thread accounts for: what threads that
+#: ended burned after their last reading
+EXITED = ("other", "exited")
+
+# the threads a search runs on besides the pools' (rest/server.py names
+# the accept loop and its connection threads; cluster/transport.py a
+# connection's thread that runs a search phase for another node)
+_REQUEST = ("rest.server", "rest.connection", "transport.search",
+            "estpu-coalescer")
+# daemons the product starts off a search's path (watchdog, watcher,
+# warm-up, transport, allocator, recovery, fault detection)
+_BACKGROUND = ("estpu-watchdog", "estpu-warmup", "resource-watcher", "tpu-")
+_DIGITS = re.compile(r"\d+")
+
+
+def classify_thread(name: Optional[str], comm: str) -> Tuple[str, str]:
+    """``(group, thread)`` of one OS thread of this process: ``name`` is
+    the name of the Python thread that owns it (None where none does),
+    ``comm`` its ``/proc`` name. ``thread`` drops per-thread indexes, so a
+    pool's workers share one series."""
+    if name is None:  # the XLA / PjRt / TPU runtime, the profiler
+        return "runtime", _DIGITS.sub("", comm) or "?"
+    if name.startswith("tpu["):  # FixedThreadPool worker tpu[<pool>][i]
+        return "request", name.rpartition("[")[0]
+    if name in _REQUEST:
+        return "request", name
+    if name.startswith(_BACKGROUND):
+        return "background", _DIGITS.sub("", name.partition("[")[0])
+    return "other", _DIGITS.sub("", name)
+
+
+def thread_cpu_seconds(tid: int) -> Optional[float]:
+    """On-CPU seconds of thread ``tid`` of this process, None once it has
+    ended: the thread's own CPU clock (the kernel's ``sum_exec_runtime``,
+    brought up to date for a thread that is running; a syscall that keeps
+    the interpreter's lock)."""
+    try:
+        # glibc's encoding of a thread's CPU clock: ~tid << 3 | PERTHREAD
+        # | SCHED — what pthread_getcpuclockid returns for that thread
+        return time.clock_gettime(((~tid) << 3) | 6)
+    except OSError:
+        return None
+
+
+def task_ids() -> List[int]:
+    return [int(t) for t in os.listdir("/proc/self/task")]
+
+
+def thread_comm(tid: int) -> str:
+    try:
+        with open(f"/proc/self/task/{tid}/comm") as fh:
+            return fh.read().strip()
+    except OSError:
+        return ""
+
+
+def python_threads() -> Dict[int, threading.Thread]:
+    """{native thread id: the Python thread that owns it}; a foreign
+    thread's ``_DummyThread`` record owns nothing."""
+    return {t.native_id: t for t in threading.enumerate()
+            if t.native_id is not None
+            and not isinstance(t, threading._DummyThread)}
+
+
+class _Thread:
+    """One live OS thread's place in the account: its series, the reading
+    its series started from, its last reading, and its names."""
+
+    __slots__ = ("key", "base", "last", "name", "comm")
+
+    def __init__(self, key: Tuple[str, str], name: Optional[str], comm: str):
+        self.key = key
+        self.base = self.last = 0.0
+        self.name = name
+        self.comm = comm
+
+
+class ThreadCpuAccount:
+    """Every CPU-second of the process by ``(group, thread)``, read from
+    the kernel at scrape time (nothing is recorded per request).
+
+    Monotone and closed: a thread that ends, or whose series changes (a
+    rename), leaves its counted seconds in its old series; retired seconds
+    are kept by series, never by thread id, so memory stays bounded by the
+    series cap. What no live or remembered thread accounts for — threads
+    that ended after their last reading — is ``{group="other",
+    thread="exited"}``: the process clock, read after every thread, minus
+    the threads. So the family summed is the process CPU at each scrape."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._keys: set = set()
+        self._live: Dict[int, _Thread] = {}
+        self._retired: Dict[Tuple[str, str], float] = {}
+        self._exited = 0.0
+        # threads that filed themselves are dropped once they are gone at
+        # the next scrape, or here past this many (a server nobody scrapes
+        # still opens and closes connections)
+        self._prune_at = 64
+
+    def _series(self, name: Optional[str], comm: str) -> Tuple[str, str]:
+        key = classify_thread(name, comm)
+        if key[0] in _NAMED_BY_CODE or key in self._keys:
+            return key
+        if len(self._keys) < THREAD_SERIES_CAP:
+            self._keys.add(key)
+            return key
+        return key[0], OVERFLOW_LABEL
+
+    def _retire(self, t: _Thread) -> None:
+        self._retired[t.key] = self._retired.get(t.key, 0.0) \
+            + t.last - t.base
+
+    def _observe(self, tid: int, secs: float,
+                 owner: Optional[threading.Thread]) -> None:
+        name = owner.name if owner is not None else None
+        t = self._live.get(tid)
+        if t is not None and secs < t.last:  # the id went to a new thread
+            self._retire(t)
+            t = None
+        if t is None:
+            comm = thread_comm(tid) if name is None else ""
+            t = self._live[tid] = _Thread(self._series(name, comm), name,
+                                          comm)
+        elif name is not None and name != t.name:
+            # renamed, or its Python thread came up after the OS thread
+            # (one that is going down keeps its series to the end)
+            t.name = name
+            key = self._series(name, t.comm)
+            if key != t.key:
+                self._retire(t)
+                t.key, t.base = key, t.last
+        t.last = secs
+
+    def _retire_ended(self, alive: set) -> None:
+        for tid in [tid for tid in self._live if tid not in alive]:
+            self._retire(self._live.pop(tid))
+
+    def observe_current(self) -> None:
+        """File the calling thread's CPU now. A thread that ends between
+        two scrapes calls it last, so what it burned stays in its series
+        and not in ``exited`` (one reading a thread, never one a request)."""
+        with self._lock:
+            self._observe(threading.get_native_id(), time.thread_time(),
+                          threading.current_thread())
+            if len(self._live) > self._prune_at:
+                self._retire_ended(set(task_ids()))
+                self._prune_at = 2 * len(self._live) + 64
+
+    def collect(self) -> List[Tuple[Tuple[str, str], float]]:
+        """``[((group, thread), seconds), ...]`` as of now."""
+        with self._lock:
+            owners = python_threads()
+            me = threading.get_native_id()
+            seen = set()
+            for tid in task_ids():
+                if tid == me:
+                    continue
+                secs = thread_cpu_seconds(tid)
+                if secs is not None:  # None: it ended since the listing
+                    seen.add(tid)
+                    self._observe(tid, secs, owners.get(tid))
+            # the scraping thread last, the process clock after it: the
+            # process then holds every thread's reading
+            self._observe(me, time.thread_time(), owners.get(me))
+            seen.add(me)
+            process = time.process_time()
+            self._retire_ended(seen)
+            totals = dict(self._retired)
+            for t in self._live.values():
+                totals[t.key] = totals.get(t.key, 0.0) + t.last - t.base
+            # never falls: a thread read a few µs before the process clock
+            # can run on between the two readings of one scrape
+            self._exited = max(self._exited,
+                               process - sum(totals.values()))
+            totals[EXITED] = totals.get(EXITED, 0.0) + self._exited
+            return sorted(totals.items())
+
+
+#: the process's account (the process, like the device, is one for every
+#: node in it)
+THREAD_CPU = ThreadCpuAccount()
+SHARED.collector(
+    "estpu_thread_cpu_seconds_total",
+    "On-CPU seconds of the process's threads by group (request, runtime, "
+    "background, other) and thread; summed, the process CPU at the scrape",
+    ("group", "thread"), THREAD_CPU.collect, kind="counter")

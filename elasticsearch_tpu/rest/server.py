@@ -21,6 +21,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
+from elasticsearch_tpu.monitor.stats import THREAD_CPU
 from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.tracing import TaskCancelledException, span
 from elasticsearch_tpu.tracing.tracer import tag_active
@@ -4336,40 +4337,30 @@ def _cluster_reroute(n: Node, p, b):
     return 200, resp
 
 
-# stack tops that mean "parked, waiting for work" — the threads
-# ignore_idle_threads (default true) filters, the reference's known-idle
-# frame list (ThreadPool.Info idle states) translated to stdlib waits
-_IDLE_TOPS = {
-    ("threading.py", "wait"),
-    ("threading.py", "_wait_for_tstate_lock"),
-    ("queue.py", "get"),
-    ("selectors.py", "select"),
-    ("socketserver.py", "serve_forever"),
-    ("socketserver.py", "service_actions"),
-}
-
-
-def _stack_is_idle(stack: tuple) -> bool:
-    if not stack:
-        return True
-    fname, _line, func = stack[-1]
-    return (os.path.basename(fname), func) in _IDLE_TOPS
-
-
 def _hot_threads(n: Node, p, b):
-    """RestNodesHotThreadsAction with the reference's sampling semantics:
-    N snapshots taken ``?interval=`` apart (``?snapshots=``, default 10 ×
-    500ms), identical stacks collated per thread ("M/N snapshots sharing
-    following K elements"), busiest threads first, idle threads filtered
-    unless ``ignore_idle_threads=false``. Python exposes no per-thread
-    CPU clock, so "busy" is the fraction of snapshots in which the
-    thread sat in a non-idle frame — honest sampling, not fake
-    percentages."""
+    """RestNodesHotThreadsAction: ``?snapshots=`` snapshots (default 10)
+    taken ``?interval=`` apart (default 500ms), each of every thread's CPU
+    clock (``monitor/stats.py``, the readings behind
+    ``estpu_thread_cpu_seconds_total``) and its Python stack. Threads rank
+    by the CPU they burned from the first reading to the last, in the
+    reference's form ``X% (Y out of Z) cpu usage by thread '<name>'``, with
+    identical stacks collated ("M/N snapshots sharing following K
+    elements"); ``ignore_idle_threads`` (default true) drops threads that
+    burned none. A native thread (the XLA / TPU runtime: no Python thread
+    owns it) ranks beside them under its ``/proc`` name, with no stack.
+    ``type`` takes the reference's values (``cpu``, ``wait``, ``block``,
+    ``mem``); every one is answered with the CPU ranking, and the header
+    says so where another was asked: Python keeps no per-thread wait,
+    block or allocation time."""
     import sys
     import traceback
 
+    from elasticsearch_tpu.monitor import stats
     from elasticsearch_tpu.search.service import _parse_timeout
 
+    kind = str(p.get("type", "cpu")).lower()
+    if kind not in ("cpu", "wait", "block", "mem"):
+        raise IllegalArgumentException(f"type not supported [{kind}]")
     limit = int(p.get("threads", 3))
     snapshots = max(1, min(int(p.get("snapshots", 10)), 64))
     interval = _parse_timeout(p.get("interval", "500ms")) or 0.5
@@ -4379,47 +4370,61 @@ def _hot_threads(n: Node, p, b):
     ignore_idle = str(p.get("ignore_idle_threads", "true")).lower() \
         not in ("false", "0")
 
-    # per-thread: sample-count per distinct stack signature
+    # per OS thread: its name, the CPU seconds of its first and last
+    # reading, and the snapshot count of each distinct stack
+    names: Dict[int, str] = {}
+    first: Dict[int, float] = {}
+    last: Dict[int, float] = {}
     seen: Dict[int, Dict[tuple, int]] = {}
-    names: Dict[int, Any] = {}
-    busy: Dict[int, int] = {}
-    me = threading.get_ident()
-    for i in range(snapshots):
-        if i:
-            time.sleep(interval)
-        frames = sys._current_frames()
-        for t in threading.enumerate():
-            fr = frames.get(t.ident)
-            # skip the sampler itself: it is non-idle in every snapshot
-            # by construction and would permanently occupy one of the
-            # busiest-N output slots
-            if fr is None or t.ident == me:
-                continue
-            stack = tuple((f.filename, f.lineno, f.name)
-                          for f in traceback.extract_stack(fr))
-            names[t.ident] = t
-            seen.setdefault(t.ident, {})
-            seen[t.ident][stack] = seen[t.ident].get(stack, 0) + 1
-            if not _stack_is_idle(stack):
-                busy[t.ident] = busy.get(t.ident, 0) + 1
+    me = threading.get_native_id()
 
-    ranked = sorted(seen, key=lambda i: (-busy.get(i, 0),
-                                         names[i].name or ""))
+    def read(stacks: bool) -> None:
+        owners = stats.python_threads()
+        frames = sys._current_frames() if stacks else {}
+        for tid in stats.task_ids():
+            # the sampler itself is left out: what it burns is this report
+            secs = None if tid == me else stats.thread_cpu_seconds(tid)
+            if secs is None:
+                continue
+            t = owners.get(tid)
+            if tid not in names:
+                names[tid] = t.name if t is not None \
+                    else stats.thread_comm(tid) or f"tid {tid}"
+                # a thread born after the first reading burned all of it
+                first[tid] = secs if not stacks else 0.0
+            last[tid] = secs
+            fr = frames.get(t.ident) if t is not None else None
+            if fr is not None:
+                stack = tuple((f.filename, f.lineno, f.name)
+                              for f in traceback.extract_stack(fr))
+                per = seen.setdefault(tid, {})
+                per[stack] = per.get(stack, 0) + 1
+
+    read(stacks=False)
+    t0 = time.perf_counter()
+    for _ in range(snapshots):
+        time.sleep(interval)
+        read(stacks=True)
+    wall_ms = 1000.0 * (time.perf_counter() - t0)
+    burned = {tid: 1000.0 * (last[tid] - first[tid]) for tid in names}
+    ranked = sorted(names, key=lambda tid: (-burned[tid], names[tid]))
     if ignore_idle:
-        ranked = [i for i in ranked if busy.get(i, 0) > 0]
+        ranked = [tid for tid in ranked if burned[tid] > 0.0]
     out = [f"::: {{{n.name}}}{{{n.node_id}}}",
            f"   Hot threads sampling: interval={int(interval * 1000)}ms, "
            f"snapshots={snapshots}, busiestThreads={limit}, "
-           f"ignoreIdleThreads={str(ignore_idle).lower()}:"]
-    for ident in ranked[:limit]:
-        t = names[ident]
-        b_ct = busy.get(ident, 0)
-        pct = 100.0 * b_ct / snapshots
-        out.append(f"\n   {pct:.1f}% ({b_ct} out of {snapshots} snapshots "
-                   f"non-idle) usage by thread '{t.name}'")
+           f"ignoreIdleThreads={str(ignore_idle).lower()}, type=cpu"
+           + ("" if kind == "cpu" else
+              f" (asked for {kind}: only CPU time is kept a thread)") + ":"]
+    for tid in ranked[:limit]:
+        pct = 100.0 * burned[tid] / wall_ms if wall_ms > 0 else 0.0
+        out.append(f"\n   {pct:.1f}% ({burned[tid]:.1f}ms out of "
+                   f"{wall_ms:.0f}ms) cpu usage by thread '{names[tid]}'")
+        if tid not in seen:
+            out.append(f"     a native thread (tid {tid}): no Python stack")
         # collate identical stacks, most-sampled first (the reference's
         # "N/M snapshots sharing following K elements" lines)
-        for stack, ct in sorted(seen[ident].items(),
+        for stack, ct in sorted(seen.get(tid, {}).items(),
                                 key=lambda kv: -kv[1]):
             out.append(f"     {ct}/{snapshots} snapshots sharing "
                        f"following {len(stack)} elements")
@@ -5575,6 +5580,17 @@ class RestServer:
             request_queue_size = 128
             daemon_threads = True
 
+            def process_request_thread(self, request, client_address):
+                # once a connection, never a request: the thread is named
+                # for the CPU account (monitor/stats.py), and files its
+                # CPU as it ends — a connection that opens and closes
+                # between two scrapes would otherwise count as `exited`
+                threading.current_thread().name = "rest.connection"
+                try:
+                    super().process_request_thread(request, client_address)
+                finally:
+                    THREAD_CPU.observe_current()
+
         self.httpd = _Server((host, port), _Handler)
         self.host = host
         self.port = self.httpd.server_address[1]
@@ -5598,7 +5614,8 @@ class RestServer:
             except Exception:  # tpulint: allow[R006] — pre-warm must
                 pass           # never block a server from binding
         if background:
-            self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
+            self._thread = threading.Thread(target=self.httpd.serve_forever,
+                                            name="rest.server", daemon=True)
             self._thread.start()
         else:
             self.httpd.serve_forever()

@@ -80,8 +80,12 @@ def test_cluster_pending_tasks_and_reroute(server):
 
 
 def test_hot_threads(server):
-    st, body = _req(server, "GET", "/_nodes/hot_threads")
+    # the test's own thread waits on the reply and burns no CPU: it is
+    # listed only with the idle threads
+    st, body = _req(server, "GET", "/_nodes/hot_threads?threads=1000&"
+                    "snapshots=2&interval=10ms&ignore_idle_threads=false")
     assert st == 200 and ":::" in body and "MainThread" in body
+    assert "cpu usage by thread 'MainThread'" in body
 
 
 def test_global_count_field_stats_flush_optimize(server):

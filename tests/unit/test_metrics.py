@@ -512,17 +512,25 @@ class TestHotThreads:
             stop.set()
             t.join(timeout=2)
             n.close()
+        assert not t.is_alive()
         assert s == 200
         assert text.startswith(f"::: {{{n.name}}}")
-        assert "snapshots=4" in text
-        assert "busy-burner" in text
+        assert "snapshots=4" in text and "type=cpu" in text
         # collation lines: M/N snapshots sharing following K elements
         m = re.search(r"(\d+)/4 snapshots sharing following (\d+) elements",
                       text)
         assert m and 1 <= int(m.group(1)) <= 4
-        # the burner is 100% busy across samples
-        assert re.search(r"100\.0% \(4 out of 4 snapshots non-idle\) usage "
-                         r"by thread 'busy-burner'", text)
+        # the reference's form, ranked by CPU burned: the burner first
+        rows = re.findall(r"([\d.]+)% \(([\d.]+)ms out of (\d+)ms\) cpu "
+                          r"usage by thread '([^']*)'", text)
+        assert rows and rows[0][3] == "busy-burner"
+        burned = [float(r[1]) for r in rows]
+        assert burned[0] > 0.0 and burned == sorted(burned, reverse=True)
+        # a thread runs on one core at a time: Y never passes Z (Z is
+        # printed to the ms)
+        assert all(float(r[1]) <= float(r[2]) + 1.0 for r in rows)
+        # the false claim is gone: the clock is read, not guessed
+        assert "snapshots non-idle" not in text
 
     def test_idle_threads_filtered_unless_asked(self):
         n = Node(name="ht2-node")
@@ -530,17 +538,27 @@ class TestHotThreads:
         try:
             _, with_idle = rc.dispatch(
                 "GET", "/_nodes/hot_threads",
-                {"interval": "5ms", "snapshots": "2", "threads": "64",
+                {"interval": "5ms", "snapshots": "2", "threads": "1000",
                  "ignore_idle_threads": "false"}, b"")
             _, without = rc.dispatch(
                 "GET", "/_nodes/hot_threads",
-                {"interval": "5ms", "snapshots": "2", "threads": "64"}, b"")
+                {"interval": "5ms", "snapshots": "2", "threads": "1000"}, b"")
+            s_wait, wait = rc.dispatch(
+                "GET", "/_nodes/hot_threads",
+                {"interval": "5ms", "snapshots": "2", "type": "wait"}, b"")
+            s_bad, _ = rc.dispatch("GET", "/_nodes/hot_threads",
+                                   {"type": "bogus"}, b"")
         finally:
             n.close()
-        # pool workers parked in queue.get are idle: reported only when
+        # pool workers parked in queue.get burn no CPU: reported only when
         # ignore_idle_threads=false
         assert with_idle.count("usage by thread") > \
             without.count("usage by thread")
+        assert "(0.0ms out of" in with_idle
+        # the reference's other types are answered with the CPU ranking,
+        # and say so; a type the reference does not know is a 400
+        assert s_wait == 200 and "type=cpu (asked for wait" in wait
+        assert s_bad == 400
 
 
 class TestCatThreadPool:
