@@ -33,6 +33,11 @@ Names:
                       the segment's last used slot x its bucket class;
                       the XLA program: D x its class): what the kernel's
                       vector work scales with
+  agg_int_sums        metric sums those programs' kernel added up as
+                      exact int32 partials, widened into f32 once a drain
+                      (ops/aggs.int_sum_fits: the column's codes cannot
+                      overflow a partial); a sum on a column past that
+                      range, and every sum of the XLA program, is f32
   agg_declined        a host-loop aggregated search segment the program
                       did not serve (host collectors)
   agg_declined_mesh   a search the mesh program served whose aggregation

@@ -18,11 +18,24 @@ document has no value) and the segment's int8 live mask:
 No per-document scatter: every bucket is a compare over a tile held in
 VMEM (the TPU path is a Pallas kernel that reads each column once, block
 by block, and keeps ``[B, 8, 128]`` accumulators resident across the
-grid; the sums are f32 with Kahan compensation across blocks, so an
-average over 10^8 codes keeps ~1e-7). Elsewhere (the CPU, tiny segments)
-the same semantics are one XLA program over a ``[B, D]`` compare. Both
-return one packed int32 vector — the segment's match count, then ``[B]``
-per number — for the caller's one pull.
+grid). A pass of the kernel counts four buckets at once: a document adds
+``1 << 8 (key % 4)`` to int32 word ``key // 4``, so one compare, select
+and add over a vreg count four buckets (a metric's values present
+likewise). Only a sum, least or greatest value takes a compare a bucket.
+A sum adds the exact int32 codes of the bucket's documents into an int32
+partial where the column's codes allow it (``int_sum_fits``), else their
+f32 values into a Kahan-compensated f32 pair every chunk. Every
+``_DRAIN_CHUNKS`` chunks, and at the end of each grid step, a drain
+empties the packed words into the ``[B, 8, 128]`` int32 counts and each
+partial, as its two exact 16-bit halves, into its f32 Kahan pair (an
+average over 10^8 codes keeps ~1e-7). The drain's invariant: no 8-bit
+field holds more than ``4 x _DRAIN_CHUNKS`` = 128 < 256 documents. And no
+partial passes int32, since a lane adds at most ``LANE_DOCS`` codes of at
+most ``_I32_MAX // LANE_DOCS`` in magnitude between two drains.
+Elsewhere (the CPU, tiny segments) the same semantics are one XLA
+program over a ``[B, D]`` compare. Both return one packed int32 vector —
+the segment's match count, then ``[B]`` per number — for the caller's
+one pull.
 
 The kernel's work is (slots scanned) x (bucket passes). Its grid stops
 at a run-time bound the caller passes with the parameters, so the
@@ -39,6 +52,7 @@ multiply the kernel's compile time.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple, Tuple
 
@@ -56,6 +70,12 @@ BUCKET_CLASSES = (8, 16, 24, 32, 40, 48, 56, 64, 96, 128)
 # loop folds into the accumulators at once
 _BLOCK_ROWS = 2048
 _CHUNK_ROWS = 32
+# chunks between two drains of the packed counts and the int32 sums: a
+# chunk adds at most _CHUNK_ROWS / 8 = 4 documents to a lane's field, so a
+# field holds at most 128 < 256 when it is drained
+_DRAIN_CHUNKS = 32
+# documents one lane of an int32 partial adds up between two drains
+LANE_DOCS = _DRAIN_CHUNKS * _CHUNK_ROWS // 8
 _VMEM_LIMIT_BYTES = 48 << 20
 
 
@@ -80,12 +100,22 @@ def bucket_class(n: int):
     return None
 
 
+def int_sum_fits(code_min: int, code_max: int) -> bool:
+    """True where a column's sums can run as exact int32 partials: no
+    lane's partial can pass int32 between two drains, whatever codes of
+    ``[code_min, code_max]`` its documents hold."""
+    return max(abs(code_min), abs(code_max)) * LANE_DOCS <= _I32_MAX
+
+
 class Metric(NamedTuple):
     col: int  # index into the program's code columns
     count: bool  # values present (needed unless every doc has one)
     sum: bool
     min: bool
     max: bool
+    # the kernel sums as exact int32 partials (int_sum_fits), else f32
+    # with Kahan compensation every chunk
+    int_sum: bool
 
 
 class TreeSpec(NamedTuple):
@@ -197,11 +227,47 @@ def _acc_layout(spec: TreeSpec):
     return rows
 
 
+def _drain_layout(spec: TreeSpec):
+    """The kernel's int32 scratch, emptied into the accumulators at every
+    drain, in order: (name, metric index, leading dim). ``packed`` and
+    ``mpacked`` hold four buckets' counts a word (bucket b in bits
+    8(b % 4) .. 8(b % 4) + 7 of word b // 4), ``part`` a metric's sums as
+    exact int32 partials."""
+    G = spec.B // 4
+    rows = [("packed", -1, G)]
+    for j, m in enumerate(spec.metrics):
+        if m.count:
+            rows.append(("mpacked", j, G))
+        if m.int_sum:
+            rows.append(("part", j, spec.B))
+    return rows
+
+
+def _kahan(s_ref, c_ref, b, *xs):
+    """Add each of ``xs`` to row ``b`` of a compensated f32 sum."""
+    s, c = s_ref[b], c_ref[b]
+    for x in xs:
+        y = x - c
+        t = s + y
+        c = (t - s) - y
+        s = t
+    s_ref[b] = s
+    c_ref[b] = c
+
+
 def _kernel(*, spec: TreeSpec, TR: int, CH: int):
     from jax.experimental import pallas as pl
 
     layout = _acc_layout(spec)
+    drains = _drain_layout(spec)
     nf = len(spec.filters)
+    G = spec.B // 4
+    nch = TR // CH
+    # chunks between two drains: a divisor of the step's chunks, so a step
+    # ends on a drain
+    DR = math.gcd(nch, _DRAIN_CHUNKS)
+    # a bucket's own compare serves its sum, least and greatest value
+    per_bucket = any(m.sum or m.min or m.max for m in spec.metrics)
 
     def fold(x, op):
         # [CH, 128] -> [8, 128]: vreg-wise, no cross-lane work
@@ -210,15 +276,20 @@ def _kernel(*, spec: TreeSpec, TR: int, CH: int):
     def kernel(params_ref, *refs):
         cols = refs[:spec.n_cols]
         live = refs[spec.n_cols]
-        accs = refs[spec.n_cols + 1:]
+        accs = refs[spec.n_cols + 1:spec.n_cols + 1 + len(layout)]
+        scratch = refs[spec.n_cols + 1 + len(layout):]
         by = {}
         for (name, j, _dt, _init), ref in zip(layout, accs):
+            by[(name, j)] = ref
+        for (name, j, _n), ref in zip(drains, scratch):
             by[(name, j)] = ref
 
         @pl.when(pl.program_id(0) == 0)
         def _init():
             for (name, j, dt, init), ref in zip(layout, accs):
                 ref[...] = jnp.full(ref.shape, init, dt)
+            for ref in scratch:
+                ref[...] = jnp.zeros(ref.shape, jnp.int32)
 
         lo = [params_ref[2 * i] for i in range(nf)]
         hi = [params_ref[2 * i + 1] for i in range(nf)]
@@ -241,30 +312,43 @@ def _kernel(*, spec: TreeSpec, TR: int, CH: int):
                 kc = cols[spec.key_col][rows, :]
                 key = _floor_key(kc - c0, q, inv_q)
                 key = jnp.where(sel & (kc != CODE_MISSING), key, -1)
-            vals = []
+            # a key's word and its field's unit; a key outside [0, B)
+            # (-1: no bucket) matches no word
+            g = key >> 2
+            unit = jnp.left_shift(1, (key & 3) * 8)
+            vals, units = [], []
             for m in spec.metrics:
                 v = cols[m.col][rows, :]
                 has = v != CODE_MISSING
-                vals.append((has.astype(jnp.int32),
-                             jnp.where(has, v, 0).astype(jnp.float32),
-                             jnp.where(has, v, _I32_MAX),
+                units.append(jnp.where(has, unit, 0) if m.count else None)
+                if m.int_sum:
+                    vs = jnp.where(has, v, 0)
+                elif m.sum:
+                    vs = jnp.where(has, v, 0).astype(jnp.float32)
+                else:
+                    vs = None
+                vals.append((vs, jnp.where(has, v, _I32_MAX),
                              v))  # CODE_MISSING is already the least
-            cnt = by[("count", -1)]
+            packed = by[("packed", -1)]
+            for w in range(G):
+                in_w = g == w
+                packed[w] += fold(jnp.where(in_w, unit, 0), jnp.sum)
+                for j, m in enumerate(spec.metrics):
+                    if m.count:
+                        ref = by[("mpacked", j)]
+                        ref[w] += fold(jnp.where(in_w, units[j], 0), jnp.sum)
+            if not per_bucket:
+                return carry
             for b in range(spec.B):
                 hit = key == b
-                cnt[b] += fold(hit.astype(jnp.int32), jnp.sum)
                 for j, m in enumerate(spec.metrics):
-                    hasi, vf, vmin, vmax = vals[j]
-                    if m.count:
-                        ref = by[("mcount", j)]
-                        ref[b] += fold(jnp.where(hit, hasi, 0), jnp.sum)
-                    if m.sum:
-                        s_ref, c_ref = by[("sum", j)], by[("comp", j)]
-                        y = fold(jnp.where(hit, vf, 0.0), jnp.sum) - c_ref[b]
-                        acc = s_ref[b]
-                        t2 = acc + y
-                        c_ref[b] = (t2 - acc) - y
-                        s_ref[b] = t2
+                    vs, vmin, vmax = vals[j]
+                    if m.int_sum:
+                        ref = by[("part", j)]
+                        ref[b] += fold(jnp.where(hit, vs, 0), jnp.sum)
+                    elif m.sum:
+                        _kahan(by[("sum", j)], by[("comp", j)], b,
+                               fold(jnp.where(hit, vs, 0.0), jnp.sum))
                     if m.min:
                         ref = by[("min", j)]
                         ref[b] = jnp.minimum(
@@ -277,11 +361,44 @@ def _kernel(*, spec: TreeSpec, TR: int, CH: int):
                                          jnp.max))
             return carry
 
+        zero = jnp.zeros((8, 128), jnp.int32)
+
+        def drain_word(w):
+            # word w holds buckets 4w .. 4w + 3, one 8-bit field each
+            def unpack(src, dst):
+                word = src[w]
+                for f in range(4):
+                    # the mask reads bits 24-31 right where the word is
+                    # negative as an int32
+                    dst[4 * w + f] += (word >> (8 * f)) & 0xFF
+                src[w] = zero
+
+            unpack(by[("packed", -1)], by[("count", -1)])
+            for j, m in enumerate(spec.metrics):
+                if m.count:
+                    unpack(by[("mpacked", j)], by[("mcount", j)])
+                if m.int_sum:
+                    part = by[("part", j)]
+                    s_ref, c_ref = by[("sum", j)], by[("comp", j)]
+                    for f in range(4):
+                        p = part[4 * w + f]
+                        # both 16-bit halves are exact in f32
+                        _kahan(s_ref, c_ref, 4 * w + f,
+                               (p >> 16).astype(jnp.float32) * 65536.0,
+                               (p & 0xFFFF).astype(jnp.float32))
+                        part[4 * w + f] = zero
+
+        def period(d, carry):
+            jax.lax.fori_loop(d * DR, (d + 1) * DR, chunk, 0)
+            for w in range(G):
+                drain_word(w)
+            return carry
+
         @pl.when(pl.program_id(0) <= last)
         def _scan():
-            jax.lax.fori_loop(0, TR // CH, chunk, 0)
+            jax.lax.fori_loop(0, nch // DR, period, 0)
 
-    return kernel, layout
+    return kernel, layout, drains
 
 
 def _pallas_tree(params, live, cols, *, spec: TreeSpec, interpret=False):
@@ -292,7 +409,7 @@ def _pallas_tree(params, live, cols, *, spec: TreeSpec, interpret=False):
     R = D // 128
     TR = block_slots(D) // 128
     CH = min(_CHUNK_ROWS, TR)
-    kernel, layout = _kernel(spec=spec, TR=TR, CH=CH)
+    kernel, layout, drains = _kernel(spec=spec, TR=TR, CH=CH)
     at = 2 * len(spec.filters) + 2  # params[at]: the last block to scan
     # past it the index repeats, so Pallas fetches nothing new
     block = pl.BlockSpec((TR, 128), lambda i, p: (jnp.minimum(i, p[at]), 0))
@@ -310,7 +427,9 @@ def _pallas_tree(params, live, cols, *, spec: TreeSpec, interpret=False):
         out_shape=shapes,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(R // TR,),
-            in_specs=[block] * (spec.n_cols + 1), out_specs=out_specs),
+            in_specs=[block] * (spec.n_cols + 1), out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((n, 8, 128), jnp.int32)
+                            for _name, _j, n in drains]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),

@@ -33,7 +33,7 @@ import numpy as np
 
 from elasticsearch_tpu.index.segment import CODE_LIMIT
 from elasticsearch_tpu.ops.aggs import (Metric, TreeSpec, bucket_class,
-                                        last_block, unpack)
+                                        int_sum_fits, last_block, unpack)
 
 BUCKET_TYPES = {"histogram": frozenset({"field", "interval", "min_doc_count",
                                         "format"}),
@@ -251,7 +251,10 @@ def plan(ctx, query, aggs) -> Optional[Plan]:
         cnt, s, mn, mx = need[c]
         # a column with a value on every document counts as the bucket
         full = cols[c].value_count >= ctx.segment.num_docs
-        metrics.append(Metric(c, cnt and not full, s, mn, mx))
+        # exact int32 partials where the column's codes cannot overflow
+        # one between two drains (epoch seconds over a year can)
+        exact = s and int_sum_fits(cols[c].code_min, cols[c].code_max)
+        metrics.append(Metric(c, cnt and not full, s, mn, mx, exact))
     spec = TreeSpec(n_cols=len(cols), filters=tuple(filters),
                     key_col=key_col, B=B, metrics=tuple(metrics))
     # the kernel's grid stops at the block of the last used slot: maxDoc
@@ -281,6 +284,9 @@ def dispatch(ctx, p: Plan):
     # slots the program scans times the bucket passes it makes over them
     slots = (int(p.params[-1]) + 1) * block_slots(ctx.D) if kernel else ctx.D
     kernels.record("agg_bucket_slots", slots * p.spec.B)
+    if kernel:  # the metric sums it adds up as exact int32 partials
+        kernels.record("agg_int_sums",
+                       sum(m.int_sum for m in p.spec.metrics))
     # a few int32 scalars a search, uploaded with the call  # tpulint: offbudget
     params = jax.device_put(p.params, seg.device) if seg.device is not None \
         else p.params

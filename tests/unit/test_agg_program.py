@@ -253,32 +253,49 @@ def test_metrics_alone_and_two_shards_on_the_mesh_default(midnight):
     assert a["lo"]["value"] == ms[sel].min()
 
 
-# the kernel (interpret mode, blocks of 32 rows = 4,096 slots) against the
-# XLA program. ``used`` is the segment's used-slot count (maxDoc): past it
-# the live byte is 0, and the blocks past its last one hold live-looking
-# documents that match, which the kernel must not read. ``nb`` is the
-# request's real bucket count: the filter on the key keeps keys in [0, nb),
-# and the buckets past it keep their initial values
+# the kernel (interpret mode) against the XLA program. ``used`` is the
+# segment's used-slot count (maxDoc): past it the live byte is 0, and the
+# blocks past its last one hold live-looking documents that match, which
+# the kernel must not read. ``nb`` is the request's real bucket count: the
+# filter on the key keeps keys in [0, nb), and the buckets past it keep
+# their initial values. ``rows`` is the block's rows of 128 slots (32 is
+# one chunk a grid step, a drain each; 2048 is the real block: 64 chunks,
+# two drains a step). ``data``: "random" codes; "bucket3" puts every used
+# document, live and with a value, in bucket 3, so each lane's field of
+# word 0 takes 4 documents a chunk and holds 128 (bits 24-31, the word
+# negative) when drained; "at_limit" / "past_limit" do that with every
+# value at the largest code the int32 partials admit, or one past it (the
+# f32 Kahan path)
 KERNEL_CASES = {
-    # name: (D, used, tree, B, nb, deletes in the last used block)
-    "the_first_case": (8192, 8192, "stats", 16, 13, False),
+    # name: (D, used, tree, B, nb, deletes, rows, data)
+    "the_first_case": (8192, 8192, "stats", 16, 13, False, 32, "random"),
     "used_ends_mid_block_nb_1": (16384, 2 * 4096 + 1234, "stats", 16, 1,
-                                 False),
+                                 False, 32, "random"),
     "used_ends_on_a_block_edge_nb_class_less_7": (16384, 3 * 4096, "stats",
-                                                  16, 9, False),
+                                                  16, 9, False, 32,
+                                                  "random"),
     "used_ends_in_the_first_block_nb_class": (16384, 1000, "stats", 16, 16,
-                                              False),
+                                              False, 32, "random"),
     "deletes_in_the_last_used_block": (16384, 2 * 4096 + 3000, "stats", 24,
-                                       17, True),
-    "count_only": (16384, 2 * 4096 + 77, "count", 16, 9, False),
-    "bucketless": (16384, 3 * 4096 - 5, "none", 8, 1, False),
+                                       17, True, 32, "random"),
+    "count_only": (16384, 2 * 4096 + 77, "count", 16, 9, False, 32,
+                   "random"),
+    "bucketless": (16384, 3 * 4096 - 5, "none", 8, 1, False, 32, "random"),
+    "fields_at_the_drain_limit": (2 * 2048 * 128, 2 * 2048 * 128, "stats",
+                                  8, 4, False, 2048, "bucket3"),
+    "int_sums_at_the_code_limit": (2 * 2048 * 128, 2 * 2048 * 128 - 4096,
+                                   "stats", 8, 4, False, 2048, "at_limit"),
+    "f32_sums_one_past_the_code_limit": (2 * 2048 * 128, 2 * 2048 * 128,
+                                         "stats", 8, 4, False, 2048,
+                                         "past_limit"),
 }
 
 
-def _kernel_case(D, used, tree, B, nb, deletes):
+def _kernel_case(D, used, tree, B, nb, deletes, rows, data):
     """(spec, params, live, cols) of a case, as a plan would give them,
     and the live mask a segment holds (0 past ``used``)."""
-    from elasticsearch_tpu.ops.aggs import Metric, TreeSpec, last_block
+    from elasticsearch_tpu.ops.aggs import (LANE_DOCS, Metric, TreeSpec,
+                                            int_sum_fits)
 
     rng = np.random.default_rng(7)
     key = rng.integers(0, 5000, D).astype(np.int32)
@@ -286,20 +303,31 @@ def _kernel_case(D, used, tree, B, nb, deletes):
     val = rng.integers(-300, 100000, D).astype(np.int32)
     val[rng.random(D) < 0.1] = CODE_MISSING
     live = (rng.random(D) < 0.95).astype(np.int8)
+    vlo, vhi = -200, 90000
+    if data != "random":
+        key[:] = rng.integers(400, 500, D)  # bucket 3 of [100, 100 nb + 99]
+        live[:] = 1
+        limit = (2 ** 31 - 1) // LANE_DOCS
+        val = {"bucket3": val, "at_limit": np.full(D, limit),
+               "past_limit": np.full(D, limit + 1)}[data].astype(np.int32)
+        val[val == CODE_MISSING] = 7
+        vlo, vhi = -limit - 1, limit + 1
     if deletes:
         live[used - 700:used:3] = 0
     live[used:] = 0
-    stats = (Metric(1, True, True, True, True),)
+    has = val != CODE_MISSING
+    fits = int_sum_fits(int(val[has].min()), int(val[has].max()))
+    stats = (Metric(1, True, True, True, True, fits),)
     if tree == "none":
         spec = TreeSpec(n_cols=2, filters=(0, 1), key_col=-1, B=B,
                         metrics=stats)
-        params = [100, 1399, -200, 90000, 1, 1]
+        params = [100, 1399, vlo, vhi, 1, 1]
     else:
         # keys floor((code - 100) / 100) over codes [100, 100 nb + 99]
         spec = TreeSpec(n_cols=2, filters=(0, 1), key_col=0, B=B,
                         metrics=stats if tree == "stats" else ())
-        params = [100, 100 * nb + 99, -200, 90000, 100, 100]
-    params += [last_block(D, used)]
+        params = [100, 100 * nb + 99, vlo, vhi, 100, 100]
+    params += [(max(used, 1) - 1) // (rows * 128)]
     return spec, np.asarray(params, np.int32), live, (key, val)
 
 
@@ -309,20 +337,22 @@ def test_the_kernel_and_the_xla_program_agree(case, monkeypatch):
 
     from elasticsearch_tpu.ops import aggs
 
-    monkeypatch.setattr(aggs, "_BLOCK_ROWS", 32)
-    D, used, tree, B, nb, deletes = KERNEL_CASES[case]
+    D, used, tree, B, nb, deletes, rows, data = KERNEL_CASES[case]
+    monkeypatch.setattr(aggs, "_BLOCK_ROWS", rows)
     spec, params, live, (key, val) = _kernel_case(*KERNEL_CASES[case])
+    block = rows * 128
     last = int(params[-1])
-    assert D // 4096 >= 2 and last == (max(used, 1) - 1) // 4096
+    assert D // block >= 2 and last == aggs.last_block(D, used)
+    assert all(m.int_sum == (data != "past_limit") for m in spec.metrics)
     xla = np.asarray(aggs.agg_tree(jnp.asarray(params), jnp.asarray(live),
                                    jnp.asarray(key), jnp.asarray(val),
                                    spec=spec))
     # past the last used block: documents that are live and match
     seen = live.copy()
-    seen[(last + 1) * 4096:] = 1
+    seen[(last + 1) * block:] = 1
     k2, v2 = key.copy(), val.copy()
-    k2[(last + 1) * 4096:] = 150
-    v2[(last + 1) * 4096:] = 7
+    k2[(last + 1) * block:] = 150
+    v2[(last + 1) * block:] = 7
     pallas = np.asarray(aggs._pallas_tree(
         jnp.asarray(params), jnp.asarray(seen),
         (jnp.asarray(k2), jnp.asarray(v2)), spec=spec, interpret=True))
@@ -339,10 +369,23 @@ def test_the_kernel_and_the_xla_program_agree(case, monkeypatch):
     np.testing.assert_allclose(xla[sums].view(np.float32),
                                pallas[sums].view(np.float32), rtol=1e-6)
     hi = 100 * nb + 99 if tree != "none" else 1399
-    assert xla[0] == int(((live != 0) & (key >= 100) & (key <= hi)
-                          & (val >= -200) & (val <= 90000)).sum()) > 0
-    if tree != "none":
-        assert (xla[1:1 + nb] > 0).all() and not xla[1 + nb:1 + B].any()
+    sel = ((live != 0) & (key >= 100) & (key <= hi)
+           & (val >= params[2]) & (val <= params[3]))
+    assert xla[0] == int(sel.sum()) > 0
+    if data == "random":
+        if tree != "none":
+            assert (xla[1:1 + nb] > 0).all() and not xla[1 + nb:1 + B].any()
+        return
+    # every selected document in bucket 3: its count and values present
+    # read the drained fields exactly, the other buckets nothing
+    want = np.zeros(B, np.int64)
+    want[3] = sel.sum()
+    np.testing.assert_array_equal(pallas[1:1 + B], want)
+    np.testing.assert_array_equal(pallas[1 + B:1 + 2 * B], want)
+    # the sum against the exact int64 one, within f32's last place
+    exact = np.float32(val[sel].astype(np.int64).sum())
+    got = pallas[1 + 2 * B + 3:1 + 2 * B + 4].view(np.float32)[0]
+    assert abs(float(got) - float(exact)) <= float(np.spacing(exact))
 
 
 @pytest.fixture
@@ -421,6 +464,82 @@ def test_agg_bucket_slots_rise_by_what_the_program_scans(trips3k,
         assert b["doc_count"] == st["count"] == n
         assert st["sum"] == pytest.approx(total, rel=1e-6)
         assert (st["min"], st["max"]) == (pytest.approx(lo), pytest.approx(hi))
+
+
+@pytest.fixture(scope="module")
+def year3k():
+    """3,000 trips over 2015: the dropoff second's code spans a year
+    (~3.15e7), past what an int32 partial admits, the cents do not."""
+    rng = np.random.default_rng(39)
+    n = 3000
+    ms = EPOCH + rng.integers(0, 365 * DAY // 1000, n) * 1000
+    dist = rng.integers(0, 3000, n)
+    amt = rng.integers(250, 20000, n)
+    node = _node(ms.astype(np.int64), dist, amt)
+    node.trips = (ms, dist, amt)
+    return node
+
+
+# (body, int-path sums the plan takes): the stats of the cents, two sums
+# of cents, a tree that sums nothing, and the stats of a year of seconds
+INT_SUM_TREES = {
+    "mile_stats": (_mile_body(0, 20), 1),
+    "two_sums_and_a_max": ({"size": 0, "query": {"match_all": {}},
+                            "aggs": {"a": {"avg": {"field": "amt"}},
+                                     "s": {"sum": {"field": "dist"}},
+                                     "m": {"max": {"field": "ts"}}}}, 2),
+    "count_only": (_day_body(2, 18), 0),
+    "stats_of_a_year_of_seconds": ({"size": 0, "query": {"match_all": {}},
+                                    "aggs": {"t": {"stats": {"field": "ts"}}}},
+                                   0),
+}
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("tree", sorted(INT_SUM_TREES))
+def test_agg_int_sums_rise_by_the_sums_the_kernel_takes_as_int32(
+        year3k, monkeypatch, tree, kernel):
+    """``estpu_kernel_dispatch_total{kernel="agg_int_sums"}`` rises by the
+    metric sums a dispatch gives the kernel as exact int32 partials: none
+    for a tree without a sum, or a column whose codes could overflow a
+    partial, and none where the XLA program runs; the answer is the
+    reference's either way."""
+    from elasticsearch_tpu.ops import aggs
+
+    body, want = INT_SUM_TREES[tree]
+    monkeypatch.setattr(aggs, "_BLOCK_ROWS", 8)
+    if kernel:
+        monkeypatch.setattr(aggs, "use_kernel", lambda D: True)
+        monkeypatch.setattr(
+            aggs, "agg_tree", lambda params, live, *cols, spec, kernel:
+            aggs._pallas_tree(params, live, cols, spec=spec, interpret=True))
+    plan = _plan(year3k, body)
+    assert sum(m.int_sum for m in plan.spec.metrics) == want
+    got, k = _search(year3k, body)
+    assert k.get("agg_one_program") == 1
+    assert k.get("agg_int_sums", 0) == (want if kernel else 0)
+    ms, dist, amt = year3k.trips
+    a = got["aggregations"]
+    if tree == "stats_of_a_year_of_seconds":
+        st = a["t"]
+        assert st["count"] == len(ms) and st["min"] == ms.min()
+        assert st["max"] == ms.max()
+        assert st["sum"] == pytest.approx(ms.sum(), rel=1e-6)
+    elif tree == "two_sums_and_a_max":
+        assert a["a"]["value"] == pytest.approx(amt.mean() / 100, rel=1e-6)
+        assert a["s"]["value"] == pytest.approx(dist.sum() / 100, rel=1e-6)
+        assert a["m"]["value"] == ms.max()
+    elif tree == "mile_stats":
+        want_m = _ref_miles(dist, amt, 0, 20)
+        buckets = a["miles"]["buckets"]
+        assert [b["key"] for b in buckets] == sorted(want_m)
+        for b in buckets:
+            n, total, lo, hi = want_m[b["key"]]
+            assert b["amt"]["count"] == n
+            assert b["amt"]["sum"] == pytest.approx(total, rel=1e-6)
+    else:
+        assert [(b["key"], b["doc_count"]) for b in a["days"]["buckets"]] \
+            == _ref_days(ms, 2, 18)
 
 
 KINDS = {"long": ("long", [5, -3, None, 2 ** 40]),
