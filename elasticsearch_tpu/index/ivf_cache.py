@@ -93,8 +93,9 @@ def content_key(vecs_host: np.ndarray, exists_host: np.ndarray,
     e = np.ascontiguousarray(exists_host, dtype=bool)
     h = hashlib.sha1()
     h.update(repr((v.shape, metric, int(max_docs))).encode())
-    h.update(v.tobytes())
-    h.update(e.tobytes())
+    # the arrays' own buffers: no second host copy of a multi-GB slab
+    h.update(memoryview(v).cast("B"))
+    h.update(memoryview(e).cast("B"))
     return h.hexdigest()
 
 

@@ -78,6 +78,48 @@ class FieldMapping:
         return self.type == "dense_vector"
 
 
+#: the parameters each ANN index type takes besides ``type`` (names of
+#: OpenSearch k-NN's faiss ``ivf`` method and ``pq`` encoder): ``nlist``
+#: inverted lists, ``m`` PQ subspaces
+_ANN_PARAMS = {"ivf": ("nlist",), "ivf_flat": ("nlist",),
+               "ivf_pq": ("nlist", "m")}
+
+
+def _ann_options(full: str, opts: Any, dims: int) -> dict:
+    """A dense_vector field's ``index_options``, checked: a known type, only
+    its own parameters, each a positive whole number, and ``m`` a divisor
+    of ``dims``. Returned with the numbers as ints."""
+    ann = opts.get("type") if isinstance(opts, dict) else None
+    if ann not in _ANN_PARAMS:
+        raise MapperParsingException(
+            f"dense_vector field [{full}] has unsupported index_options "
+            f"type [{ann}]; use one of [{', '.join(_ANN_PARAMS)}]")
+    out = {"type": ann}
+    for key, value in opts.items():
+        if key == "type":
+            continue
+        if key not in _ANN_PARAMS[ann]:
+            raise MapperParsingException(
+                f"dense_vector field [{full}]: index_options type [{ann}] "
+                f"has no parameter [{key}]; it takes "
+                f"{list(_ANN_PARAMS[ann])}")
+        try:
+            n = int(value)
+            ok = n > 0 and n == float(value) and not isinstance(value, bool)
+        except (TypeError, ValueError):
+            ok = False
+        if not ok:
+            raise MapperParsingException(
+                f"dense_vector field [{full}]: index_options [{key}] must be "
+                f"a positive integer, got [{value}]")
+        out[key] = n
+    if "m" in out and dims % out["m"]:
+        raise MapperParsingException(
+            f"dense_vector field [{full}]: index_options [m] = {out['m']} "
+            f"must divide dims [{dims}]")
+    return out
+
+
 def _canonical_type(props: dict) -> str:
     t = props.get("type", "object")
     if t == "string":  # ES 2.0 legacy
@@ -246,13 +288,7 @@ class Mappings:
         if t == "dense_vector" and fm.dims <= 0:
             raise MapperParsingException(f"dense_vector field [{full}] requires [dims]")
         if t == "dense_vector" and fm.index_options:
-            ann = (fm.index_options.get("type")
-                   if isinstance(fm.index_options, dict) else None)
-            if ann not in ("ivf", "ivf_flat", "ivf_pq"):
-                raise MapperParsingException(
-                    f"dense_vector field [{full}] has unsupported "
-                    f"index_options type [{ann}]; use one of "
-                    f"[ivf, ivf_flat, ivf_pq]")
+            fm.index_options = _ann_options(full, fm.index_options, fm.dims)
         for sub, subp in p.get("fields", {}).items():
             st = _canonical_type(subp)
             fm.fields[sub] = self._parse_field(f"{full}.{sub}", st, subp, nested_path)

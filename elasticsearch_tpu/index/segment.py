@@ -535,6 +535,9 @@ class VectorColumn:
     vecs_host: Any = None  # host mirror (mesh stacking, IVF build)
     exists_host: Any = None
     similarity: str = "cosine"
+    # the field's ANN ``index_options`` (mappings._ann_options): its
+    # ``nlist`` and ``m`` size the IVF and PQ builds; None = defaults
+    index_options: Optional[dict] = None
     # lazy IVF-flat coarse quantizer (ops/ivf.py); False = build attempted
     # and declined (too few vectors)
     _ivf: Any = None
@@ -596,25 +599,70 @@ class VectorColumn:
             self._ck_max = max_docs
         return self._ck
 
+    def _ann_param(self, key: str) -> Optional[int]:
+        return (self.index_options or {}).get(key)
+
+    def _build_source(self):
+        """(slab, live mask) the builds read: the resident slab where it is
+        on the device (read in place), else the host mirror."""
+        from elasticsearch_tpu.ops.ivf import is_device_array
+
+        vecs = (self.vecs if is_device_array(self.vecs) else
+                self.vecs_host if self.vecs_host is not None
+                else np.asarray(self.vecs))
+        eh = (self.exists_host if self.exists_host is not None
+              else np.asarray(self.exists))
+        return vecs, eh
+
+    def _ann_key(self, max_docs: int, key: str) -> str:
+        """The blob cache's key: the slab's content, and the mapping's
+        parameter where it names one (a default build keeps the bare
+        content key)."""
+        ck = self.cache_key(max_docs)
+        value = self._ann_param(key)
+        return ck if value is None else f"{ck}-{key}{value}"
+
+    def build_ann(self, max_docs: int, tracer=None) -> None:
+        """The index-time ANN build of this column, as its mapping's
+        ``index_options`` ask: the IVF quantizer for every ANN type, the
+        PQ tier beside it for ``ivf_pq``. One ``ann.build`` span over
+        both (a root span on ``tracer`` where one is given, else a child
+        of the open span)."""
+        opts = self.index_options or {}
+        if opts.get("type") not in ("ivf", "ivf_flat", "ivf_pq"):
+            return
+        from elasticsearch_tpu.tracing.tracer import span
+
+        cm = (tracer.span("ann.build", field=self.name) if tracer is not None
+              else span("ann.build", field=self.name))
+        with cm:
+            # index-time ANN build (like Lucene building HNSW at flush):
+            # refreshes/merges/restores pay the k-means here, never the
+            # first query (r3 verdict weak #9)
+            self.get_ivf(max_docs)
+            if opts["type"] == "ivf_pq":
+                # PQ codes ride beside the coarse quantizer; best-effort —
+                # a tight fielddata breaker leaves the exact fine-rank
+                # path and a later query retries placement
+                self.get_pq(max_docs)
+
     def get_ivf(self, max_docs: int):
         """Build-once IVF index over this (immutable) slab, consulting the
         content-addressed blob cache first so restarts / snapshot restores
         reload the persisted quantizer instead of re-running k-means
         (index/ivf_cache.py; counters ivf_cache_hit / ivf_build)."""
-        # (uses the host mirrors — never forces the lazy device slab)
         if self._ivf is None:
             from elasticsearch_tpu.index import ivf_cache
             from elasticsearch_tpu.monitor import kernels
             from elasticsearch_tpu.ops.ivf import build_ivf
 
-            vh = (self.vecs_host if self.vecs_host is not None
-                  else np.asarray(self.vecs))
-            eh = (self.exists_host if self.exists_host is not None
-                  else np.asarray(self.exists))
-            key = self.cache_key(max_docs)
+            key = self._ann_key(max_docs, "nlist")
             idx = ivf_cache.load(key)
             if idx is None:
-                idx = build_ivf(vh, eh, max_docs, metric=self.similarity)
+                vecs, eh = self._build_source()
+                idx = build_ivf(vecs, eh, max_docs, C=self._ann_param("nlist"),
+                                metric=self.similarity,
+                                vecs_host=self.vecs_host)
                 if idx is not None:
                     kernels.record("ivf_build")
                     ivf_cache.store(key, idx)
@@ -642,14 +690,13 @@ class VectorColumn:
 
         parts = self._pq_parts
         if parts is None:
-            vh = (self.vecs_host if self.vecs_host is not None
-                  else np.asarray(self.vecs))
-            eh = (self.exists_host if self.exists_host is not None
-                  else np.asarray(self.exists))
-            key = self.cache_key(max_docs)
+            key = self._ann_key(max_docs, "m")
             parts = ivf_cache.load_pq(key)
             if parts is None:
-                parts = build_pq(vh, eh, self.similarity)
+                vecs, eh = self._build_source()
+                parts = build_pq(vecs, eh, self.similarity,
+                                 M=self._ann_param("m"),
+                                 vecs_host=self.vecs_host)
                 if parts is None:
                     self._pq = False  # too few vectors: permanent decline
                     return None
@@ -1117,22 +1164,14 @@ class SegmentBuilder:
                     exists[i] = True
             # host arrays: the device slab loads lazily into the
             # evictable fielddata tier on first touch (_resident_field)
+            fm = self.mappings.get(fname)
             vc = VectorColumn(
                 name=fname, vecs=mat, exists=exists,
                 dims=dims, vecs_host=mat, exists_host=exists, similarity=sim,
+                index_options=(getattr(fm, "index_options", None)
+                               if fm is not None else None),
             )
-            fm = self.mappings.get(fname)
-            opts = getattr(fm, "index_options", None) if fm is not None else None
-            if opts and opts.get("type") in ("ivf", "ivf_flat", "ivf_pq"):
-                # index-time ANN build (like Lucene building HNSW at flush):
-                # refreshes/merges/restores pay the k-means here, never the
-                # first query (r3 verdict weak #9)
-                vc.get_ivf(max_docs)
-            if opts and opts.get("type") == "ivf_pq":
-                # PQ codes ride beside the coarse quantizer; best-effort —
-                # a tight fielddata breaker leaves the exact fine-rank
-                # path and a later query retries placement
-                vc.get_pq(max_docs)
+            vc.build_ann(max_docs)
             vectors[fname] = vc
 
         ids = [d.doc_id for d in self.docs]

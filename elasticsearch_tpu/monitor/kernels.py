@@ -51,6 +51,12 @@ Names:
   knn_ivf             IVF-flat probe + exact candidate scoring
   knn_ivf_pq          IVF probe + ADC coarse rank over PQ codes + exact
                       fine re-rank of the top survivors (ops/pq.py)
+  knn_ivf_pq_starved  an IVF-PQ search whose filter left fewer than k
+                      admissible survivors, answered by the brute-force
+                      scan instead (queries.KnnQuery.execute)
+  ann_candidate_slots candidate slots the IVF programs read: nprobe x
+                      Lmax a search, the lists' padding and all
+                      (ops/ivf.ivf_candidate_scores)
   knn_maxsim          multi-vector MaxSim query served by the fused
                       per-token sweep + device scatter-max merge
   knn_fused_batch     kNN/MaxSim request served by the fused BATCH tier

@@ -49,10 +49,14 @@ k-means.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import lru_cache, partial
 from typing import Any, Optional, Tuple
 
 import numpy as np
 
+#: training rows a codeword: faiss's default cap (256 a centroid,
+#: ClusteringParameters.max_points_per_centroid), 65,536 rows at K 256
+TRAIN_PER_CODE = 256
 #: encode-time chunk: bounds the [chunk, M, K] argmax intermediate so a
 #: million-row slab never materializes an N x M x K affinity tensor
 _ENCODE_CHUNK = 16384
@@ -127,59 +131,63 @@ class PqIndex:
         return self.codes
 
 
-def _normalize_rows(x: np.ndarray) -> np.ndarray:
-    n = np.linalg.norm(x, axis=-1, keepdims=True)
-    return x / np.maximum(n, 1e-12)
-
-
-_ENCODE_PROGRAMS: dict = {}
-
-
-def _encode_program(M: int, dsub: int):
-    """Compiled chunk encoder for one (M, dsub) shape class: nearest
-    codeword per subspace via the norm expansion (argmin ||x - c||^2 ==
-    argmax x.c - ||c||^2 / 2) — one einsum on the MXU per chunk."""
-    key = (M, dsub)
-    prog = _ENCODE_PROGRAMS.get(key)
-    if prog is not None:
-        return prog
+@lru_cache(maxsize=None)
+def _encode_program():
+    """The chunk encoder, made once a process (compiled once a shape):
+    the nearest codeword of each subspace of each of the slab's first
+    ``rows`` rows, by the norm expansion (argmin ||x - c||^2 == argmax
+    x.c - ||c||^2 / 2) in full f32 — one einsum on the MXU a chunk, read
+    in place from the slab. ``normalize`` encodes the rows' directions
+    (cosine)."""
     jax = _jax()
     import jax.numpy as jnp
+    from jax import lax
 
-    @jax.jit
-    def run(chunk, codebooks):
-        x = chunk.reshape(chunk.shape[0], M, dsub)
-        aff = jnp.einsum("nmd,mkd->nmk", x, codebooks,
-                         preferred_element_type=jnp.float32)
+    @partial(jax.jit, static_argnames=("chunk", "rows", "normalize"))
+    def pq_encode_rows(slab, codebooks, *, chunk, rows, normalize):
+        M, _K, dsub = codebooks.shape
         # codebooks are repeat-padded when training was tiny (train_pq
         # tiles codewords to K): duplicate codewords are argmax-neutral,
         # and the norm sum runs over the full dsub axis  # tpulint: masked
-        aff = aff - 0.5 * jnp.sum(codebooks * codebooks, axis=-1)[None, :, :]
-        return jnp.argmax(aff, axis=2).astype(jnp.uint8)
+        half = 0.5 * jnp.sum(codebooks * codebooks, axis=-1)[None, :, :]
 
-    # factory-key discipline (ROADMAP #6): the encoder rides the AOT
-    # blob cache so a restarted node re-encodes without recompiling
-    from elasticsearch_tpu.parallel import aot
+        def body(i, out):
+            s = jnp.minimum(i * chunk, rows - chunk)
+            x = lax.dynamic_slice_in_dim(slab, s, chunk)
+            if normalize:
+                x = x / jnp.maximum(
+                    jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
+            aff = jnp.einsum("nmd,mkd->nmk", x.reshape(chunk, M, dsub),
+                             codebooks, precision=lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32) - half
+            code = jnp.argmax(aff, axis=2).astype(jnp.uint8)
+            return lax.dynamic_update_slice_in_dim(out, code, s, 0)
 
-    run = aot.wrap(run, "pq_encode", key)
-    _ENCODE_PROGRAMS[key] = run
-    return run
+        return lax.fori_loop(0, -(-rows // chunk), body,
+                             jnp.zeros((rows, M), jnp.uint8))
+
+    return pq_encode_rows
 
 
-def train_pq(train: np.ndarray, M: int, K: int, iters: int = 6,
-             metric: str = "cosine") -> np.ndarray:
-    """Per-subspace k-means codebooks f32[M, K, dsub] over live training
-    rows (already normalized for cosine). Subspace clustering is ALWAYS
-    squared-l2 (standard PQ — the reconstruction objective), regardless
-    of the field similarity; the similarity shapes the LUT instead."""
-    from elasticsearch_tpu.ops.ivf import kmeans
+def train_pq(train, M: int, K: int, iters: int = 6) -> np.ndarray:
+    """Per-subspace k-means codebooks f32[M, K, dsub] over training rows
+    ``train`` [n, dims] (device or host; already normalized for cosine).
+    Subspace clustering is ALWAYS squared-l2 (standard PQ — the
+    reconstruction objective), regardless of the field similarity; the
+    similarity shapes the LUT instead. The M subspaces share one shape,
+    so each jitted step compiles once for all of them."""
+    import jax.numpy as jnp
+    from jax import lax
 
-    n, dims = train.shape
-    dsub = dims // M
+    from elasticsearch_tpu.ops.ivf import train_centroids
+
+    train = jnp.asarray(train, jnp.float32)
+    dsub = train.shape[1] // M
     books = np.empty((M, K, dsub), np.float32)
     for m in range(M):
-        sub = np.ascontiguousarray(train[:, m * dsub:(m + 1) * dsub])
-        cents, _ = kmeans(sub, K, iters=iters, metric="l2")
+        sub = lax.dynamic_slice_in_dim(train, m * dsub, dsub, axis=1)
+        cents = np.asarray(train_centroids(sub, K, iters=iters,
+                                           metric="l2"))
         if cents.shape[0] < K:  # tiny training set: repeat-pad codewords
             reps = int(np.ceil(K / cents.shape[0]))
             cents = np.tile(cents, (reps, 1))[:K]
@@ -187,45 +195,52 @@ def train_pq(train: np.ndarray, M: int, K: int, iters: int = 6,
     return books
 
 
-def pq_encode(vecs: np.ndarray, codebooks: np.ndarray) -> np.ndarray:
-    """uint8[N, M] codes for every slab row (chunked device encode)."""
+def pq_encode(vecs, codebooks: np.ndarray, rows: Optional[int] = None,
+              normalize: bool = False) -> np.ndarray:
+    """uint8[N, M] codes of the slab ``vecs`` [N, dims] (device: read in
+    place; host: sent up once): the first ``rows`` rows encoded (all
+    when None), the rest 0."""
     jax = _jax()
 
-    M, _K, dsub = codebooks.shape
+    N = vecs.shape[0]
+    rows = N if rows is None else int(rows)
+    M = codebooks.shape[0]
+    from elasticsearch_tpu.ops.ivf import is_device_array
+
+    if not is_device_array(vecs):
+        vecs = jax.device_put(  # tpulint: offbudget
+            np.asarray(vecs[:rows], np.float32))
     # (M, dsub) is the pq_layout shape class for the field's dims — a
     # config-bounded universe, one program per layout  # tpulint: bucketed
-    prog = _encode_program(M, dsub)
-    # offbudget: build-time temporaries, freed when the encode returns
-    d_books = jax.device_put(codebooks)  # tpulint: offbudget
-    N = vecs.shape[0]
-    out = np.empty((N, M), np.uint8)
-    step = _ENCODE_CHUNK
-    for s in range(0, N, step):
-        chunk = vecs[s:s + step]
-        if chunk.shape[0] < step and N > step:
-            pad = np.zeros((step - chunk.shape[0], vecs.shape[1]),
-                           np.float32)
-            enc = prog(jax.device_put(  # tpulint: offbudget
-                np.concatenate([chunk, pad])), d_books)
-            out[s:s + chunk.shape[0]] = np.asarray(enc)[: chunk.shape[0]]
-        else:
-            enc = prog(jax.device_put(chunk), d_books)  # tpulint: offbudget
-            out[s:s + chunk.shape[0]] = np.asarray(enc)
+    enc = _encode_program()(vecs, jax.device_put(codebooks),  # tpulint: offbudget
+                            chunk=min(_ENCODE_CHUNK, rows), rows=rows,
+                            normalize=normalize)
+    out = np.zeros((N, M), np.uint8)
+    out[:rows] = np.asarray(enc)
     return out
 
 
-def build_pq(vecs_np: np.ndarray, exists_np: np.ndarray, metric: str,
+def build_pq(vecs, exists_np: np.ndarray, metric: str,
              M: Optional[int] = None, K: Optional[int] = None,
-             iters: int = 6, min_train: int = 128) -> Optional[PqHostParts]:
-    """Train + encode the PQ tier for one frozen slab (host in, host
-    out — placement is the caller's). None = declined (too few live
-    vectors for a codebook worth having; exact scoring wins there)."""
+             iters: int = 6, min_train: int = 128,
+             seed: int = 1234, vecs_host=None) -> Optional[PqHostParts]:
+    """Train + encode the PQ tier for one frozen slab (host out —
+    placement is the caller's). ``vecs`` is the slab, resident on the
+    device (read in place) or a host array; ``vecs_host`` its host
+    mirror, where the training rows are read. Codebooks train on
+    TRAIN_PER_CODE rows a codeword drawn by ``seed`` (every live row
+    where there are fewer); every slab row up to the last live one is
+    encoded. None = declined (too few live vectors for a codebook worth
+    having; exact scoring wins there). ``M`` is the mapping's ``m``."""
+    from elasticsearch_tpu.ops.ivf import gather_rows, sample_rows
+    from elasticsearch_tpu.tracing.tracer import span
+
     # host-side BUILD path (freeze-time, never traced)
     ids = np.nonzero(exists_np)[0]  # tpulint: host
     n = ids.size
     if n < min_train:
         return None
-    dims = vecs_np.shape[1]
+    dims = vecs.shape[1]
     if M is None:
         M, dsub = pq_layout(dims)
     else:
@@ -234,16 +249,16 @@ def build_pq(vecs_np: np.ndarray, exists_np: np.ndarray, metric: str,
         dsub = dims // M
     if K is None:
         K = pq_codebook_size(n)
-    slab = vecs_np.astype(np.float32, copy=False)
-    if metric == "cosine":
-        # encode the DIRECTIONS: the ADC table-sum then approximates
-        # cos(q, v) directly (query side normalizes in the LUT build)
-        slab = _normalize_rows(slab)
-        train = slab[ids]
-    else:
-        train = slab[ids]
-    books = train_pq(train, M, K, iters=iters, metric=metric)
-    codes = pq_encode(slab, books)
+    # cosine encodes the DIRECTIONS: the ADC table-sum then approximates
+    # cos(q, v) directly (query side normalizes in the LUT build)
+    unit = metric == "cosine"
+    with span("ann.pq_train", m=M, k=K):
+        train = gather_rows(vecs, sample_rows(ids, TRAIN_PER_CODE * K, seed),
+                            normalize=unit, host=vecs_host)
+        books = train_pq(train, M, K, iters=iters)
+        del train
+    with span("ann.pq_encode", m=M):
+        codes = pq_encode(vecs, books, rows=int(ids[-1]) + 1, normalize=unit)
     return PqHostParts(codebooks=books, codes=codes, M=M, K=K, dsub=dsub,
                        dims=dims, metric=metric)
 

@@ -1219,6 +1219,7 @@ class KnnQuery(Query):
             if ivf is not None and pq is not None:
                 from elasticsearch_tpu.ops.bitvec import pack_mask, popcount
                 from elasticsearch_tpu.ops.ivf import ivf_candidate_scores
+                from elasticsearch_tpu.tracing.tracer import span
                 from elasticsearch_tpu.utils.shapes import pow2_bucket
 
                 # coarse->fine: the filter (and liveness) PRE-filters
@@ -1230,29 +1231,35 @@ class KnnQuery(Query):
                 num_cand = self.num_candidates
                 if self.filter is not None:
                     num_cand *= 4
-                pre = vc.exists & ctx.segment.live
-                if self.filter is not None:
-                    _, fm2 = self.filter.execute(ctx)
-                    pre = pre & fm2
-                words = pack_mask(pre)
-                # ~8-16x oversample: the ADC rank is a proxy — near-tie
-                # neighbors can land just past 4k survivors on tightly
-                # clustered corpora; 128 exact re-scores are still noise
-                # next to the old path's num_candidates-sized gather
-                fine_k = min(pow2_bucket(max(8 * self.k, 128)), ctx.D)
-                scores, mask = ivf_candidate_scores(
-                    ivf, vc.vecs, self.tokens[0], num_cand, vc.similarity,
-                    ctx.D, pq=pq, fine_k=fine_k, filter_words=words)
-                # recall floor: enough admissible survivors to cover k
-                # (ONE fused reduction + ONE host pull)
-                starved = jnp.sum(mask.astype(jnp.int32)) < jnp.minimum(
-                    jnp.int32(self.k), popcount(words))
-                if not bool(starved):
+                # the branch's host work: the filter, the [D] pack, the
+                # query's upload and dispatch, the starved check's pull
+                with span("search.ann", nprobe=ivf.nprobe_for(num_cand)):
+                    pre = vc.exists & ctx.segment.live
+                    if self.filter is not None:
+                        _, fm2 = self.filter.execute(ctx)
+                        pre = pre & fm2
+                    words = pack_mask(pre)
+                    # ~8-16x oversample: the ADC rank is a proxy — near-tie
+                    # neighbors can land just past 4k survivors on tightly
+                    # clustered corpora; 128 exact re-scores are still
+                    # noise next to the old path's num_candidates gather
+                    fine_k = min(pow2_bucket(max(8 * self.k, 128)), ctx.D)
+                    scores, mask = ivf_candidate_scores(
+                        ivf, vc.vecs, self.tokens[0], num_cand,
+                        vc.similarity, ctx.D, pq=pq, fine_k=fine_k,
+                        filter_words=words)
+                    # recall floor: enough admissible survivors to cover
+                    # k (ONE fused reduction + ONE host pull)
+                    starved = bool(jnp.sum(mask.astype(jnp.int32))
+                                   < jnp.minimum(jnp.int32(self.k),
+                                                 popcount(words)))
+                if not starved:
                     kernels.record("knn_ivf_pq")
                     scores = jnp.where(mask, scores, 0.0) * self.boost
                     return scores, mask
                 # starved (filter excluded the probed clusters): brute
                 # force below selects from ALL admissible docs
+                kernels.record("knn_ivf_pq_starved")
             elif ivf is not None:
                 from elasticsearch_tpu.ops.ivf import ivf_candidate_scores
 
